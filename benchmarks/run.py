@@ -160,6 +160,8 @@ def main() -> None:
                          "baseline dir (after running any --only set)")
     args = ap.parse_args()
     keys = set(args.only.split(",")) if args.only else None
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     failures = []
     # Benches run when a module set is named, or on a plain invocation;

@@ -1,24 +1,13 @@
-"""Version compatibility shims for moved/renamed jax APIs.
+"""Deprecation plumbing shared by the pre-engine entry points.
 
-Keep each shim tiny and in one place so call sites stay clean.  Mesh
-axis-type compatibility lives in `repro.launch.mesh.auto_axis_kwargs`.
-
-Also home to :func:`warn_deprecated`, the warn-once plumbing shared by
-the pre-engine entry points (`map_pairs`, the `distributed.make_*`
-factories) that now delegate to `repro.engine` — it lives here rather
-than in the engine package so `repro.core` modules can import it without
-a core <-> engine cycle.
+:func:`warn_deprecated` is the warn-once helper of the entry points
+(`map_pairs`, the `distributed.make_*` factories) that now delegate to
+`repro.engine` — it lives here rather than in the engine package so
+`repro.core` modules can import it without a core <-> engine cycle.
 """
 from __future__ import annotations
 
 import warnings
-
-import jax
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # older jax: pre-promotion location
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 
 _warned: set[str] = set()

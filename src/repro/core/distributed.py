@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map, warn_deprecated
+from repro.compat import warn_deprecated
 from repro.core.pipeline import PipelineConfig
 from repro.core.query import QueryResult, merge_read_starts
 from repro.core.seedmap import INVALID_LOC, SeedMap, SeedMapConfig
@@ -46,7 +46,8 @@ class ShardedSeedMap(NamedTuple):
 
 
 def shard_seedmap(sm: SeedMap, n_shards: int) -> ShardedSeedMap:
-    """Split a CSR SeedMap into `n_shards` bucket-range shards (host side)."""
+    """Split a CSR SeedMap into `n_shards` bucket-range shards (host
+    arrays in and out)."""
     T = sm.config.table_size
     if T % n_shards:
         raise ValueError("table_size must divide by shard count")
@@ -65,11 +66,10 @@ def shard_seedmap(sm: SeedMap, n_shards: int) -> ShardedSeedMap:
     loc = np.full((n_shards, nmax), INVALID_LOC, np.int32)
     for d, l in enumerate(shard_loc):
         loc[d, : len(l)] = l
-    return ShardedSeedMap(
-        offsets=jnp.asarray(np.stack(shard_off)),
-        locations=jnp.asarray(loc),
-        config=sm.config,
-    )
+    # Host arrays: the session's placement puts shard d on its own
+    # model-axis devices, and no full copy stays on the default device.
+    return ShardedSeedMap(offsets=np.stack(shard_off), locations=loc,
+                          config=sm.config)
 
 
 def _local_query(offsets, locations, shard_id, hashes, cfg: SeedMapConfig, K: int):
@@ -113,7 +113,7 @@ def make_sharded_locs(mesh: Mesh, model_axis: str = "model",
     def locs_fn(ssm: ShardedSeedMap, hashes: jnp.ndarray,
                 K: int) -> jnp.ndarray:
         batch_spec = P(batch_axes)
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(_inner, K=K, cfg=ssm.config),
             mesh=mesh,
             in_specs=(P(model_axis), P(model_axis), batch_spec),

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.distributed import ShardedSeedMap, _local_query
 from repro.core.encoding import BASES_PER_WORD, unpack_2bit
 from repro.kernels.candidate_align.ops import candidate_pair_align
@@ -88,22 +87,26 @@ def genpair_shardings(mesh: Mesh, batch_axes=("data",), model_axis="model"):
 def make_genpair_serve_step(mesh: Mesh, pipe_cfg: PipelineConfig,
                             sm_cfg: SeedMapConfig,
                             batch_axes=("data",), model_axis="model"):
-    """Returns serve_step(offsets, locations, ref_words, reads1, reads2)."""
+    """Returns serve_step(offsets, locations, ref_words, reads1, reads2).
+
+    The whole step is one shard_map: each device queries its bucket
+    shard (``pmin`` over ``model`` merges the shards' rows), then maps its
+    ``batch_axes`` slice of the batch locally.  Mosaic kernels cannot be
+    partitioned automatically, so the kernel backends need the local
+    view; the residual DP buffer therefore holds
+    ``residual_capacity_frac`` of each device's rows.
+    """
 
     K = pipe_cfg.max_locs_per_seed
 
-    def _sharded_query(offsets, locations, hashes):
-        def inner(off, loc, h):
-            sid = jax.lax.axis_index(model_axis)
-            locs, _ = _local_query(off[0], loc[0], sid, h, sm_cfg, K)
-            return jax.lax.pmin(locs, model_axis)
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(P(model_axis), P(model_axis), P(batch_axes)),
-            out_specs=P(batch_axes),
-        )(offsets, locations, hashes)
+    def local_step(offsets, locations, ref_words, reads1, reads2):
+        sid = jax.lax.axis_index(model_axis)
 
-    def serve_step(offsets, locations, ref_words, reads1, reads2):
+        def query(hashes):
+            locs, _ = _local_query(offsets[0], locations[0], sid, hashes,
+                                   sm_cfg, K)
+            return jax.lax.pmin(locs, model_axis)
+
         cfg = pipe_cfg
         B, R = reads1.shape
         reads2_fwd = (3 - reads2)[:, ::-1]
@@ -111,8 +114,8 @@ def make_genpair_serve_step(mesh: Mesh, pipe_cfg: PipelineConfig,
                                  sm_cfg.hash_seed)
         seeds2 = seed_read_batch(reads2_fwd, cfg.seed_len,
                                  cfg.seeds_per_read, sm_cfg.hash_seed)
-        locs1 = _sharded_query(offsets, locations, seeds1.hashes)
-        locs2 = _sharded_query(offsets, locations, seeds2.hashes)
+        locs1 = query(seeds1.hashes)
+        locs2 = query(seeds2.hashes)
         # Steps 2.5-3 fused (`kernels/pair_frontend`): start conversion +
         # sorted merge + Δ filter + compaction in one op.  The SeedMap
         # lookup itself stays under shard_map (tables are bucket-sharded
@@ -180,4 +183,10 @@ def make_genpair_serve_step(mesh: Mesh, pipe_cfg: PipelineConfig,
             n_valid=jnp.ones((B,), bool),
         )
 
-    return serve_step
+    return jax.shard_map(
+        local_step, mesh=mesh,
+        in_specs=(P(model_axis), P(model_axis), P(), P(batch_axes),
+                  P(batch_axes)),
+        # Pallas outputs carry no varying-axes annotation; every result
+        # derives from the ``pmin``-merged rows, identical across `model`.
+        out_specs=P(batch_axes), check_vma=False)

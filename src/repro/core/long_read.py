@@ -49,7 +49,12 @@ from repro.core.pair_filter import paired_adjacency_filter
 from repro.core.pipeline import PipelineConfig
 from repro.core.query import QueryResult, padded_rows_device, query_read_batch
 from repro.core.seeding import seed_read_batch
-from repro.core.seedmap import INVALID_LOC, PaddedSeedMap, SeedMap
+from repro.core.seedmap import (
+    INVALID_LOC,
+    LinedSeedMap,
+    PaddedSeedMap,
+    SeedMap,
+)
 from repro.kernels.backend import resolve_backend
 
 
@@ -171,7 +176,7 @@ def _anchor_windows(ref: jnp.ndarray, position: jnp.ndarray,
 
 
 def map_long_impl(
-    sm: SeedMap | PaddedSeedMap,
+    sm: SeedMap | PaddedSeedMap | LinedSeedMap,
     ref: jnp.ndarray,
     reads: jnp.ndarray,
     cfg: LongReadConfig = LongReadConfig(),
@@ -214,7 +219,8 @@ def map_long_impl(
         cands = paired_adjacency_filter(q1, q2, delta, p.max_candidates)
         pos1, n_cand = cands.pos1, cands.n
     else:
-        rows = (sm.rows if isinstance(sm, PaddedSeedMap)
+        rows = (sm if isinstance(sm, LinedSeedMap)
+                else sm.rows if isinstance(sm, PaddedSeedMap)
                 else padded_rows_device(sm, p.max_locs_per_seed))
         fe = segment_pair_frontend(
             rows, reads, cfg.segment_len, cfg.segment_stride, p.seed_len,

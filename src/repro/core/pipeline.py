@@ -66,7 +66,12 @@ from repro.core.pair_filter import CandidateSet, paired_adjacency_filter
 from repro.core.query import padded_rows_device, query_read_batch
 from repro.core.scoring import Scoring
 from repro.core.seeding import seed_read_batch
-from repro.core.seedmap import INVALID_LOC, PaddedSeedMap, SeedMap
+from repro.core.seedmap import (
+    INVALID_LOC,
+    LinedSeedMap,
+    PaddedSeedMap,
+    SeedMap,
+)
 from repro.kernels.backend import resolve_backend
 
 M_UNMAPPED, M_LIGHT, M_DP, M_RESIDUAL_FULL, M_DP_OVERFLOW = 0, 1, 2, 3, 4
@@ -342,7 +347,7 @@ def _residual_dp_stage(ref, reads1, reads2_fwd, pair, passed, light_ok,
 
 
 def map_pairs_impl(
-    sm: SeedMap | PaddedSeedMap,
+    sm: SeedMap | PaddedSeedMap | LinedSeedMap,
     ref: jnp.ndarray,
     reads1: jnp.ndarray,
     reads2: jnp.ndarray,
@@ -358,10 +363,11 @@ def map_pairs_impl(
     may instead be the (Lw,) uint32 2-bit packing (`pack_2bit`), which
     skips the in-step repack.
 
-    ``sm`` is the CSR `SeedMap` or the kernel-layout `PaddedSeedMap`
-    (`to_padded`).  The kernel front-end backends gather rows from the
-    padded layout; handing them a CSR map re-lays it out in-jit
-    (`padded_rows_device` — test scales only).  The padded row width
+    ``sm`` is the CSR `SeedMap`, the kernel-layout `PaddedSeedMap`
+    (`to_padded`) or its device line layout `LinedSeedMap` (`to_lined`,
+    what a kernel-backend session holds).  The kernel front-end backends
+    gather rows from the padded rows; handing them a CSR map re-lays it
+    out in-jit (`padded_rows_device` — test scales only).  The padded row width
     caps locations per seed, superseding ``cfg.max_locs_per_seed``.
     """
     B, R = reads1.shape
@@ -390,7 +396,8 @@ def map_pairs_impl(
             q1, q2, cfg.delta, cfg.max_candidates
         )
     else:
-        rows = (sm.rows if isinstance(sm, PaddedSeedMap)
+        rows = (sm if isinstance(sm, LinedSeedMap)
+                else sm.rows if isinstance(sm, PaddedSeedMap)
                 else padded_rows_device(sm, cfg.max_locs_per_seed))
         fe = pair_frontend(
             rows, reads1, reads2_fwd, cfg.seed_len, cfg.seeds_per_read,

@@ -45,7 +45,7 @@ class SeedMapConfig:
 
 
 class SeedMap(NamedTuple):
-    """CSR index. Device arrays; a valid JAX pytree."""
+    """CSR index: host arrays until a session places it; a JAX pytree."""
 
     offsets: jnp.ndarray    # int32[T + 1]
     locations: jnp.ndarray  # int32[N]
@@ -64,7 +64,33 @@ class PaddedSeedMap(NamedTuple):
     config: SeedMapConfig
 
 
+class LinedSeedMap(NamedTuple):
+    """A `PaddedSeedMap`'s rows as 128-lane lines: the device layout the
+    Pallas row gather (`kernels/pair_frontend`) DMAs from.
+
+    Row ``b`` is elements ``[b*K, (b+1)*K)`` of the flattened lines.  On a
+    TPU a ``(T, K<128)`` int32 array is stored column-major, so the kernel
+    cannot take the padded rows without a relayout through a 4x-padded
+    temporary; the lines are the same dense bytes, reshaped on the host.
+    """
+
+    lines: jnp.ndarray      # int32[n, 128]
+    config: SeedMapConfig   # static; padded_cap is the row width K
+
+
 jax.tree_util.register_static(SeedMapConfig)
+
+
+def to_lined(psm: PaddedSeedMap) -> LinedSeedMap:
+    """`PaddedSeedMap` -> `LinedSeedMap` (a free reshape for host rows
+    whose size is a whole number of (8, 128) tiles, as at every power-of-
+    two table size with K dividing 128)."""
+    from repro.kernels._util import lines_spanned, to_lines
+
+    K = psm.rows.shape[1]
+    return LinedSeedMap(
+        lines=to_lines(psm.rows.reshape(-1), lines_spanned(K, K)),
+        config=dataclasses.replace(psm.config, padded_cap=K))
 
 
 def packed_words_all_positions(ref: np.ndarray, seed_len: int) -> np.ndarray:
@@ -124,17 +150,16 @@ def build_seedmap(ref: np.ndarray, config: SeedMapConfig = SeedMapConfig()) -> S
         counts = np.where(dropped, 0, counts)
     offsets = np.zeros(config.table_size + 1, dtype=np.int32)
     np.cumsum(counts, out=offsets[1:])
-    return SeedMap(
-        offsets=jnp.asarray(offsets),
-        locations=jnp.asarray(sorted_pos.astype(np.int32)),
-        config=config,
-    )
+    # Host arrays: the session that consumes the index places it (CSR,
+    # padded or lined) on its devices once, in the layout it needs.
+    return SeedMap(offsets=offsets, locations=sorted_pos, config=config)
 
 
 def to_padded(sm: SeedMap, cap: int | None = None) -> PaddedSeedMap:
     """CSR -> bucket-major fixed-width rows (truncating at ``cap``).
 
-    ``cap`` defaults to ``config.padded_cap``; the engine passes the
+    Host arrays in, host arrays out.  ``cap`` defaults to
+    ``config.padded_cap``; the engine passes the
     pipeline's ``max_locs_per_seed`` so the padded row width matches the
     per-seed location cap the CSR query would have applied (the rows are
     then bit-identical to `query.padded_rows_device` at the same cap —
@@ -146,12 +171,15 @@ def to_padded(sm: SeedMap, cap: int | None = None) -> PaddedSeedMap:
     offsets = np.asarray(sm.offsets)
     locations = np.asarray(sm.locations)
     T, cap = cfg.table_size, cfg.padded_cap
-    counts = np.minimum(offsets[1:] - offsets[:-1], cap).astype(np.int32)
+    starts = offsets[:-1]
+    counts = np.minimum(offsets[1:] - starts, cap).astype(np.int32)
     rows = np.full((T, cap), INVALID_LOC, dtype=np.int32)
-    idx = offsets[:-1, None] + np.arange(cap)[None, :]
-    valid = np.arange(cap)[None, :] < counts[:, None]
-    rows[valid] = locations[np.minimum(idx[valid], len(locations) - 1)]
-    return PaddedSeedMap(rows=jnp.asarray(rows), counts=jnp.asarray(counts), config=cfg)
+    # One column at a time: (T,)-sized temporaries, not (T, cap) int64
+    # index tensors (8 GiB at a 2^25-bucket table).
+    for k in range(cap):
+        filled = counts > k
+        rows[filled, k] = locations[starts[filled] + k]
+    return PaddedSeedMap(rows=rows, counts=counts, config=cfg)
 
 
 def seedmap_stats(sm: SeedMap) -> dict:

@@ -45,6 +45,7 @@ from repro.core.seedmap import (
     SeedMap,
     SeedMapConfig,
     build_seedmap,
+    to_lined,
     to_padded,
 )
 from repro.engine.config import (
@@ -74,6 +75,19 @@ _DONATE_MSG = ".*donated.*"   # XLA's unusable-donation note, expected on CPU
 #: recompile anyway; the bound keeps them from also growing the cache
 #: without limit.
 _FUSED_CACHE_MAX = 8
+
+
+def _place_state(index, ref_arr, cfg: PipelineConfig, mesh) -> tuple:
+    """The replicated plan's device state ``(index, ref)``, placed once.
+
+    A kernel front end reads the padded rows in their line layout
+    (`to_lined`, a host reshape), so a genome-scale table goes to the
+    device once, dense; host arrays are placed here, not per dispatch.
+    """
+    if isinstance(index, PaddedSeedMap) and cfg.frontend_backend != "jnp":
+        index = to_lined(index)
+    where = NamedSharding(mesh, P()) if mesh is not None else None
+    return jax.device_put((index, ref_arr), where)
 
 
 class Mapper:
@@ -197,10 +211,8 @@ class Mapper:
             shardings = None
             if mesh is not None:
                 repl = NamedSharding(mesh, P())
-                index = jax.device_put(index, repl)
-                ref_arr = jax.device_put(ref_arr, repl)
                 shardings = (repl, repl)
-            state = (index, ref_arr)
+            state = _place_state(index, ref_arr, cfg, mesh)
             raw = plan.raw_pipeline_step(cfg)
         lr_cfg = raw_long = None
         if not exec_cfg.shard_index:
@@ -318,14 +330,9 @@ class Mapper:
                     and np.asarray(o).dtype == np.asarray(n).dtype
                     for o, n in zip(old_leaves, new_leaves))
         if same_shapes:
-            new_index = jax.tree.map(jnp.asarray, payload.index)
-            new_ref = jnp.asarray(payload.ref)
-            if self.exec_cfg.mesh is not None:
-                repl = NamedSharding(self.exec_cfg.mesh, P())
-                new_index = jax.device_put(new_index, repl)
-                new_ref = jax.device_put(new_ref, repl)
-            self._state = (new_index, new_ref)
-            self.index = new_index
+            self._state = _place_state(payload.index, payload.ref,
+                                       self.pipe_cfg, self.exec_cfg.mesh)
+            self.index = payload.index
             return "reused"
         warnings.warn(
             "swap_index: store differs in shape or config from the live "

@@ -36,6 +36,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.dp_fallback import band_center
 from repro.core.scoring import Scoring
+from repro.kernels._util import first_index, prefix_scan
 
 DEFAULT_BLOCK = 128
 NEG = -(1 << 20)
@@ -72,17 +73,10 @@ def count_dp_block_calls():
         _counter = prev
 
 
-def _prefix_max(x: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive running max along axis -1, Hillis–Steele (static unroll)."""
-    n = x.shape[-1]
-    d = 1
-    while d < n:
-        shifted = jnp.concatenate(
-            [jnp.full(x.shape[:-1] + (d,), NEG, x.dtype), x[..., :-d]], -1
-        )
-        x = jnp.maximum(x, shifted)
-        d *= 2
-    return x
+def _rotate_left(x: jnp.ndarray) -> jnp.ndarray:
+    """Rotate lanes left by one: the per-row column walk of the DP
+    (Mosaic lowers no value-level `dynamic_slice`)."""
+    return jnp.concatenate([x[:, 1:], x[:, :1]], axis=1)
 
 
 def _dp_block_full(read, win, scoring: Scoring):
@@ -100,8 +94,8 @@ def _dp_block_full(read, win, scoring: Scoring):
     e0 = jnp.full((BLK, W + 1), NEG, jnp.int32)
 
     def row(i, carry):
-        h_prev, e_prev = carry
-        read_col = jax.lax.dynamic_slice_in_dim(read, i, 1, axis=1)  # (BLK,1)
+        h_prev, e_prev, read_sh = carry
+        read_col = read_sh[:, :1]                                  # (BLK,1)
         e = jnp.maximum(h_prev - first, e_prev - ext)
         sub = jnp.where(read_col == win, match, -mis)  # (BLK, W)
         diag = h_prev[:, :-1] + sub
@@ -110,16 +104,16 @@ def _dp_block_full(read, win, scoring: Scoring):
         h_tmp = jnp.concatenate(
             [jnp.full((BLK, 1), 1, jnp.int32) * col0, h_tmp], -1)
         g = h_tmp + ext * j_idx
-        gmax = _prefix_max(g)
+        gmax = prefix_scan(g, jnp.maximum, NEG)
         f = jnp.concatenate(
             [jnp.full((BLK, 1), NEG, jnp.int32), gmax[:, :-1]], -1
         ) - open_ - ext * j_idx
         h = jnp.maximum(h_tmp, f)
-        return (h, e)
+        return (h, e, _rotate_left(read_sh))
 
-    h_last, _ = jax.lax.fori_loop(0, R, row, (h0, e0))
+    h_last, _, _ = jax.lax.fori_loop(0, R, row, (h0, e0, read))
     score = jnp.max(h_last, axis=-1)
-    ref_end = jnp.argmax(h_last, axis=-1).astype(jnp.int32)
+    ref_end = first_index(h_last == score[:, None])
     return score, ref_end
 
 
@@ -128,6 +122,7 @@ def _dp_block_banded(read, win, scoring: Scoring, band: int):
     ``j = i + c - band + k`` (c the center diagonal), K = 2*band + 1."""
     BLK, R = read.shape
     W = win.shape[1]
+    assert W >= R, (R, W)   # row slices never clamp: 0 <= c, R + c <= W
     c = band_center(R, W)
     K = 2 * band + 1
     match = jnp.int32(scoring.match)
@@ -140,8 +135,12 @@ def _dp_block_banded(read, win, scoring: Scoring, band: int):
 
     # Window padded so every row's K-wide substring slice is in bounds;
     # the -1 sentinel can never equal a base code (masked cells anyway).
+    # Row i compares win_pad[i + c + 1 : i + c + 1 + K]: the carry holds
+    # win_pad rotated left by i + c + 1 (and the read by i), so each row
+    # takes static leading slices and rotates by one lane.
     pad = jnp.full((BLK, band + 1), -1, jnp.int32)
     win_pad = jnp.concatenate([pad, win, pad], axis=1)
+    win_sh = jnp.concatenate([win_pad[:, c + 1:], win_pad[:, :c + 1]], 1)
 
     # Row 0 frame: H[0, j] = 0 inside the window, dead outside.
     j0 = c - band + k_iota
@@ -150,8 +149,8 @@ def _dp_block_banded(read, win, scoring: Scoring, band: int):
     e0 = jnp.full((BLK, K), NEG, jnp.int32)
 
     def row(i, carry):
-        h_prev, e_prev = carry           # row i frame ends at j = i+c+band
-        read_col = jax.lax.dynamic_slice_in_dim(read, i, 1, axis=1)
+        h_prev, e_prev, read_sh, win_sh = carry  # frame ends at i+c+band
+        read_col = read_sh[:, :1]
         jcol = (i + 1 + c - band) + k_iota          # row i+1 frame columns
         # Vertical moves read the SAME column of the previous row, which
         # sits one frame slot to the left after the slide: shift in NEG
@@ -160,7 +159,7 @@ def _dp_block_banded(read, win, scoring: Scoring, band: int):
         e_up = jnp.concatenate([e_prev[:, 1:], neg_col], -1)
         e = jnp.maximum(h_up - first, e_up - ext)
         # Diagonal moves keep the slot index; sub compares win[j-1].
-        wrow = jax.lax.dynamic_slice_in_dim(win_pad, i + c + 1, K, axis=1)
+        wrow = win_sh[:, :K]
         sub = jnp.where(read_col == wrow, match, -mis)
         h_tmp = jnp.maximum(h_prev + sub, e)
         col0 = -(open_ + ext * (i + 1))
@@ -170,15 +169,16 @@ def _dp_block_banded(read, win, scoring: Scoring, band: int):
         # of the oracle's ext*j term is a row constant, so ext*k gives
         # the identical max.
         g = h_tmp + ext * k_iota
-        gmax = _prefix_max(g)
+        gmax = prefix_scan(g, jnp.maximum, NEG)
         f = jnp.concatenate([neg_col, gmax[:, :-1]], -1) - open_ - ext * k_iota
         h = jnp.maximum(h_tmp, f)
         h = jnp.where((jcol >= 0) & (jcol <= W), h, NEG)
-        return (h, e)
+        return (h, e, _rotate_left(read_sh), _rotate_left(win_sh))
 
-    h_last, _ = jax.lax.fori_loop(0, R, row, (h0, e0))
+    h_last, _, _, _ = jax.lax.fori_loop(0, R, row,
+                                        (h0, e0, read, win_sh))
     score = jnp.max(h_last, axis=-1)
-    k_best = jnp.argmax(h_last, axis=-1).astype(jnp.int32)
+    k_best = first_index(h_last == score[:, None])
     ref_end = R + c - band + k_best      # frame slot -> window column
     return score, ref_end
 

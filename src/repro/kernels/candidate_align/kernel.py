@@ -71,8 +71,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.encoding import BASES_PER_WORD
 from repro.core.scoring import Scoring
-from repro.kernels._util import unpack_window_block
+from repro.kernels._util import (
+    LANES,
+    cut_lanes,
+    gather_lines,
+    lines_spanned,
+    unpack_window_block,
+)
 from repro.kernels.light_align.kernel import align_block
 
 DEFAULT_BLOCK = 16     # batch rows per grid step (C candidates x 2 mates each)
@@ -98,19 +105,19 @@ def _candidate_align_kernel(
     # to every grid step (required to issue step g+1's fetches from step g)
     sdma1_ref, sdma2_ref,
     # blocked inputs
-    off1_ref, off2_ref,          # (BLK, C) int32 VMEM: intra-word base offset
+    off1_ref, off2_ref,          # (BLK, C) int32 VMEM: window offset in line
     valid1_ref, valid2_ref,      # (BLK, C) int32 VMEM: candidate validity
     reads1_ref, reads2_ref,      # (BLK, R) int32 VMEM
-    ref_any,                     # (L_pad,) int32 ANY/HBM: padded reference
+    ref_any,                     # (n, 128) int32 ANY/HBM: reference lines
     # outputs, all (BLK, 1) int32
     slot_ref, rank_ref, sc1_ref, sc2_ref, ok1_ref, ok2_ref,
     et1_ref, el1_ref, ep1_ref, et2_ref, el2_ref, ep2_ref,
     # scratch
-    win1, win2,                  # (N_BANKS, C, BLK, win_elems) int32 VMEM
-    sems,                        # (N_BANKS, 2, C, BLK) DMA semaphores
+    win1, win2,                  # (N_BANKS, C, BLK*nl, 128) int32 VMEM
+    sems,                        # (N_BANKS, 2) DMA semaphores
     *,
     E: int, R: int, scoring: Scoring, threshold: int, mode: str,
-    prescreen_top: int, packed: bool, win_elems: int,
+    prescreen_top: int, packed: bool, win_elems: int, nl: int,
 ):
     BLK, C = off1_ref.shape
     W = R + 2 * E
@@ -119,14 +126,15 @@ def _candidate_align_kernel(
     bank = jax.lax.rem(g, N_BANKS)
 
     # ---- ping-pong window streaming HBM -> VMEM -------------------------
+    # A window's `nl` covering lines land in rows [r*nl, r*nl + nl).
     def _dma(bnk, mate, step, i):
         r, c = i // C, i % C
         starts = (sdma1_ref, sdma2_ref)[mate]
         win = (win1, win2)[mate]
-        s = starts[step * BLK + r, c]
+        s = starts[(step * BLK + r) * C + c]
         return pltpu.make_async_copy(
-            ref_any.at[pl.ds(s, win_elems)], win.at[bnk, c, r],
-            sems.at[bnk, mate, c, r])
+            ref_any.at[pl.ds(s, nl), :], win.at[bnk, c, pl.ds(r * nl, nl), :],
+            sems.at[bnk, mate])
 
     def _start_step(step, bnk):
         def issue(i, _):
@@ -154,30 +162,35 @@ def _candidate_align_kernel(
 
     def window(win, off_ref, c):
         """Candidate c's (BLK, W) base window from the active bank."""
-        raw = win[bank, c]                             # (BLK, win_elems)
+        off = off_ref[:, c:c + 1]
+        lines = gather_lines(win.at[bank, c], BLK, nl)  # (BLK, nl*128)
         if not packed:
-            return raw
-        # Shared 2-bit unpack + per-row offset cut (kernels/_util.py).
-        return unpack_window_block(raw, off_ref[:, c:c + 1], W)
+            return cut_lanes(lines, off, W)
+        # Packed: `off` = 16 * word lane + base-in-word; the shared 2-bit
+        # unpack cuts the per-row base offset (kernels/_util.py).
+        raw = cut_lanes(lines, off >> 4, win_elems)    # (BLK, n_words)
+        return unpack_window_block(raw, off & (BASES_PER_WORD - 1), W)
 
     reads1 = reads1_ref[...]
     reads2 = reads2_ref[...]
-    valid1 = valid1_ref[...] != 0
-    valid2 = valid2_ref[...] != 0
+    valid1 = valid1_ref[...]                           # (BLK, C) int32 0/1
+    valid2 = valid2_ref[...]
     w1 = [window(win1, off1_ref, c) for c in range(C)]
     w2 = [window(win2, off2_ref, c) for c in range(C)]
     col = jax.lax.broadcasted_iota(jnp.int32, (BLK, C), 1)
 
+    # Per-candidate selects and reductions stay on int32: Mosaic cannot
+    # slice or widen i1 vectors here.
     if 0 < prescreen_top < C:
         P = prescreen_top
         # Zero-shift Hamming pass over all C candidate pairs (one vector
         # compare per candidate — far cheaper than a full alignment).
         mm0 = jnp.concatenate(
-            [(jnp.sum((w1[c][:, E:E + R] != reads1).astype(jnp.int32), -1)
-              + jnp.sum((w2[c][:, E:E + R] != reads2).astype(jnp.int32), -1)
+            [(jnp.sum(jnp.where(w1[c][:, E:E + R] != reads1, 1, 0), -1)
+              + jnp.sum(jnp.where(w2[c][:, E:E + R] != reads2, 1, 0), -1)
               )[:, None]
              for c in range(C)], axis=1)               # (BLK, C)
-        mm0 = jnp.where(valid1 & valid2, mm0, MM_BIG)
+        mm0 = jnp.where((valid1 & valid2) != 0, mm0, MM_BIG)
         # rank = candidate's position in the mm0-ascending stable sort,
         # replicating lax.top_k's lower-index-first tie-breaking; ranks are
         # a per-row permutation of 0..C-1, so `rank == j` is exactly
@@ -186,17 +199,16 @@ def _candidate_align_kernel(
         for cp in range(C):
             mcp = mm0[:, cp:cp + 1]
             ahead = (mcp < mm0) | ((mcp == mm0) & (cp < col))
-            rank = rank + ahead.astype(jnp.int32)
-        sel = [rank == j for j in range(P)]
+            rank = rank + jnp.where(ahead, 1, 0)
 
         def gwin(ws, j):                               # -> (BLK, W)
             out = ws[0]
             for c in range(1, C):
-                out = jnp.where(sel[j][:, c:c + 1], ws[c], out)
+                out = jnp.where(rank[:, c:c + 1] == j, ws[c], out)
             return out
 
         def gcol(mat, j):                              # (BLK, C) -> (BLK,)
-            return jnp.sum(jnp.where(sel[j], mat, 0), axis=1)
+            return jnp.sum(jnp.where(rank == j, mat, 0), axis=1)
 
         # Full shifted-mask alignment only for the P survivors: the Pallas
         # backend now does P/C of the alignment work (DMA is unchanged —
@@ -206,16 +218,14 @@ def _candidate_align_kernel(
         slots = jnp.concatenate(
             [gcol(col, j)[:, None] for j in range(P)], axis=1)
         gv1 = jnp.concatenate(
-            [(gcol(valid1.astype(jnp.int32), j) != 0)[:, None]
-             for j in range(P)], axis=1)
+            [gcol(valid1, j)[:, None] for j in range(P)], axis=1) != 0
         gv2 = jnp.concatenate(
-            [(gcol(valid2.astype(jnp.int32), j) != 0)[:, None]
-             for j in range(P)], axis=1)
+            [gcol(valid2, j)[:, None] for j in range(P)], axis=1) != 0
     else:
         P = C
         aw1, aw2 = w1, w2
         slots = col
-        gv1, gv2 = valid1, valid2
+        gv1, gv2 = valid1 != 0, valid2 != 0
 
     cols1 = [align_block(reads1, aw1[j], E=E, scoring=scoring, mode=mode)
              for j in range(P)]
@@ -244,8 +254,8 @@ def _candidate_align_kernel(
     rank_ref[...] = pick(idx)
     sc1_ref[...] = pick(sc1)
     sc2_ref[...] = pick(sc2)
-    ok1_ref[...] = pick(((sc1_raw >= threshold) & gv1).astype(jnp.int32))
-    ok2_ref[...] = pick(((sc2_raw >= threshold) & gv2).astype(jnp.int32))
+    ok1_ref[...] = pick(jnp.where((sc1_raw >= threshold) & gv1, 1, 0))
+    ok2_ref[...] = pick(jnp.where((sc2_raw >= threshold) & gv2, 1, 0))
     et1_ref[...] = pick(et1)
     el1_ref[...] = pick(el1)
     ep1_ref[...] = pick(ep1)
@@ -255,12 +265,12 @@ def _candidate_align_kernel(
 
 
 def candidate_align_pallas(
-    ref_arr: jnp.ndarray,        # (L_pad,) int32 padded ref (bases or words)
+    ref_lines: jnp.ndarray,      # (n, 128) int32 padded ref lines
     reads1: jnp.ndarray,         # (B, R) int32
     reads2: jnp.ndarray,         # (B, R) int32
-    sdma1: jnp.ndarray,          # (B, C) int32 window DMA starts
+    sdma1: jnp.ndarray,          # (B*C,) int32 first line of each window
     sdma2: jnp.ndarray,
-    off1: jnp.ndarray,           # (B, C) int32 intra-word offsets (packed)
+    off1: jnp.ndarray,           # (B, C) int32 window offset in that line
     off2: jnp.ndarray,
     valid1: jnp.ndarray,         # (B, C) int32 0/1
     valid2: jnp.ndarray,
@@ -287,9 +297,10 @@ def candidate_align_pallas(
     edit_type1, edit_len1, edit_pos1, edit_type2, edit_len2, edit_pos2).
     """
     B, R = reads1.shape
-    C = sdma1.shape[1]
+    C = off1.shape[1]
     assert B % block == 0, (B, block)
     assert C <= MAX_CANDIDATES, (C, MAX_CANDIDATES)
+    nl = lines_spanned(win_elems)
     grid = (B // block,)
     row_spec = lambda cols: pl.BlockSpec((block, cols), lambda i, *_: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -298,23 +309,23 @@ def candidate_align_pallas(
         in_specs=[
             row_spec(C), row_spec(C), row_spec(C), row_spec(C),
             row_spec(R), row_spec(R),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[row_spec(1)] * 12,
         scratch_shapes=[
-            pltpu.VMEM((N_BANKS, C, block, win_elems), jnp.int32),
-            pltpu.VMEM((N_BANKS, C, block, win_elems), jnp.int32),
-            pltpu.SemaphoreType.DMA((N_BANKS, 2, C, block)),
+            pltpu.VMEM((N_BANKS, C, block * nl, LANES), jnp.int32),
+            pltpu.VMEM((N_BANKS, C, block * nl, LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((N_BANKS, 2)),
         ],
     )
     outs = pl.pallas_call(
         functools.partial(
             _candidate_align_kernel, E=max_gap, R=R, scoring=scoring,
             threshold=threshold, mode=mode, prescreen_top=prescreen_top,
-            packed=packed, win_elems=win_elems,
+            packed=packed, win_elems=win_elems, nl=nl,
         ),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 12,
         interpret=interpret,
-    )(sdma1, sdma2, off1, off2, valid1, valid2, reads1, reads2, ref_arr)
+    )(sdma1, sdma2, off1, off2, valid1, valid2, reads1, reads2, ref_lines)
     return tuple(o[:, 0] for o in outs)

@@ -17,7 +17,14 @@ import jax.numpy as jnp
 from repro.core.encoding import BASES_PER_WORD, packed_gather_coords
 from repro.core.scoring import Scoring
 from repro.core.seedmap import INVALID_LOC
-from repro.kernels._util import chunked_launch, clamp_window_starts, pad_rows
+from repro.kernels._util import (
+    LANES,
+    chunked_launch,
+    clamp_window_starts,
+    lines_spanned,
+    pad_rows,
+    to_lines,
+)
 from repro.kernels.backend import resolve_backend
 from repro.kernels.candidate_align.kernel import (
     DEFAULT_BLOCK,
@@ -87,11 +94,8 @@ def candidate_pair_align(
 
         def prep(pos, valid):
             s = jnp.clip(jnp.where(valid, pos - E, 0), 0, hi)
-            return ((s // BASES_PER_WORD).astype(jnp.int32),
-                    (s % BASES_PER_WORD).astype(jnp.int32))
+            return s // BASES_PER_WORD, s % BASES_PER_WORD
 
-        sdma1, off1 = prep(pos1, valid1)
-        sdma2, off2 = prep(pos2, valid2)
         # Back-pad with the last word so word reads past Lw-1 see the same
         # value the oracle's index clamp produces.
         words = jax.lax.bitcast_convert_type(ref, jnp.int32)
@@ -114,24 +118,38 @@ def candidate_pair_align(
 
         def prep(pos, valid):
             s = clamp_window_starts(pos, valid, L, W, E)
-            return s + (W - E), jnp.zeros_like(s, jnp.int32)
+            return s + (W - E), jnp.zeros_like(s)
 
-        sdma1, off1 = prep(pos1, valid1)
-        sdma2, off2 = prep(pos2, valid2)
         win_elems = W
+
+    # Line layout (kernels/_util.py): each window DMAs the lines covering
+    # element `e` onward; `off` is its lane in the first line (packed:
+    # 16 * word lane + base-in-word).
+    def tables(pos, valid):
+        e, base = prep(pos, valid)
+        off = (e % LANES) * (BASES_PER_WORD if packed_ref else 1) + base
+        return (e // LANES).astype(jnp.int32), off.astype(jnp.int32)
+
+    sdma1, off1 = tables(pos1, valid1)
+    sdma2, off2 = tables(pos2, valid2)
+    ref_lines = to_lines(ref_arr, lines_spanned(win_elems))
 
     # Chunk the launch so the scalar-prefetch DMA tables (SMEM, 2*rows*C*4
     # bytes per launch) stay bounded for arbitrarily large batches; every
     # chunk shares one trace/compile (identical shapes).
     total, rows = chunked_launch(B, block, LAUNCH_ROWS)
 
-    ins = tuple(pad_rows(x, total) for x in (
-        reads1.astype(jnp.int32), reads2.astype(jnp.int32),
-        sdma1, sdma2, off1, off2,
-        valid1.astype(jnp.int32), valid2.astype(jnp.int32)))
+    reads1, reads2, sdma1, sdma2, off1, off2, valid1, valid2 = (
+        pad_rows(x, total) for x in (
+            reads1.astype(jnp.int32), reads2.astype(jnp.int32),
+            sdma1, sdma2, off1, off2,
+            valid1.astype(jnp.int32), valid2.astype(jnp.int32)))
     parts = [
         candidate_align_pallas(
-            ref_arr, *(x[s:s + rows] for x in ins),
+            ref_lines, reads1[s:s + rows], reads2[s:s + rows],
+            # SMEM start tables flattened 1-D (row-major (rows, C))
+            sdma1[s:s + rows].reshape(-1), sdma2[s:s + rows].reshape(-1),
+            *(x[s:s + rows] for x in (off1, off2, valid1, valid2)),
             E, scoring, threshold, mode, prescreen_top, packed_ref,
             win_elems, block, interpret=(backend == "interpret"),
         )

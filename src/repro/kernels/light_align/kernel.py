@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.scoring import Scoring
+from repro.kernels._util import first_index, prefix_scan
 
 DEFAULT_BLOCK = 128
 BIG = 1 << 20
@@ -68,13 +69,12 @@ def align_block(read, win, *, E: int, scoring: Scoring, mode: str):
     BLK, R = read.shape
     m2 = scoring.match + scoring.mismatch
 
-    # Hamming masks for every shift, as int32 mismatch indicators.
-    masks = [
-        (win[:, s : s + R] != read).astype(jnp.int32) for s in range(2 * E + 1)
-    ]
-    zeros = jnp.zeros((BLK, 1), jnp.int32)
-    cum = [jnp.concatenate([zeros, jnp.cumsum(m, axis=-1)], axis=-1)
-           for m in masks]  # each (BLK, R+1)
+    # Hamming masks for every shift, as int32 mismatch indicators, and
+    # their exclusive prefix sums (each (BLK, R+1)).
+    cum = [prefix_scan(jnp.concatenate(
+        [jnp.zeros((BLK, 1), jnp.int32),
+         (win[:, s : s + R] != read).astype(jnp.int32)], axis=-1),
+        jnp.add, 0) for s in range(2 * E + 1)]
     cum0 = cum[E]
     p_range = jax.lax.broadcasted_iota(jnp.int32, (1, R + 1), 1)
 
@@ -102,8 +102,8 @@ def align_block(read, win, *, E: int, scoring: Scoring, mode: str):
         cand = jnp.where(interior, cand, BIG)
         if mode == "paper":
             cand = jnp.where(cand == 0, cand, BIG)
-        p_d = jnp.argmin(cand, axis=-1).astype(jnp.int32)
         mm_d = jnp.min(cand, axis=-1)
+        p_d = first_index(cand == mm_d[:, None])
         sc_d = scoring.match * R - m2 * mm_d - (
             scoring.gap_open + scoring.gap_extend * k)
         sc_d = jnp.where(mm_d >= BIG, -BIG, sc_d)
@@ -119,8 +119,8 @@ def align_block(read, win, *, E: int, scoring: Scoring, mode: str):
         cand = jnp.where(interior, cand, BIG)
         if mode == "paper":
             cand = jnp.where(cand == 0, cand, BIG)
-        p_i = jnp.argmin(cand, axis=-1).astype(jnp.int32)
         mm_i = jnp.min(cand, axis=-1)
+        p_i = first_index(cand == mm_i[:, None])
         sc_i = scoring.match * (R - k) - m2 * mm_i - (
             scoring.gap_open + scoring.gap_extend * k)
         sc_i = jnp.where(mm_i >= BIG, -BIG, sc_i)

@@ -10,12 +10,13 @@ vbin[:, j]) & valid[j]``), then ``votes = max`` over the valid counts and
 the per-read candidate budget ((S-1) * max_candidates, ~100), while the
 bin range spans the whole reference.
 
-Same double-buffered DMA protocol as `residual_dp`: the per-read row
-starts ride in as a scalar-prefetch table, two VMEM banks ping-pong
-between "being reduced" and "being filled", and both the issue and the
-wait are gated on the block being live (``step * BLK < n_rows``), so the
-grid steps past the batch's true row count cost neither HBM traffic nor
-compute — they just write zero sentinels.
+Same double-buffered DMA protocol as `residual_dp`, one DMA per block
+(a step's diagonal rows are contiguous in the 2-D row array): two VMEM
+banks ping-pong between "being reduced" and "being filled", and both the
+issue and the wait are gated on the block being live (``step * BLK <
+n_rows``, the launch's live row count riding in as a scalar-prefetch
+operand), so the grid steps past the batch's true row count cost neither
+HBM traffic nor compute — they just write zero sentinels.
 """
 from __future__ import annotations
 
@@ -27,27 +28,25 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.seedmap import INVALID_LOC
+from repro.kernels._util import LANES
 
 DEFAULT_BLOCK = 64     # reads per grid step
 N_BANKS = 2            # ping-pong VMEM diagonal-row banks
 
-# Reads per pallas launch (ops.py chunks bigger batches): the
-# scalar-prefetch DMA start table is SMEM-resident at rows * 4 bytes per
-# launch, bounded no matter how large the read batch is.
+# Reads per pallas launch (ops.py chunks bigger batches).
 LAUNCH_ROWS = 4096
 
 
 def _location_vote_kernel(
     # scalar prefetch (SMEM, visible to every grid step)
-    sdma_ref,                    # (rows,) int32 diagonal-row DMA starts
     nrows_ref,                   # (1,) int32 live read count of this launch
     # inputs
-    diag_any,                    # (rows*M,) int32 ANY/HBM: flat diagonals
+    diag_any,                    # (rows, Mp) int32 ANY/HBM: diagonal rows
     # outputs, all (BLK, 1) int32
     bin_ref, votes_ref, did_ref,
     # scratch
-    win,                         # (N_BANKS, BLK, M) int32 VMEM
-    sems,                        # (N_BANKS, BLK) DMA semaphores
+    win,                         # (N_BANKS, BLK, Mp) int32 VMEM
+    sems,                        # (N_BANKS,) DMA semaphores
     *,
     M: int, vote_bin: int,
 ):
@@ -60,48 +59,42 @@ def _location_vote_kernel(
     def live(step):
         return step * BLK < n
 
-    # ---- ping-pong row streaming HBM -> VMEM (live blocks only) ---------
-    def _dma(step, bnk, r):
-        s = sdma_ref[step * BLK + r]
+    # ---- ping-pong block streaming HBM -> VMEM (live blocks only) -------
+    # A step's BLK diagonal rows are contiguous: one DMA per block.
+    def _dma(step, bnk):
         return pltpu.make_async_copy(
-            diag_any.at[pl.ds(s, M)], win.at[bnk, r], sems.at[bnk, r])
-
-    def _start_step(step, bnk):
-        def issue(r, _):
-            _dma(step, bnk, r).start()
-            return 0
-        jax.lax.fori_loop(0, BLK, issue, 0)
-
-    def _wait_step(step, bnk):
-        def drain(r, _):
-            _dma(step, bnk, r).wait()
-            return 0
-        jax.lax.fori_loop(0, BLK, drain, 0)
+            diag_any.at[pl.ds(step * BLK, BLK), :], win.at[bnk],
+            sems.at[bnk])
 
     @pl.when((g == 0) & live(0))
     def _():                     # warm-up: first step fetches its own bank
-        _start_step(0, 0)
+        _dma(0, 0).start()
 
     @pl.when((g + 1 < nsteps) & live(g + 1))
     def _():                     # prefetch next live step, other bank
-        _start_step(g + 1, jax.lax.rem(g + 1, N_BANKS))
+        _dma(g + 1, jax.lax.rem(g + 1, N_BANKS)).start()
 
     @pl.when(live(g))
     def _():                     # this block holds real reads
-        _wait_step(g, bank)
-        d = win[bank]                                  # (BLK, M)
+        _dma(g, bank).wait()
+        d = win[bank]                                  # (BLK, Mp)
         valid = d != INVALID_LOC
         # Floored division, matching the oracle: negative near-origin
         # diagonals must round toward -inf, not toward zero.
         vbin = jnp.floor_divide(d, vote_bin)
+        valid_i = jnp.where(valid, 1, 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
 
         def count_slot(j, counts):
-            bj = jax.lax.dynamic_slice_in_dim(vbin, j, 1, axis=1)
-            vj = jax.lax.dynamic_slice_in_dim(valid, j, 1, axis=1)
-            return counts + jnp.where((vbin == bj) & vj, 1, 0)
+            # Slot j's bin and validity as (BLK, 1) columns (one-hot lane
+            # reductions: Mosaic lowers no dynamic lane slice).
+            at_j = lane == j
+            bj = jnp.sum(jnp.where(at_j, vbin, 0), axis=1, keepdims=True)
+            vj = jnp.sum(jnp.where(at_j, valid_i, 0), axis=1, keepdims=True)
+            return counts + jnp.where((vbin == bj) & (vj != 0), 1, 0)
 
         counts = jax.lax.fori_loop(
-            0, M, count_slot, jnp.zeros((BLK, M), jnp.int32))
+            0, M, count_slot, jnp.zeros(d.shape, jnp.int32))
         votes = jnp.max(jnp.where(valid, counts, 0), axis=-1)
         at_max = valid & (counts == votes[:, None])
         win_bin = jnp.min(
@@ -118,31 +111,33 @@ def _location_vote_kernel(
 
 
 def location_vote_pallas(
-    flat_diag: jnp.ndarray,      # (rows*M,) int32 flattened diagonal rows
-    sdma: jnp.ndarray,           # (rows,) int32 row DMA starts
+    diag: jnp.ndarray,           # (rows, Mp) int32 diagonal rows
     n_rows: jnp.ndarray,         # (1,) int32 live read count
     vote_bin: int,
     M: int,
     block: int = DEFAULT_BLOCK,
     interpret: bool = False,
 ):
-    """rows must be a multiple of `block` (ops.py pads and chunks).
+    """rows must be a multiple of `block` (ops.py pads and chunks), and
+    Mp >= M a multiple of 128 (a row DMA moves whole lines): slots past
+    the M real ones must hold INVALID_LOC.
 
     Returns 3 (rows,) int32 arrays: (win_bin, votes, did) — `did` is 1
     exactly on the lanes of grid steps that executed at runtime.
     """
-    rows = sdma.shape[0]
+    rows, Mp = diag.shape
     assert rows % block == 0, (rows, block)
+    assert Mp % LANES == 0 and Mp >= M, (Mp, M)
     grid = (rows // block,)
     row_spec = lambda cols: pl.BlockSpec((block, cols), lambda i, *_: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[row_spec(1)] * 3,
         scratch_shapes=[
-            pltpu.VMEM((N_BANKS, block, M), jnp.int32),
-            pltpu.SemaphoreType.DMA((N_BANKS, block)),
+            pltpu.VMEM((N_BANKS, block, Mp), jnp.int32),
+            pltpu.SemaphoreType.DMA((N_BANKS,)),
         ],
     )
     outs = pl.pallas_call(
@@ -150,5 +145,5 @@ def location_vote_pallas(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.int32)] * 3,
         interpret=interpret,
-    )(sdma, n_rows, flat_diag)
+    )(n_rows, diag)
     return tuple(o[:, 0] for o in outs)

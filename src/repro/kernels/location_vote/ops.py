@@ -15,7 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels._util import chunked_launch, pad_rows
+from repro.core.seedmap import INVALID_LOC
+from repro.kernels._util import LANES, chunked_launch, pad_rows
 from repro.kernels.backend import resolve_backend
 from repro.kernels.location_vote.kernel import (
     DEFAULT_BLOCK,
@@ -48,14 +49,15 @@ def location_vote(
         return location_vote_ref(diag, vote_bin)
 
     B, M = diag.shape
-    # Chunk the launch so the scalar-prefetch DMA start table (SMEM,
-    # rows * 4 bytes per launch) stays bounded for arbitrarily large
-    # batches; every chunk shares one trace/compile (identical shapes).
+    # Chunk the launch so every chunk shares one trace/compile
+    # (identical shapes) whatever the batch; rows are padded to whole
+    # 128-lane lines with non-voting INVALID_LOC slots.
     total, rows = chunked_launch(B, block, LAUNCH_ROWS)
-    flat = pad_rows(diag.astype(jnp.int32), total).reshape(-1)
+    diag = jnp.pad(pad_rows(diag.astype(jnp.int32), total),
+                   ((0, 0), (0, (-M) % LANES)), constant_values=INVALID_LOC)
     parts = [
         location_vote_pallas(
-            flat, (jnp.arange(rows, dtype=jnp.int32) + s) * M,
+            diag[s:s + rows],
             jnp.full((1,), min(max(B - s, 0), rows), jnp.int32),
             vote_bin, M, block, interpret=(backend == "interpret"))
         for s in range(0, total, rows)

@@ -31,9 +31,10 @@ In-VMEM sorted merge
 `jnp.sort` has no Mosaic lowering, so the merge uses the same
 stable-rank one-hot idiom as the candidate_align prescreen: rank every
 element by `#{j : x_j < x_i or (x_j == x_i and j < i)}` with one
-`(BLK, M, M)` compare, then scatter values to their rank with a one-hot
-sum.  M = S*K (96 at the paper's S=3, K=32), so the compare tensors are
-a few hundred KB of VMEM at the default block.
+`(M, M)` compare, then scatter values to their rank with a one-hot
+sum.  M = S*K (96 at the paper's S=3, K=32); the kernel runs the merge
+one read at a time, since a whole block's `(BLK, M, M)` tensors would
+overflow VMEM.
 
 The Δ filter mirrors `pair_filter._row_filter` exactly: a broadcast-
 compare `searchsorted`, per-occurrence partner probing (duplicate
@@ -43,21 +44,26 @@ dedup via adjacent-compare, and cumulative-sum front compaction.
 Double-buffered row DMA (ping-pong protocol)
 --------------------------------------------
 The row-gather kernel reuses the `candidate_align` cross-grid-step
-protocol: the `(B, S)` DMA start tables are scalar-prefetch operands
+protocol: the `(B*S,)` DMA start tables are scalar-prefetch operands
 (SMEM, visible to every step), so step ``g`` issues step ``g+1``'s
 2*S*BLK row fetches into the *other* of two VMEM location banks while its
 own merge/filter compute runs, then waits only on its own bank's
-semaphores.  Each (bank, mate, row, seed) DMA has its own semaphore; the
-refill of the bank step ``g`` computed on is issued during step ``g+1``,
-after step ``g``'s compute has fully completed (grid steps run
-sequentially), so no write-after-read hazard exists.  This replaces the
-start-all/wait-all
-burst the kernel shipped with — the Location-Table HBM traffic of step
-g+1 hides behind the `(BLK, M, M)` sort/filter compute of step g.
+semaphores (one per bank and mate; each wait consumes one row's
+bytes).  The refill of the bank step ``g`` computed on is issued during
+step ``g+1``, after step ``g``'s compute has fully completed (grid steps
+run sequentially), so no write-after-read hazard exists: the
+Location-Table HBM traffic of step g+1 hides behind the sort/filter
+compute of step g.
+
+Mosaic DMAs a slice of an HBM table only at tile boundaries, so the
+table is the padded rows as dense 128-lane lines (`kernels/_util.py`):
+each seed's DMA fetches the line holding its row, and the kernel cuts
+the row at its lane offset.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +71,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.seedmap import INVALID_LOC
+from repro.kernels._util import LANES, cut_lanes, lines_spanned
 from repro.kernels.xxhash.kernel import xxhash32_lanes
 
 DEFAULT_BLOCK = 8        # batch rows per grid step (2*S row DMAs each)
@@ -81,24 +88,28 @@ LAUNCH_ROWS = 2048
 # --------------------------------------------------------------- hashing --
 def _seed_bucket_kernel(reads_ref, out_ref, *, offs, seed_len: int,
                         hash_seed: int, mask: int):
-    """(BLK, R) int32 base codes -> (BLK, S) int32 SeedMap bucket ids."""
-    reads = reads_ref[...]
+    """(R, BLK) int32 base codes, read-major -> (S, BLK) int32 SeedMap
+    bucket ids.
+
+    Reads lie along lanes and read positions along sublanes, so every
+    base of a seed is one static row load of the block and every hash
+    lane is a read.  (The (BLK, R) layout needs a width-1 lane column per
+    base, which Mosaic miscompiles on the chip.)
+    """
     n_full, rem = divmod(seed_len, 16)
-    cols = []
-    for off in offs:
+    for s, off in enumerate(offs):
         words = []
         for w in range(MAX_SEED_WORDS):
             # 2-bit pack bases [off+16w, off+16w+cnt) little-endian; words
             # past the seed are zero (pack_seed_words' zero padding).
+            # int32 bits of the uint32 words (see `xxhash32_lanes`).
             cnt = 16 if w < n_full else (rem if w == n_full else 0)
-            acc = jnp.zeros((reads.shape[0], 1), jnp.uint32)
+            acc = jnp.zeros((1, reads_ref.shape[1]), jnp.int32)
             for i in range(cnt):
-                b = reads[:, off + 16 * w + i : off + 16 * w + i + 1]
-                acc = acc | (b.astype(jnp.uint32) << jnp.uint32(2 * i))
+                p = off + 16 * w + i
+                acc = acc | (reads_ref[p:p + 1, :] << (2 * i))
             words.append(acc)
-        h = xxhash32_lanes(*words, seed=hash_seed)
-        cols.append((h & jnp.uint32(mask)).astype(jnp.int32))
-    out_ref[...] = jnp.concatenate(cols, axis=1)
+        out_ref[s:s + 1, :] = xxhash32_lanes(*words, seed=hash_seed) & mask
 
 
 def seed_buckets_pallas(
@@ -119,11 +130,11 @@ def seed_buckets_pallas(
         functools.partial(_seed_bucket_kernel, offs=offs, seed_len=seed_len,
                           hash_seed=hash_seed, mask=table_size - 1),
         grid=(n // block,),
-        in_specs=[pl.BlockSpec((block, R), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block, S), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, S), jnp.int32),
+        in_specs=[pl.BlockSpec((R, block), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((S, block), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((S, n), jnp.int32),
         interpret=interpret,
-    )(reads)
+    )(reads.T).T
 
 
 # ---------------------------------------------------------- merge+filter --
@@ -180,16 +191,20 @@ def merge_filter_block(l1, l2, *, seed_offs, K: int, delta: int, cap: int):
 
     within = ((p2 != INVALID_LOC) & (jnp.abs(p2 - s1) <= delta)
               & (s1 != INVALID_LOC))
-    prev_same = jnp.concatenate(
-        [jnp.zeros((BLK, 1), jnp.bool_),
-         (s1[:, 1:] == s1[:, :-1]) & (p2[:, 1:] == p2[:, :-1])], axis=1)
+    # Mosaic neither concatenates nor lane->sublane reshapes i1 vectors,
+    # so the adjacent-compare and the compaction scatter work on int32.
+    def prev(x):
+        return jnp.concatenate([x[:, :1], x[:, :-1]], axis=1)
+    first = jax.lax.broadcasted_iota(jnp.int32, (BLK, M), 1) == 0
+    prev_same = (s1 == prev(s1)) & (p2 == prev(p2)) & ~first
     keep = within & ~prev_same
 
     # Front compaction: kept element i lands at slot #{j < i : keep_j}.
     cpos = jnp.sum((keep[:, None, :] & (j_idx < i_idx)).astype(jnp.int32),
                    axis=2)
+    cpos = jnp.where(keep, cpos, -1)                          # -1: dropped
     c_idx = jax.lax.broadcasted_iota(jnp.int32, (BLK, M, cap), 2)
-    sel = keep[:, :, None] & (cpos[:, :, None] == c_idx)     # (BLK, M, cap)
+    sel = cpos[:, :, None] == c_idx                           # (BLK, M, cap)
     pos1 = jnp.sum(jnp.where(sel, s1[:, :, None], 0), axis=1)
     pos2 = jnp.sum(jnp.where(sel, p2[:, :, None], 0), axis=1)
     nkeep = jnp.sum(keep.astype(jnp.int32), axis=1, keepdims=True)
@@ -199,35 +214,48 @@ def merge_filter_block(l1, l2, *, seed_offs, K: int, delta: int, cap: int):
     return pos1, pos2, jnp.minimum(nkeep, cap), nh1, nh2
 
 
+def _merge_filter_rows(n_rows: int, locs_of, outs, **kw):
+    """`merge_filter_block` one row at a time, writing row r of each
+    output ref: a whole block's (BLK, M, M) compare tensors would
+    overflow VMEM.  ``locs_of(r)`` returns row r's (1, M) locations."""
+    def body(r, carry):
+        res = merge_filter_block(*locs_of(r), **kw)
+        for ref, val in zip(outs, res):
+            ref[pl.ds(r, 1), :] = val
+        return carry
+    jax.lax.fori_loop(0, n_rows, body, 0)
+
+
 # ------------------------------------------------- fused gather + filter --
 def _frontend_kernel(
-    # scalar prefetch: full (B, S) int32 flattened-row-offset tables, SMEM
+    # scalar prefetch: (B*S,) int32 flattened-row-offset tables, SMEM
     sdma1_ref, sdma2_ref,
     # inputs
-    table_any,                   # (T*K,) int32 ANY/HBM: padded location rows
+    table_any,                   # (n, 128) int32 ANY/HBM: row-table lines
     # outputs
     pos1_ref, pos2_ref,          # (BLK, C) int32
     n_ref, nh1_ref, nh2_ref,     # (BLK, 1) int32
     # scratch
-    loc1, loc2,                  # (N_BANKS, BLK, S*K) int32 VMEM
-    sems,                        # (N_BANKS, 2, BLK, S) DMA semaphores
+    loc,                         # (N_BANKS, 2, S, BLK*nl, 128) int32 VMEM
+    sems,                        # (N_BANKS, 2) DMA semaphores
     *,
-    S: int, K: int, seed_offs: tuple, delta: int, cap: int,
+    S: int, K: int, nl: int, seed_offs: tuple, delta: int, cap: int,
 ):
     BLK = pos1_ref.shape[0]
     g = pl.program_id(0)
     nsteps = pl.num_programs(0)
     bank = jax.lax.rem(g, N_BANKS)
+    starts = (sdma1_ref, sdma2_ref)
 
     # ---- ping-pong row streaming HBM -> VMEM (candidate_align protocol) --
+    # Row (r, s)'s `nl` covering lines land in rows [r*nl, r*nl + nl).
     def _dma(bnk, mate, step, i):
         r, s = i // S, i % S
-        starts = (sdma1_ref, sdma2_ref)[mate]
-        loc = (loc1, loc2)[mate]
-        st = starts[step * BLK + r, s]
-        return pltpu.make_async_copy(table_any.at[pl.ds(st, K)],
-                                     loc.at[bnk, r, pl.ds(s * K, K)],
-                                     sems.at[bnk, mate, r, s])
+        st = starts[mate][(step * BLK + r) * S + s]
+        return pltpu.make_async_copy(
+            table_any.at[pl.ds(st // LANES, nl), :],
+            loc.at[bnk, mate, s, pl.ds(r * nl, nl), :],
+            sems.at[bnk, mate])
 
     def _start_step(step, bnk):
         def issue(i, _):
@@ -253,19 +281,27 @@ def _frontend_kernel(
 
     _wait_step(g, bank)          # this step's rows are now resident
 
-    pos1, pos2, n, nh1, nh2 = merge_filter_block(
-        loc1[bank], loc2[bank], seed_offs=seed_offs, K=K, delta=delta,
-        cap=cap)
-    pos1_ref[...] = pos1
-    pos2_ref[...] = pos2
-    n_ref[...] = n
-    nh1_ref[...] = nh1
-    nh2_ref[...] = nh2
+    def row_locs(mate, r):
+        """(1, S*K) seed-major locations of row r: each seed's row cut at
+        its lane offset out of its covering lines."""
+        cuts = []
+        for s in range(S):
+            lines = loc[bank, mate, s, pl.ds(r * nl, nl), :]   # (nl, 128)
+            line = jnp.concatenate([lines[q:q + 1] for q in range(nl)],
+                                   axis=1) if nl > 1 else lines
+            st = starts[mate][(g * BLK + r) * S + s]
+            cuts.append(cut_lanes(line, st % LANES, K, math.gcd(K, LANES)))
+        return jnp.concatenate(cuts, axis=1)
+
+    _merge_filter_rows(
+        BLK, lambda r: (row_locs(0, r), row_locs(1, r)),
+        (pos1_ref, pos2_ref, n_ref, nh1_ref, nh2_ref),
+        seed_offs=seed_offs, K=K, delta=delta, cap=cap)
 
 
 def pair_frontend_pallas(
-    table: jnp.ndarray,          # (T*K,) int32 flattened padded rows
-    sdma1: jnp.ndarray,          # (B, S) int32 row offsets (bucket * K)
+    table: jnp.ndarray,          # (n, 128) int32 padded-row-table lines
+    sdma1: jnp.ndarray,          # (B*S,) int32 row offsets (bucket * K)
     sdma2: jnp.ndarray,
     seed_offs: tuple,            # static per-seed read offsets
     K: int,
@@ -279,24 +315,25 @@ def pair_frontend_pallas(
 
     Returns (pos1, pos2) (B, C) and (n, n_hits1, n_hits2) (B,) int32.
     """
-    B, S = sdma1.shape
+    S = len(seed_offs)
+    B = sdma1.shape[0] // S
     assert B % block == 0, (B, block)
     C = max_candidates
+    nl = lines_spanned(K, K)
     row_spec = lambda cols: pl.BlockSpec((block, cols), lambda i, *_: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B // block,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[row_spec(C), row_spec(C),
                    row_spec(1), row_spec(1), row_spec(1)],
         scratch_shapes=[
-            pltpu.VMEM((N_BANKS, block, S * K), jnp.int32),
-            pltpu.VMEM((N_BANKS, block, S * K), jnp.int32),
-            pltpu.SemaphoreType.DMA((N_BANKS, 2, block, S)),
+            pltpu.VMEM((N_BANKS, 2, S, block * nl, LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((N_BANKS, 2)),
         ],
     )
     outs = pl.pallas_call(
-        functools.partial(_frontend_kernel, S=S, K=K,
+        functools.partial(_frontend_kernel, S=S, K=K, nl=nl,
                           seed_offs=tuple(seed_offs), delta=delta, cap=C),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, C), jnp.int32)] * 2
@@ -311,14 +348,11 @@ def pair_frontend_pallas(
 def _merge_filter_kernel(l1_ref, l2_ref, pos1_ref, pos2_ref,
                          n_ref, nh1_ref, nh2_ref, *,
                          seed_offs: tuple, K: int, delta: int, cap: int):
-    pos1, pos2, n, nh1, nh2 = merge_filter_block(
-        l1_ref[...], l2_ref[...], seed_offs=seed_offs, K=K, delta=delta,
-        cap=cap)
-    pos1_ref[...] = pos1
-    pos2_ref[...] = pos2
-    n_ref[...] = n
-    nh1_ref[...] = nh1
-    nh2_ref[...] = nh2
+    _merge_filter_rows(
+        pos1_ref.shape[0],
+        lambda r: (l1_ref[pl.ds(r, 1), :], l2_ref[pl.ds(r, 1), :]),
+        (pos1_ref, pos2_ref, n_ref, nh1_ref, nh2_ref),
+        seed_offs=seed_offs, K=K, delta=delta, cap=cap)
 
 
 def merge_filter_pallas(

@@ -22,7 +22,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.seeding import seed_offsets_tuple
-from repro.kernels._util import chunked_launch, pad_rows
+from repro.core.seedmap import LinedSeedMap
+from repro.kernels._util import (
+    chunked_launch,
+    lines_spanned,
+    pad_rows,
+    to_lines,
+)
 from repro.kernels.backend import resolve_backend
 from repro.kernels.pair_frontend.kernel import (
     DEFAULT_BLOCK,
@@ -45,7 +51,7 @@ from repro.kernels.pair_frontend.ref import (
                      "max_candidates", "block", "backend"),
 )
 def pair_frontend(
-    rows: jnp.ndarray,       # (T, K) int32 padded location rows
+    rows,                    # (T, K) int32 padded rows, or a LinedSeedMap
     reads1: jnp.ndarray,     # (B, R) mate 1, reference orientation
     reads2: jnp.ndarray,     # (B, R) mate 2, reference orientation
     seed_len: int,
@@ -59,22 +65,33 @@ def pair_frontend(
     """Fused front end for a batch of read pairs.
 
     ``rows`` is the bucket-major padded Location Table (`to_padded(sm).rows`
-    or the in-jit CSR derivation in `core/pipeline.py`); its row width K
-    caps the locations per seed.  Both reads are expected in reference
-    orientation (mate 2 pre-revcomp'd, as everywhere in the pipeline).
+    or the in-jit CSR derivation in `core/pipeline.py`), or the same table
+    as a `LinedSeedMap` — the layout the kernels DMA from, which a session
+    places once (laying out (T, K) rows in-jit is a relayout: test scales
+    only).  Its row width K caps the locations per seed.  Both reads are
+    expected in reference orientation (mate 2 pre-revcomp'd, as
+    everywhere in the pipeline).
     ``block=None`` resolves to `DEFAULT_BLOCK`; the autotuner
     (`repro.tune`) threads per-shape winners here through
     `PipelineConfig.frontend_block`.
     """
     backend = resolve_backend(backend, family="pair_frontend")
     block = block or DEFAULT_BLOCK
+    if isinstance(rows, LinedSeedMap):
+        T, K = rows.config.table_size, rows.config.padded_cap
+        table = rows.lines
+        rows = table.reshape(-1)[:T * K].reshape(T, K)   # jnp oracle only
+    else:
+        T, K = rows.shape
+        table = None
     if backend == "jnp":
         return pair_frontend_ref(rows, reads1, reads2, seed_len,
                                  seeds_per_read, hash_seed, delta,
                                  max_candidates)
+    if table is None:
+        table = to_lines(rows.reshape(-1), lines_spanned(K, K))
     interpret = backend == "interpret"
     B, R = reads1.shape
-    T, K = rows.shape
     offs = seed_offsets_tuple(R, seed_len, seeds_per_read)
 
     # -- kernel 1: both mates' bucket ids in one launch -------------------
@@ -86,18 +103,16 @@ def pair_frontend(
         interpret=interpret)[:n]
 
     # -- kernel 2: row gather + merge + filter ----------------------------
-    # Scalar-prefetch tables hold flattened row offsets into the (T*K,)
-    # table; padding rows aim at bucket 0 (a safe in-bounds DMA) and are
-    # sliced off below.
-    sdma1 = buckets[:B] * K
-    sdma2 = buckets[B:] * K
-    table = rows.reshape(-1)
+    # Scalar-prefetch tables hold flattened row offsets into the line
+    # table, 1-D per launch; padding rows aim at bucket 0 (a safe
+    # in-bounds DMA) and are sliced off below.
     total, rows_per = chunked_launch(B, block, LAUNCH_ROWS)
-    sdma1 = pad_rows(sdma1, total)
-    sdma2 = pad_rows(sdma2, total)
+    sdma1 = pad_rows(buckets[:B] * K, total)
+    sdma2 = pad_rows(buckets[B:] * K, total)
     parts = [
         pair_frontend_pallas(
-            table, sdma1[s:s + rows_per], sdma2[s:s + rows_per], offs, K,
+            table, sdma1[s:s + rows_per].reshape(-1),
+            sdma2[s:s + rows_per].reshape(-1), offs, K,
             delta, max_candidates, block=block, interpret=interpret)
         for s in range(0, total, rows_per)
     ]
@@ -109,7 +124,7 @@ def pair_frontend(
 
 
 def segment_pair_frontend(
-    rows: jnp.ndarray,       # (T, K) int32 padded location rows
+    rows,                    # (T, K) int32 padded rows, or a LinedSeedMap
     reads: jnp.ndarray,      # (B, L) long reads, reference orientation
     segment_len: int,
     segment_stride: int,

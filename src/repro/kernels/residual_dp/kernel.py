@@ -45,8 +45,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.encoding import BASES_PER_WORD
 from repro.core.scoring import Scoring
-from repro.kernels._util import unpack_window_block
+from repro.kernels._util import (
+    LANES,
+    cut_lanes,
+    gather_lines,
+    lines_spanned,
+    unpack_window_block,
+)
 from repro.kernels.banded_sw.kernel import NEG, dp_block
 
 DEFAULT_BLOCK = 32     # work items (failed mates) per grid step
@@ -64,16 +71,16 @@ def _residual_dp_kernel(
     nitems_ref,                  # (1,) int32 live item count of this launch
     # blocked inputs
     reads_ref,                   # (BLK, R) int32 item reads
-    off_ref,                     # (BLK, 1) int32 intra-word offsets (packed)
-    ref_any,                     # (L_pad,) int32 ANY/HBM: padded reference
+    off_ref,                     # (BLK, 1) int32 window offset in line
+    ref_any,                     # (n, 128) int32 ANY/HBM: reference lines
     # outputs, all (BLK, 1) int32
     score_ref, end_ref, did_ref,
     # scratch
-    win,                         # (N_BANKS, BLK, win_elems) int32 VMEM
-    sems,                        # (N_BANKS, BLK) DMA semaphores
+    win,                         # (N_BANKS, BLK*nl, 128) int32 VMEM
+    sems,                        # (N_BANKS,) DMA semaphores
     *,
     R: int, W: int, band: int | None, scoring: Scoring, packed: bool,
-    win_elems: int,
+    win_elems: int, nl: int,
 ):
     BLK = reads_ref.shape[0]
     g = pl.program_id(0)
@@ -85,11 +92,12 @@ def _residual_dp_kernel(
         return step * BLK < n
 
     # ---- ping-pong window streaming HBM -> VMEM (live blocks only) ------
+    # An item's `nl` covering lines land in rows [r*nl, r*nl + nl).
     def _dma(step, bnk, r):
         s = sdma_ref[step * BLK + r]
         return pltpu.make_async_copy(
-            ref_any.at[pl.ds(s, win_elems)], win.at[bnk, r],
-            sems.at[bnk, r])
+            ref_any.at[pl.ds(s, nl), :], win.at[bnk, pl.ds(r * nl, nl), :],
+            sems.at[bnk])
 
     def _start_step(step, bnk):
         def issue(r, _):
@@ -114,10 +122,15 @@ def _residual_dp_kernel(
     @pl.when(live(g))
     def _():                     # this block holds real failed-mate items
         _wait_step(g, bank)
-        raw = win[bank]                                # (BLK, win_elems)
-        # Packed refs: the shared 2-bit unpack + per-item offset cut
-        # (the same `unpack_window_block` candidate_align uses).
-        wrow = unpack_window_block(raw, off_ref[...], W) if packed else raw
+        lines = gather_lines(win.at[bank], BLK, nl)    # (BLK, nl*128)
+        off = off_ref[...]
+        if packed:
+            # `off` = 16 * word lane + base-in-word: the lane cut, then the
+            # shared 2-bit unpack (the same one candidate_align uses).
+            raw = cut_lanes(lines, off >> 4, win_elems)
+            wrow = unpack_window_block(raw, off & (BASES_PER_WORD - 1), W)
+        else:
+            wrow = cut_lanes(lines, off, W)
         score, end = dp_block(reads_ref[...], wrow,
                               scoring=scoring, band=band)
         score_ref[...] = score[:, None]
@@ -132,11 +145,11 @@ def _residual_dp_kernel(
 
 
 def residual_dp_pallas(
-    ref_arr: jnp.ndarray,        # (L_pad,) int32 padded ref (bases or words)
-    sdma: jnp.ndarray,           # (rows,) int32 window DMA starts
+    ref_lines: jnp.ndarray,      # (n, 128) int32 padded ref lines
+    sdma: jnp.ndarray,           # (rows,) int32 first line of each window
     n_items: jnp.ndarray,        # (1,) int32 live item count
     reads: jnp.ndarray,          # (rows, R) int32 item reads
-    off: jnp.ndarray,            # (rows, 1) int32 intra-word offsets
+    off: jnp.ndarray,            # (rows, 1) int32 window offset in line
     dp_pad: int,
     band: int | None,
     scoring: Scoring,
@@ -153,6 +166,7 @@ def residual_dp_pallas(
     rows, R = reads.shape
     W = R + 2 * dp_pad
     assert rows % block == 0, (rows, block)
+    nl = lines_spanned(win_elems)
     grid = (rows // block,)
     row_spec = lambda cols: pl.BlockSpec((block, cols), lambda i, *_: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -160,21 +174,21 @@ def residual_dp_pallas(
         grid=grid,
         in_specs=[
             row_spec(R), row_spec(1),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[row_spec(1)] * 3,
         scratch_shapes=[
-            pltpu.VMEM((N_BANKS, block, win_elems), jnp.int32),
-            pltpu.SemaphoreType.DMA((N_BANKS, block)),
+            pltpu.VMEM((N_BANKS, block * nl, LANES), jnp.int32),
+            pltpu.SemaphoreType.DMA((N_BANKS,)),
         ],
     )
     outs = pl.pallas_call(
         functools.partial(
             _residual_dp_kernel, R=R, W=W, band=band, scoring=scoring,
-            packed=packed, win_elems=win_elems,
+            packed=packed, win_elems=win_elems, nl=nl,
         ),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.int32)] * 3,
         interpret=interpret,
-    )(sdma, n_items, reads, off, ref_arr)
+    )(sdma, n_items, reads, off, ref_lines)
     return tuple(o[:, 0] for o in outs)

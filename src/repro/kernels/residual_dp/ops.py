@@ -26,7 +26,14 @@ import jax.numpy as jnp
 from repro.core.encoding import BASES_PER_WORD, packed_gather_coords
 from repro.core.scoring import Scoring
 from repro.core.seedmap import INVALID_LOC
-from repro.kernels._util import chunked_launch, clamp_window_starts, pad_rows
+from repro.kernels._util import (
+    LANES,
+    chunked_launch,
+    clamp_window_starts,
+    lines_spanned,
+    pad_rows,
+    to_lines,
+)
 from repro.kernels.backend import resolve_backend
 from repro.kernels.banded_sw.kernel import NEG
 from repro.kernels.residual_dp.kernel import (
@@ -88,8 +95,7 @@ def residual_pair_dp(
         def prep(pos):
             s = jnp.clip(jnp.where(pos != INVALID_LOC, pos - dp_pad, 0),
                          0, hi)
-            return ((s // BASES_PER_WORD).astype(jnp.int32),
-                    (s % BASES_PER_WORD).astype(jnp.int32))
+            return s // BASES_PER_WORD, s % BASES_PER_WORD
 
         words = jax.lax.bitcast_convert_type(ref, jnp.int32)
         ref_arr = jnp.concatenate(
@@ -111,12 +117,21 @@ def residual_pair_dp(
 
         def prep(pos):
             s = clamp_window_starts(pos, pos != INVALID_LOC, L, W, dp_pad)
-            return s + (W - dp_pad), jnp.zeros_like(s, jnp.int32)
+            return s + (W - dp_pad), jnp.zeros_like(s)
 
         win_elems = W
 
-    sd1, off1 = prep(pos1)
-    sd2, off2 = prep(pos2)
+    # Line layout (kernels/_util.py), as in candidate_align: the first
+    # covering line, and the window's offset in it (packed: 16 * word
+    # lane + base-in-word).
+    def tables(pos):
+        e, base = prep(pos)
+        off = (e % LANES) * (BASES_PER_WORD if packed_ref else 1) + base
+        return (e // LANES).astype(jnp.int32), off.astype(jnp.int32)
+
+    sd1, off1 = tables(pos1)
+    sd2, off2 = tables(pos2)
+    ref_lines = to_lines(ref_arr, lines_spanned(win_elems))
 
     # ---- single-mate-aware item compaction ------------------------------
     # Slot layout is row-major, mate-minor: slot 2*r + m is (row r, mate
@@ -142,7 +157,7 @@ def residual_pair_dp(
     ins = tuple(pad_rows(x, total) for x in (sd_c, item_reads, off_c))
     parts = [
         residual_dp_pallas(
-            ref_arr, ins[0][s:s + rows],
+            ref_lines, ins[0][s:s + rows],
             jnp.clip(n_items - s, 0, rows).astype(jnp.int32)[None],
             ins[1][s:s + rows], ins[2][s:s + rows],
             dp_pad, band, scoring, packed_ref, win_elems, block,

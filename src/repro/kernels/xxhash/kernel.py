@@ -19,42 +19,51 @@ from repro.core.hashing import PRIME1, PRIME2, PRIME3
 DEFAULT_BLOCK = 1024
 
 
-def _u32(x):
-    return jnp.uint32(x)
+def _i32(x: int):
+    """A uint32 constant as the int32 with the same bits."""
+    x %= 1 << 32
+    return jnp.int32(x - (1 << 32) if x >= 1 << 31 else x)
+
+
+def _shr(x, r: int):
+    return jax.lax.shift_right_logical(x, jnp.int32(r))
 
 
 def _rotl(x, r: int):
-    return (x << _u32(r)) | (x >> _u32(32 - r))
+    return (x << r) | _shr(x, 32 - r)
 
 
 def _round(acc, lane):
-    return _rotl(acc + lane * _u32(PRIME2), 13) * _u32(PRIME1)
+    return _rotl(acc + lane * _i32(PRIME2), 13) * _i32(PRIME1)
 
 
 def xxhash32_lanes(w0, w1, w2, w3, seed: int):
-    """Elementwise xxHash32 of a 16-byte message given as four uint32 lanes.
+    """Elementwise xxHash32 of a 16-byte message given as four int32 lanes
+    (the uint32 words' bits); returns the hash's bits as int32.
 
     The kernel-body hashing unit, shared with the fused pair_frontend
     kernel (which packs seeds and hashes them in-kernel).  All operands
-    broadcast; the result has the broadcast shape.
+    broadcast; the result has the broadcast shape.  The arithmetic is
+    int32 with two's-complement wraparound (the low 32 bits of every
+    add and multiply match uint32's) and logical right shifts, so the
+    kernels that pack seeds stay in int32 end to end.
     """
-    s = _u32(seed)
-    v1 = _round(s + _u32(PRIME1) + _u32(PRIME2), w0)
-    v2 = _round(s + _u32(PRIME2), w1)
-    v3 = _round(s + _u32(0), w2)
-    v4 = _round(s - _u32(PRIME1), w3)
+    v1 = _round(_i32(seed + PRIME1 + PRIME2), w0)
+    v2 = _round(_i32(seed + PRIME2), w1)
+    v3 = _round(_i32(seed), w2)
+    v4 = _round(_i32(seed - PRIME1), w3)
     acc = _rotl(v1, 1) + _rotl(v2, 7) + _rotl(v3, 12) + _rotl(v4, 18)
-    acc = acc + _u32(16)
-    acc = acc ^ (acc >> _u32(15))
-    acc = acc * _u32(PRIME2)
-    acc = acc ^ (acc >> _u32(13))
-    acc = acc * _u32(PRIME3)
-    acc = acc ^ (acc >> _u32(16))
+    acc = acc + 16
+    acc = acc ^ _shr(acc, 15)
+    acc = acc * _i32(PRIME2)
+    acc = acc ^ _shr(acc, 13)
+    acc = acc * _i32(PRIME3)
+    acc = acc ^ _shr(acc, 16)
     return acc
 
 
 def _xxhash_kernel(words_ref, out_ref, *, seed: int):
-    w = words_ref[...]  # (BLK, 4) uint32
+    w = words_ref[...]  # (BLK, 4) int32: the uint32 words' bits
     acc = xxhash32_lanes(w[:, 0], w[:, 1], w[:, 2], w[:, 3], seed)
     out_ref[...] = acc[:, None]
 
@@ -66,7 +75,7 @@ def xxhash32_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """(N, 4) uint32 -> (N,) uint32.  N must be a multiple of `block`
-    (ops.py pads)."""
+    (ops.py pads).  The kernel sees the words' bits as int32."""
     n = words.shape[0]
     assert n % block == 0, (n, block)
     grid = (n // block,)
@@ -75,7 +84,7 @@ def xxhash32_pallas(
         grid=grid,
         in_specs=[pl.BlockSpec((block, 4), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         interpret=interpret,
-    )(words)
-    return out[:, 0]
+    )(jax.lax.bitcast_convert_type(words, jnp.int32))
+    return jax.lax.bitcast_convert_type(out[:, 0], jnp.uint32)

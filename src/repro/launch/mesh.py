@@ -13,24 +13,11 @@ from __future__ import annotations
 import jax
 
 
-def auto_axis_kwargs(n_axes: int) -> dict:
-    """`axis_types` kwargs for `jax.make_mesh`, across jax versions.
-
-    Newer jax exposes `jax.sharding.AxisType` and wants every mesh axis
-    tagged (we use Auto everywhere); older releases predate the enum and
-    default to auto semantics, so the kwarg is simply omitted.  Single
-    version guard for all mesh construction sites.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_auto_mesh(shape, axis_names) -> jax.sharding.Mesh:
     """`jax.make_mesh` with every axis in Auto sharding mode."""
-    return jax.make_mesh(shape, axis_names,
-                         **auto_axis_kwargs(len(axis_names)))
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

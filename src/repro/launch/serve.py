@@ -48,6 +48,7 @@ from repro.core import (
     map_pairs_impl, random_reference, simulate_long_reads, simulate_pairs,
     stage_stats,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.core.seedmap import INVALID_LOC
 from repro.data.pipeline import ReadStreamConfig, read_pairs_for_step
 from repro.engine import ExecutionConfig, LongReadConfig, Mapper
@@ -316,14 +317,12 @@ def serve_frontdoor(ref_len: int = 500_000, batch: int = 256,
     Synthesizes a request trace the paper's target traffic looks like —
     ragged sizes (1..batch read pairs or long reads per request), the
     short-read and long-read lanes interleaved — and drives it through
-    `engine.frontdoor.FrontDoor` on one `Mapper` session: coalescing
-    into fixed-shape device batches, admission control, per-request
-    latency ledger, starvation-free two-lane scheduling.  The output
-    JSON reports throughput per lane next to the queue-latency
+    `engine.frontdoor.FrontDoor` on one `Mapper` session (`frontdoor_trace`):
+    coalescing into fixed-shape device batches, admission control,
+    per-request latency ledger, starvation-free two-lane scheduling.  The
+    output JSON reports throughput per lane next to the queue-latency
     percentiles and the shed/reject accounting.
     """
-    from repro.engine import FrontDoor, FrontDoorConfig
-
     rng = np.random.default_rng(seed)
     t0 = time.time()
     ref = random_reference(ref_len, rng)
@@ -336,24 +335,54 @@ def serve_frontdoor(ref_len: int = 500_000, batch: int = 256,
         t_index = time.time() - t0
         mapper = Mapper.from_index(sm, ref, pipe_cfg,
                                    ExecutionConfig(stream_batch=batch))
+    out = {"loop": "frontdoor", "index_build_s": t_index,
+           **frontdoor_trace(mapper, ref, batch, batches, sub_rate=sub_rate,
+                             long_sub_rate=long_sub_rate, read_len=read_len,
+                             long_frac=long_frac,
+                             max_queue_rows=max_queue_rows,
+                             deadline_s=deadline_s, rng=rng, seed=seed)}
+    if verbose:
+        print(json.dumps(out, indent=1), flush=True)
+    return out
 
+
+def frontdoor_trace(mapper: Mapper, ref, batch: int, batches: int, *,
+                    sub_rate: float = 1e-3, long_sub_rate: float = 0.01,
+                    read_len: int = 2000, long_frac: float = 0.2,
+                    max_queue_rows: int | None = None,
+                    deadline_s: float | None = None, rng=None,
+                    seed: int = 0) -> dict:
+    """Serve one bursty ragged two-lane trace through a `FrontDoor` on an
+    existing session: ``batch * batches`` read pairs plus ``long_frac``
+    as many ``read_len`` long reads, in requests of 1..``batch`` rows.
+    Returns throughput, the serving ledger (``requests`` counts the
+    arrivals) and the per-lane stage totals.
+    """
+    from repro.engine import FrontDoor, FrontDoorConfig
+
+    if rng is None:
+        rng = np.random.default_rng(seed)
+    read_len_pairs = mapper.pipe_cfg.read_len
     # Request pools are simulated up front so arrivals pay no host-side
     # generation inside the latency-stamped serve window.
     n_pair_rows = batch * batches
     sim = simulate_pairs(
         ref, n_pair_rows,
-        ReadSimConfig(read_len=pipe_cfg.read_len, sub_rate=sub_rate),
+        ReadSimConfig(read_len=read_len_pairs, sub_rate=sub_rate),
         seed=seed)
     n_long_rows = int(round(n_pair_rows * long_frac)) if long_frac > 0 else 0
     if n_long_rows:
         long_reads, _ = simulate_long_reads(ref, n_long_rows, read_len,
                                             long_sub_rate, seed=seed + 1)
+    n_requests = 0
 
     def arrivals():
         """Ragged bursty trace: mixed small/large requests, lanes
         interleaved, until both pools are spent."""
+        nonlocal n_requests
         pair_off = long_off = 0
         while pair_off < n_pair_rows or long_off < n_long_rows:
+            n_requests += 1
             go_long = (long_off < n_long_rows
                        and (pair_off >= n_pair_rows
                             or rng.random() < long_frac))
@@ -383,22 +412,18 @@ def serve_frontdoor(ref_len: int = 500_000, batch: int = 256,
 
     pair_rows = report["stage_totals"]["pairs"]["n_pairs"]
     long_rows = report["stage_totals"].get("long", {}).get("n_reads", 0)
-    out = {
-        "loop": "frontdoor",
-        "index_build_s": t_index,
+    return {
         "seconds": seconds,
         "pairs": pair_rows,
         "long_reads": long_rows,
         "pairs_per_s": pair_rows / max(seconds, 1e-9),
-        "mbp_per_s": (pair_rows * 2 * pipe_cfg.read_len
+        "mbp_per_s": (pair_rows * 2 * read_len_pairs
                       + long_rows * read_len) / max(seconds, 1e-9) / 1e6,
+        "requests": n_requests,
         **report["serve"],
         "stage_totals": report["stage_totals"],
         "watchdog": report["watchdog"],
     }
-    if verbose:
-        print(json.dumps(out, indent=1), flush=True)
-    return out
 
 
 def _serve_legacy(ref, sm, stream, sim_cfg, batch, batches, pipe_cfg,
@@ -607,6 +632,7 @@ def main():
                     help="write the --chaos health ledger JSON here "
                          "(the CI fleet artifact)")
     args = ap.parse_args()
+    enable_compile_cache()
     # The shared flag must not clobber per-workload defaults: short pairs
     # default 1e-3, the long lane the PacBio-like 0.01.
     sub_rate = args.sub_rate

@@ -72,7 +72,7 @@ FAMILIES = ("pair_frontend", "candidate_align", "residual_dp",
 #: Launch-block grids per family (the hand-picked default is always a
 #: candidate; see each family's kernel.py DEFAULT_BLOCK).
 BLOCK_GRID = {
-    "pair_frontend": (4, 8, 16, 32),
+    "pair_frontend": (8, 16, 32),
     "candidate_align": (8, 16, 32),
     "residual_dp": (16, 32, 64),
     "location_vote": (32, 64, 128),
@@ -306,13 +306,24 @@ def _time_candidates(cands: list[tuple[str, dict, object]],
                      reps: int = 3) -> dict:
     """Counterbalanced timing: warm every candidate (compile), then time
     them round-robin so drift hits all candidates alike.  Returns
-    label -> median us.  Candidates that fail to run are dropped."""
+    label -> median us.
+
+    Off the chip a candidate that fails to run is dropped with a warning.
+    On a TPU a failing kernel candidate raises: dropping it would let the
+    staged jnp oracle "win" the tune and the cached backend winner would
+    silently keep every later session off the kernels.
+    """
     live = []
     for label, params, fn in cands:
         try:
             jax.block_until_ready(fn())
             live.append((label, params, fn, []))
         except Exception as e:  # noqa: BLE001 — a bad config is a skip
+            if (jax.default_backend() == "tpu"
+                    and params.get("backend") != "jnp"):
+                raise RuntimeError(
+                    f"tune candidate {label!r} failed on the TPU; a kernel "
+                    "the chip refuses is a bug, not a slower config") from e
             warnings.warn(f"tune candidate {label!r} failed: {e!r}",
                           stacklevel=2)
     for _ in range(reps):
@@ -535,6 +546,8 @@ def main(argv=None) -> None:
                     help=f"cache file (default {DEFAULT_CACHE}; "
                          f"${ENV_CACHE} honored)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     ref = random_reference(args.ref_len, rng)

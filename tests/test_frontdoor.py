@@ -268,6 +268,8 @@ def test_serve_cli_sub_rate_defaults(monkeypatch):
 
     monkeypatch.setattr(serve_mod, "serve_long", fake_long)
     monkeypatch.setattr(serve_mod, "serve", fake_pairs)
+    # The CLI's persistent-cache placement would outlive this test.
+    monkeypatch.setattr(serve_mod, "enable_compile_cache", lambda: None)
 
     monkeypatch.setattr("sys.argv", ["serve", "--workload", "long"])
     serve_mod.main()

@@ -229,3 +229,37 @@ def test_tune_session_winners_never_lose_to_staged(world, tmp_path):
             key, e)
     # and the written cache is immediately consumable
     assert load_cache(tmp_path / "tc.json") == entries
+
+
+# ------------------------------------------- no fallback on the chip ---
+def _failing():
+    raise RuntimeError("Mosaic refused the kernel")
+
+
+def test_failed_kernel_candidate_raises_on_tpu(monkeypatch):
+    """On a TPU a Pallas candidate that fails to compile is a bug: the
+    tuner raises instead of letting the staged oracle win by default."""
+    import jax
+
+    from repro import tune
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="failed on the TPU"):
+        tune._time_candidates([("staged", {"backend": "jnp"}, lambda: 0),
+                               ("block8", {"block": 8}, _failing)], reps=1)
+    # A failing staged-oracle candidate is still just dropped.
+    with pytest.warns(UserWarning, match="staged"):
+        timed = tune._time_candidates(
+            [("staged", {"backend": "jnp"}, _failing),
+             ("block8", {"block": 8}, lambda: 0)], reps=1)
+    assert list(timed) == ["block8"]
+
+
+def test_failed_candidate_is_dropped_off_the_chip():
+    from repro import tune
+
+    with pytest.warns(UserWarning, match="block8"):
+        timed = tune._time_candidates(
+            [("staged", {"backend": "jnp"}, lambda: 0),
+             ("block8", {"block": 8}, _failing)], reps=1)
+    assert list(timed) == ["staged"]
