@@ -1,0 +1,14 @@
+"""Share of the traced window in which the `location_vote` kernels ran: the
+summed device time of the operations the Pallas calls of `location_vote`
+compile to, over the window."""
+
+PATTERN = r"location_vote(\.\d+)?"
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    t = run.trace.op_seconds(PATTERN)
+    if t <= 0:
+        return None
+    return 100.0 * t / run.trace.window_s
