@@ -191,72 +191,79 @@ def map_long_impl(
     kernel-layout `PaddedSeedMap`.
     """
     p = cfg.pipe
-    segs, n_seg = _segments(reads, cfg)           # (B, S, R)
-    B, S, R = segs.shape
     delta = cfg.pair_delta()
+    # Stages run under `jax.named_scope`s (`lr.frontend`, `lr.vote`,
+    # `lr.anchor_dp`, `assemble`), as in `core.pipeline.map_pairs_impl`.
+    with jax.named_scope("lr.frontend"):
+        segs, n_seg = _segments(reads, cfg)           # (B, S, R)
+        B, S, R = segs.shape
 
-    # -- front end: segments through the pseudo-pair pipeline -------------
-    # Imported at call time for the same core-package circularity reason
-    # as the short-read pipeline's kernel imports.
-    from repro.kernels.pair_frontend.ops import segment_pair_frontend
+        # -- front end: segments through the pseudo-pair pipeline ---------
+        # Imported at call time for the same core-package circularity reason
+        # as the short-read pipeline's kernel imports.
+        from repro.kernels.pair_frontend.ops import segment_pair_frontend
 
-    fe_backend = resolve_backend(p.frontend_backend, family="pair_frontend")
-    if isinstance(sm, SeedMap) and fe_backend == "jnp":
-        # Staged oracle: seed and query every segment ONCE (B*S flat),
-        # then pair adjacent segments' sorted start lists for the Δ
-        # filter — mathematically identical to running `pair_frontend`
-        # over the S-1 pseudo-pairs, without re-seeding shared segments.
-        flat = segs.reshape(B * S, R)
-        seeds = seed_read_batch(flat, p.seed_len, p.seeds_per_read,
-                                sm.config.hash_seed)
-        q = query_read_batch(sm, seeds, p.max_locs_per_seed)
-        starts = q.starts.reshape(B, S, -1)
-        hits = q.n_hits.reshape(B, S)
-        q1 = QueryResult(starts=starts[:, :-1].reshape(B * (S - 1), -1),
-                         n_hits=hits[:, :-1].reshape(-1))
-        q2 = QueryResult(starts=starts[:, 1:].reshape(B * (S - 1), -1),
-                         n_hits=hits[:, 1:].reshape(-1))
-        cands = paired_adjacency_filter(q1, q2, delta, p.max_candidates)
-        pos1, n_cand = cands.pos1, cands.n
-    else:
-        rows = (sm if isinstance(sm, LinedSeedMap)
-                else sm.rows if isinstance(sm, PaddedSeedMap)
-                else padded_rows_device(sm, p.max_locs_per_seed))
-        fe = segment_pair_frontend(
-            rows, reads, cfg.segment_len, cfg.segment_stride, p.seed_len,
-            p.seeds_per_read, sm.config.hash_seed, delta, p.max_candidates,
-            block=p.frontend_block, backend=fe_backend)
-        pos1, n_cand = fe.pos1, fe.n
+        fe_backend = resolve_backend(p.frontend_backend,
+                                     family="pair_frontend")
+        if isinstance(sm, SeedMap) and fe_backend == "jnp":
+            # Staged oracle: seed and query every segment ONCE (B*S flat),
+            # then pair adjacent segments' sorted start lists for the Δ
+            # filter — mathematically identical to running `pair_frontend`
+            # over the S-1 pseudo-pairs, without re-seeding shared segments.
+            flat = segs.reshape(B * S, R)
+            seeds = seed_read_batch(flat, p.seed_len, p.seeds_per_read,
+                                    sm.config.hash_seed)
+            q = query_read_batch(sm, seeds, p.max_locs_per_seed)
+            starts = q.starts.reshape(B, S, -1)
+            hits = q.n_hits.reshape(B, S)
+            q1 = QueryResult(starts=starts[:, :-1].reshape(B * (S - 1), -1),
+                             n_hits=hits[:, :-1].reshape(-1))
+            q2 = QueryResult(starts=starts[:, 1:].reshape(B * (S - 1), -1),
+                             n_hits=hits[:, 1:].reshape(-1))
+            cands = paired_adjacency_filter(q1, q2, delta, p.max_candidates)
+            pos1, n_cand = cands.pos1, cands.n
+        else:
+            rows = (sm if isinstance(sm, LinedSeedMap)
+                    else sm.rows if isinstance(sm, PaddedSeedMap)
+                    else padded_rows_device(sm, p.max_locs_per_seed))
+            fe = segment_pair_frontend(
+                rows, reads, cfg.segment_len, cfg.segment_stride, p.seed_len,
+                p.seeds_per_read, sm.config.hash_seed, delta, p.max_candidates,
+                block=p.frontend_block, backend=fe_backend)
+            pos1, n_cand = fe.pos1, fe.n
 
-    # -- Location Voting (fused reduction) ---------------------------------
-    from repro.kernels.location_vote.ops import location_vote
+    with jax.named_scope("lr.vote"):
+        # -- Location Voting (fused reduction) -----------------------------
+        from repro.kernels.location_vote.ops import location_vote
 
-    diag = candidate_diagonals(pos1, S - 1, cfg.segment_stride)
-    vote = location_vote(diag, cfg.vote_bin, block=cfg.vote_block,
-                         backend=cfg.vote_backend)
-    votes = vote.votes
-    mapped = votes > 0
-    position = vote.win_bin * cfg.vote_bin
+        diag = candidate_diagonals(pos1, S - 1, cfg.segment_stride)
+        vote = location_vote(diag, cfg.vote_bin, block=cfg.vote_block,
+                             backend=cfg.vote_backend)
+        votes = vote.votes
+        mapped = votes > 0
+        position = vote.win_bin * cfg.vote_bin
 
-    # -- banded DP of the anchor segment at the voted diagonal -------------
-    win = _anchor_windows(ref, position, mapped, cfg)
-    band = cfg.band()
-    dp_backend = resolve_backend(p.residual_backend, family="banded_sw")
-    if dp_backend == "jnp":
-        dp = gotoh_semiglobal_banded(segs[:, 0], win, band, p.scoring)
-    else:
-        from repro.kernels.banded_sw.ops import banded_sw
-        dp = banded_sw(segs[:, 0], win, scoring=p.scoring, band=band,
-                       backend=dp_backend)
+    with jax.named_scope("lr.anchor_dp"):
+        # -- banded DP of the anchor segment at the voted diagonal ---------
+        win = _anchor_windows(ref, position, mapped, cfg)
+        band = cfg.band()
+        dp_backend = resolve_backend(p.residual_backend, family="banded_sw")
+        if dp_backend == "jnp":
+            dp = gotoh_semiglobal_banded(segs[:, 0], win, band, p.scoring)
+        else:
+            from repro.kernels.banded_sw.ops import banded_sw
+            dp = banded_sw(segs[:, 0], win, scoring=p.scoring, band=band,
+                           backend=dp_backend)
 
-    return LongReadResult(
-        position=jnp.where(mapped, position, INVALID_LOC),
-        votes=votes,
-        score=jnp.where(mapped, dp.score, NEG),
-        mapped=mapped,
-        n_candidates=n_cand.reshape(B, S - 1).sum(-1).astype(jnp.int32),
-        n_valid=jnp.ones((B,), bool),
-    )
+    with jax.named_scope("assemble"):
+        return LongReadResult(
+            position=jnp.where(mapped, position, INVALID_LOC),
+            votes=votes,
+            score=jnp.where(mapped, dp.score, NEG),
+            mapped=mapped,
+            n_candidates=n_cand.reshape(B, S - 1).sum(-1).astype(jnp.int32),
+            n_valid=jnp.ones((B,), bool),
+        )
 
 
 def long_stage_stat_counts(res: LongReadResult) -> dict:

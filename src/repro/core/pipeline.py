@@ -372,86 +372,94 @@ def map_pairs_impl(
     """
     B, R = reads1.shape
     assert R == cfg.read_len, (R, cfg.read_len)
-    reads2_fwd = (3 - reads2)[:, ::-1]  # reference orientation (revcomp)
+    # Each stage runs under a `jax.named_scope` (`frontend`,
+    # `light_align`, `residual_dp`, `assemble`), so the device ops it
+    # compiles to carry the stage in their op_name metadata, which a
+    # profile reports per op (docs/ENGINE.md, "Tracing").
+    with jax.named_scope("frontend"):
+        reads2_fwd = (3 - reads2)[:, ::-1]  # reference orientation (revcomp)
 
-    # -- 1-3. Front end: seeding + SeedMap query + adjacency filter -------
-    # One fused `pair_frontend` op (kernel backends: the (B, S, K)
-    # location tensor and the (B, S*K) sorted start lists stay in VMEM).
-    # The staged core modules remain the bit-exact jnp path.  Imported at
-    # call time for the same core-package circularity reason as the
-    # candidate_align import below.
-    from repro.kernels.pair_frontend.ops import pair_frontend
+        # -- 1-3. Front end: seeding + SeedMap query + adjacency filter ---
+        # One fused `pair_frontend` op (kernel backends: the (B, S, K)
+        # location tensor and the (B, S*K) sorted start lists stay in VMEM).
+        # The staged core modules remain the bit-exact jnp path.  Imported at
+        # call time for the same core-package circularity reason as the
+        # candidate_align import below.
+        from repro.kernels.pair_frontend.ops import pair_frontend
 
-    fe_backend = resolve_backend(cfg.frontend_backend,
-                                 family="pair_frontend")
-    if isinstance(sm, SeedMap) and fe_backend == "jnp":
-        seeds1 = seed_read_batch(reads1, cfg.seed_len, cfg.seeds_per_read,
-                                 sm.config.hash_seed)
-        seeds2 = seed_read_batch(reads2_fwd, cfg.seed_len,
-                                 cfg.seeds_per_read, sm.config.hash_seed)
-        q1 = query_read_batch(sm, seeds1, cfg.max_locs_per_seed)
-        q2 = query_read_batch(sm, seeds2, cfg.max_locs_per_seed)
-        had_hits = (q1.n_hits > 0) & (q2.n_hits > 0)
-        cands: CandidateSet = paired_adjacency_filter(
-            q1, q2, cfg.delta, cfg.max_candidates
+        fe_backend = resolve_backend(cfg.frontend_backend,
+                                     family="pair_frontend")
+        if isinstance(sm, SeedMap) and fe_backend == "jnp":
+            seeds1 = seed_read_batch(reads1, cfg.seed_len, cfg.seeds_per_read,
+                                     sm.config.hash_seed)
+            seeds2 = seed_read_batch(reads2_fwd, cfg.seed_len,
+                                     cfg.seeds_per_read, sm.config.hash_seed)
+            q1 = query_read_batch(sm, seeds1, cfg.max_locs_per_seed)
+            q2 = query_read_batch(sm, seeds2, cfg.max_locs_per_seed)
+            had_hits = (q1.n_hits > 0) & (q2.n_hits > 0)
+            cands: CandidateSet = paired_adjacency_filter(
+                q1, q2, cfg.delta, cfg.max_candidates
+            )
+        else:
+            rows = (sm if isinstance(sm, LinedSeedMap)
+                    else sm.rows if isinstance(sm, PaddedSeedMap)
+                    else padded_rows_device(sm, cfg.max_locs_per_seed))
+            fe = pair_frontend(
+                rows, reads1, reads2_fwd, cfg.seed_len, cfg.seeds_per_read,
+                sm.config.hash_seed, cfg.delta, cfg.max_candidates,
+                block=cfg.frontend_block, backend=fe_backend)
+            had_hits = (fe.n_hits1 > 0) & (fe.n_hits2 > 0)
+            cands = CandidateSet(pos1=fe.pos1, pos2=fe.pos2, n=fe.n)
+        passed = cands.n > 0
+
+    with jax.named_scope("light_align"):
+        # -- 4. Light Alignment over candidates (fused kernel) -----------
+        # With packed_ref both the candidate windows and the DP fallback
+        # windows gather from the 2-bit packed reference (4x less HBM window
+        # traffic, the serve step's flavor).  Callers that already hold the
+        # packed words (uint32) should pass them directly — packing a uint8
+        # ref in here costs a full reference read per jitted call, which at
+        # genome scale dwarfs the window-DMA saving.
+        packed = cfg.packed(default=False)
+        ref_words = None
+        if packed:
+            ref_words = ref if ref.dtype == jnp.uint32 else pack_2bit(ref)
+        pair = _best_candidate_light(ref_words if packed else ref,
+                                     reads1, reads2_fwd, cands, cfg, packed)
+        b_pos1, b_pos2 = pair.pos1, pair.pos2
+        b_sc1, b_sc2 = pair.score1, pair.score2
+        light_ok = passed & pair.ok1 & pair.ok2
+        cig1, cig2 = pair.cigar1, pair.cigar2
+
+    with jax.named_scope("residual_dp"):
+        # -- 5. DP fallback on the fixed-capacity residual buffer --------
+        # One fused `residual_dp` op (cfg.residual_backend): compacted window
+        # gather + banded Gotoh of exactly the failed mates.
+        (dp_sc1, dp_sc2, dp_done, dp_overflow, dp_m1,
+         dp_m2) = _residual_dp_stage(
+            ref_words if packed else ref, reads1, reads2_fwd, pair, passed,
+            light_ok, cfg, packed)
+
+    with jax.named_scope("assemble"):
+        # -- assemble -----------------------------------------------------
+        method = jnp.full((B,), M_UNMAPPED, jnp.int32)
+        method = jnp.where(~had_hits, M_RESIDUAL_FULL, method)
+        method = jnp.where(had_hits & ~passed, M_RESIDUAL_FULL, method)
+        method = jnp.where(light_ok, M_LIGHT, method)
+        method = jnp.where(dp_done, M_DP, method)
+        method = jnp.where(dp_overflow, M_DP_OVERFLOW, method)
+
+        mapped = light_ok | dp_done
+        pos1 = jnp.where(mapped, b_pos1, INVALID_LOC)
+        pos2 = jnp.where(mapped, b_pos2, INVALID_LOC)
+        score1 = jnp.where(light_ok, b_sc1, jnp.where(dp_done, dp_sc1, NEG))
+        score2 = jnp.where(light_ok, b_sc2, jnp.where(dp_done, dp_sc2, NEG))
+        return MapResult(
+            pos1=pos1, pos2=pos2, score1=score1, score2=score2,
+            method=method, cigar1=cig1, cigar2=cig2, had_hits=had_hits,
+            passed_adjacency=passed, light_ok=light_ok, dp_mate1=dp_m1,
+            dp_mate2=dp_m2, n_valid=jnp.ones((B,), bool),
         )
-    else:
-        rows = (sm if isinstance(sm, LinedSeedMap)
-                else sm.rows if isinstance(sm, PaddedSeedMap)
-                else padded_rows_device(sm, cfg.max_locs_per_seed))
-        fe = pair_frontend(
-            rows, reads1, reads2_fwd, cfg.seed_len, cfg.seeds_per_read,
-            sm.config.hash_seed, cfg.delta, cfg.max_candidates,
-            block=cfg.frontend_block, backend=fe_backend)
-        had_hits = (fe.n_hits1 > 0) & (fe.n_hits2 > 0)
-        cands = CandidateSet(pos1=fe.pos1, pos2=fe.pos2, n=fe.n)
-    passed = cands.n > 0
-
-    # -- 4. Light Alignment over candidates (fused kernel) ---------------
-    # With packed_ref both the candidate windows and the DP fallback
-    # windows gather from the 2-bit packed reference (4x less HBM window
-    # traffic, the serve step's flavor).  Callers that already hold the
-    # packed words (uint32) should pass them directly — packing a uint8
-    # ref in here costs a full reference read per jitted call, which at
-    # genome scale dwarfs the window-DMA saving.
-    packed = cfg.packed(default=False)
-    ref_words = None
-    if packed:
-        ref_words = ref if ref.dtype == jnp.uint32 else pack_2bit(ref)
-    pair = _best_candidate_light(ref_words if packed else ref,
-                                 reads1, reads2_fwd, cands, cfg, packed)
-    b_pos1, b_pos2 = pair.pos1, pair.pos2
-    b_sc1, b_sc2 = pair.score1, pair.score2
-    light_ok = passed & pair.ok1 & pair.ok2
-    cig1, cig2 = pair.cigar1, pair.cigar2
-
-    # -- 5. DP fallback on the fixed-capacity residual buffer ------------
-    # One fused `residual_dp` op (cfg.residual_backend): compacted window
-    # gather + banded Gotoh of exactly the failed mates.
-    dp_sc1, dp_sc2, dp_done, dp_overflow, dp_m1, dp_m2 = _residual_dp_stage(
-        ref_words if packed else ref, reads1, reads2_fwd, pair, passed,
-        light_ok, cfg, packed)
-
-    # -- assemble ---------------------------------------------------------
-    method = jnp.full((B,), M_UNMAPPED, jnp.int32)
-    method = jnp.where(~had_hits, M_RESIDUAL_FULL, method)
-    method = jnp.where(had_hits & ~passed, M_RESIDUAL_FULL, method)
-    method = jnp.where(light_ok, M_LIGHT, method)
-    method = jnp.where(dp_done, M_DP, method)
-    method = jnp.where(dp_overflow, M_DP_OVERFLOW, method)
-
-    mapped = light_ok | dp_done
-    pos1 = jnp.where(mapped, b_pos1, INVALID_LOC)
-    pos2 = jnp.where(mapped, b_pos2, INVALID_LOC)
-    score1 = jnp.where(light_ok, b_sc1, jnp.where(dp_done, dp_sc1, NEG))
-    score2 = jnp.where(light_ok, b_sc2, jnp.where(dp_done, dp_sc2, NEG))
-
-    return MapResult(
-        pos1=pos1, pos2=pos2, score1=score1, score2=score2, method=method,
-        cigar1=cig1, cigar2=cig2, had_hits=had_hits, passed_adjacency=passed,
-        light_ok=light_ok, dp_mate1=dp_m1, dp_mate2=dp_m2,
-        n_valid=jnp.ones((B,), bool),
-    )
 
 
 _jitted_map_pairs = jax.jit(map_pairs_impl, static_argnames=("cfg",))

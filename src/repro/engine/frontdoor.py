@@ -18,7 +18,9 @@ traffic into the stream the device wants:
   * **latency ledger** — every request is stamped at enqueue, dispatch
     and result; `engine.stats.ServeStats` aggregates the decomposition
     (queue wait / service / total, p50 + p99) next to the device-side
-    stage totals;
+    stage totals, and the host work of each batch opens the spans
+    ``door.form_batch``, ``door.dispatch`` and ``door.retire``
+    (`engine.spans`);
   * **admission control** — the queue is bounded (``max_queue_rows``):
     arrivals past the bound are *rejected*; requests whose deadline
     passes while queued are *expired* at dispatch time instead of wasting
@@ -68,6 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine.mapper import _DONATE_MSG, Mapper
+from repro.engine.spans import span
 from repro.engine.stats import ServeStats, fetch_stage_totals, \
     init_stage_totals
 from repro.engine.stream import pad_tail
@@ -290,7 +293,7 @@ class FrontDoor:
         target = self._target(lane)
         q = self._queues[lane]
         picked, rows = [], 0
-        with self._lock:
+        with span("door.form_batch"), self._lock:
             while q and rows < target:
                 req = q[0]
                 if req.deadline is not None and now > req.deadline:
@@ -309,20 +312,22 @@ class FrontDoor:
 
     def _dispatch(self, lane: str, picked: list, rows: int) -> None:
         B = self.stream_batch
-        reads = tuple(
-            pad_tail(np.concatenate([r.reads[i] for r in picked], axis=0), B)
-            for i in range(self._n_arrays[lane]))
-        t = time.time()
-        for r in picked:
-            r.status = DISPATCHED
-            r.t_dispatch = t
-        with warnings.catch_warnings():
-            # donated read buffers have no size-matching output on CPU
-            warnings.filterwarnings("ignore", message=_DONATE_MSG,
-                                    category=UserWarning)
-            res, self._carries[lane] = self._steps[lane](
-                self.mapper._state, self._carries[lane], *reads,
-                jnp.int32(rows), ())
+        with span("door.dispatch"):
+            reads = tuple(
+                pad_tail(np.concatenate([r.reads[i] for r in picked],
+                                        axis=0), B)
+                for i in range(self._n_arrays[lane]))
+            t = time.time()
+            for r in picked:
+                r.status = DISPATCHED
+                r.t_dispatch = t
+            with warnings.catch_warnings():
+                # donated read buffers have no size-matching output on CPU
+                warnings.filterwarnings("ignore", message=_DONATE_MSG,
+                                        category=UserWarning)
+                res, self._carries[lane] = self._steps[lane](
+                    self.mapper._state, self._carries[lane], *reads,
+                    jnp.int32(rows), ())
         spans, lo = [], 0
         for r in picked:
             spans.append((r, lo, lo + r.n))
@@ -338,20 +343,21 @@ class FrontDoor:
         if entry is None:
             return
         lane, res, spans, t_dispatch = entry
-        jax.block_until_ready(res)
-        t = time.time()
-        if self._watchdogs[lane].observe(t - t_dispatch) == EVICT:
-            # persistent straggler: degrading didn't help — stop taking
-            # traffic and drain what was accepted
-            self.stats.mark_drain("watchdog-evict")
-            self._guard.request()
-        for req, lo, hi in spans:
-            req.result = jax.tree.map(lambda a: a[lo:hi], res)
-            req.status = DONE
-            req.t_result = t
-            self.stats.observe_request(
-                rows=req.n, t_enqueue=req.t_enqueue,
-                t_dispatch=req.t_dispatch, t_result=t)
+        with span("door.retire"):
+            jax.block_until_ready(res)
+            t = time.time()
+            if self._watchdogs[lane].observe(t - t_dispatch) == EVICT:
+                # persistent straggler: degrading didn't help — stop
+                # taking traffic and drain what was accepted
+                self.stats.mark_drain("watchdog-evict")
+                self._guard.request()
+            for req, lo, hi in spans:
+                req.result = jax.tree.map(lambda a: a[lo:hi], res)
+                req.status = DONE
+                req.t_result = t
+                self.stats.observe_request(
+                    rows=req.n, t_enqueue=req.t_enqueue,
+                    t_dispatch=req.t_dispatch, t_result=t)
 
     # ------------------------------------------------------ serve loops --
     def dispatch_ready(self) -> int:

@@ -54,6 +54,7 @@ from repro.engine.config import (
     resolved_pipeline,
 )
 from repro.engine import plan
+from repro.engine.spans import note_trace, span
 from repro.engine.stats import (
     LONG_STAT_KEYS,
     STAT_KEYS,
@@ -87,7 +88,8 @@ def _place_state(index, ref_arr, cfg: PipelineConfig, mesh) -> tuple:
     if isinstance(index, PaddedSeedMap) and cfg.frontend_backend != "jnp":
         index = to_lined(index)
     where = NamedSharding(mesh, P()) if mesh is not None else None
-    return jax.device_put((index, ref_arr), where)
+    with span("session.place"):
+        return jax.device_put((index, ref_arr), where)
 
 
 class Mapper:
@@ -269,27 +271,29 @@ class Mapper:
         lane config.
         """
         from repro.engine.index_store import IndexStoreError, load_store
-        payload = load_store(path)
-        if payload is None:
-            if fallback_ref is None:
-                raise IndexStoreError(
-                    f"index store {os.fspath(path)!r} is unreadable and "
-                    "no fallback_ref was provided to rebuild from")
-            warnings.warn(
-                f"index store {os.fspath(path)!r} unreadable; rebuilding "
-                "the session from the reference", stacklevel=2)
-            return cls.build(fallback_ref, seedmap_cfg, pipe_cfg, exec_cfg)
-        exec_cfg = exec_cfg or ExecutionConfig()
-        if exec_cfg.tune is None:
-            exec_cfg = dataclasses.replace(exec_cfg, tune=False)
-        if exec_cfg.long_read is None and payload.lr_cfg is not None \
-                and not exec_cfg.shard_index:
-            exec_cfg = dataclasses.replace(exec_cfg,
-                                           long_read=payload.lr_cfg)
-        mapper = cls.from_index(payload.index, payload.ref,
-                                payload.pipe_cfg, exec_cfg)
-        mapper._tune_entries = dict(payload.tune_entries)
-        return mapper
+        with span("session.load"):
+            with span("session.store_read"):
+                payload = load_store(path)
+            if payload is None:
+                if fallback_ref is None:
+                    raise IndexStoreError(
+                        f"index store {os.fspath(path)!r} is unreadable and "
+                        "no fallback_ref was provided to rebuild from")
+                warnings.warn(
+                    f"index store {os.fspath(path)!r} unreadable; rebuilding "
+                    "the session from the reference", stacklevel=2)
+                return cls.build(fallback_ref, seedmap_cfg, pipe_cfg, exec_cfg)
+            exec_cfg = exec_cfg or ExecutionConfig()
+            if exec_cfg.tune is None:
+                exec_cfg = dataclasses.replace(exec_cfg, tune=False)
+            if exec_cfg.long_read is None and payload.lr_cfg is not None \
+                    and not exec_cfg.shard_index:
+                exec_cfg = dataclasses.replace(exec_cfg,
+                                               long_read=payload.lr_cfg)
+            mapper = cls.from_index(payload.index, payload.ref,
+                                    payload.pipe_cfg, exec_cfg)
+            mapper._tune_entries = dict(payload.tune_entries)
+            return mapper
 
     def swap_index(self, store, *, strict: bool = False) -> str:
         """Hot-swap the device-resident index from a saved store.
@@ -414,20 +418,29 @@ class Mapper:
         factory like `launch.serve._make_accuracy_reduce`, not a fresh
         closure per call) reuses the jitted step; distinct callables
         evict the least recently used entry past `_FUSED_CACHE_MAX`.
+        Each trace of the body counts once under ``fused.<lane>[.<reduce
+        fn>]`` in `engine.spans`' trace counts, so a recompiling step
+        shows there.
         """
         raw_attr, counts_fn, keys, n_arrays = self._LANES[lane]
         raw = getattr(self, raw_attr)
         mesh = self.exec_cfg.mesh
+        trace_key = f"fused.{lane}" + (
+            "" if reduce_fn is None
+            else f".{getattr(reduce_fn, '__qualname__', 'reduce')}")
 
         def build():
             def fused(state, carry, *rest):
+                note_trace(trace_key)
                 *reads, n, aux = rest
                 res = raw(*state, *reads, n)
                 totals, red = carry
-                counts = counts_fn(res)
-                totals = {k: totals[k] + counts[k] for k in keys}
+                with jax.named_scope("stage_stats"):
+                    counts = counts_fn(res)
+                    totals = {k: totals[k] + counts[k] for k in keys}
                 if reduce_fn is not None:
-                    red = reduce_fn(red, res, aux)
+                    with jax.named_scope("reduce"):
+                        red = reduce_fn(red, res, aux)
                 return res, (totals, red)
 
             donate = (1,) + (tuple(range(2, 2 + n_arrays))
@@ -475,10 +488,11 @@ class Mapper:
                 # Throwaway carry: a deep copy, because the step donates
                 # its carry buffers and the real loop reuses reduce_init.
                 scrap_carry = jax.tree.map(jnp.copy, carry)
-                _, scrap = step(self._state, scrap_carry,
-                                *(pad_tail(r, nb) for r in reads),
-                                jnp.int32(nb), wa)
-                jax.block_until_ready(scrap)
+                with span("stream.warmup"):
+                    _, scrap = step(self._state, scrap_carry,
+                                    *(pad_tail(r, nb) for r in reads),
+                                    jnp.int32(nb), wa)
+                    jax.block_until_ready(scrap)
 
             def dispatch(*args):
                 nonlocal carry

@@ -42,10 +42,11 @@ def _mask_tail(res, n: jnp.ndarray):
     not a global prefix (`engine.multihost`).  The rank check is static
     at trace time: the two flavors compile to distinct steps.
     """
-    if getattr(n, "ndim", 0) == 1:
-        return res._replace(n_valid=n.astype(bool))
-    B = res.n_valid.shape[0]
-    return res._replace(n_valid=jnp.arange(B, dtype=jnp.int32) < n)
+    with jax.named_scope("assemble"):
+        if getattr(n, "ndim", 0) == 1:
+            return res._replace(n_valid=n.astype(bool))
+        B = res.n_valid.shape[0]
+        return res._replace(n_valid=jnp.arange(B, dtype=jnp.int32) < n)
 
 
 def raw_pipeline_step(cfg: PipelineConfig):

@@ -24,12 +24,16 @@ jax's async dispatch so the stages pipeline:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 
 import jax
 import numpy as np
 
+from repro.engine.spans import span
 from repro.engine.stats import stage_fractions
+
+_END = object()   # end-of-iterator sentinel for `run_stream`
 
 
 @dataclasses.dataclass
@@ -116,41 +120,54 @@ def run_stream(dispatch, batches, *, stream_batch=None,
     ``n_arrays`` read arrays each; the first batch fixes the stream shape
     unless ``stream_batch`` pins it.  Returns ``(n_items, n_batches,
     seconds, last_result)``; accumulation state lives inside ``dispatch``
-    (the Mapper's fused carry).
+    (the Mapper's fused carry).  Each batch opens the spans
+    ``stream.pull`` (the iterator's ``next``), ``stream.pad``,
+    ``stream.dispatch`` and ``stream.retire`` (``on_result``); the final
+    wait is ``stream.drain`` (`engine.spans`).
     """
     n_items = 0
     n_batches = 0
     prev = None
     res = None
     t0 = None
-    for idx, item in enumerate(batches):
-        reads, aux = split_batch(item, n_arrays)
-        # Shape only — never np.asarray here: a multi-host global array
-        # is not fully addressable, and materializing a device array
-        # just for its row count would force a sync anyway.
-        r0 = reads[0]
-        n = int(r0.shape[0]) if hasattr(r0, "shape") \
-            else int(np.asarray(r0).shape[0])
-        if stream_batch is None:
-            stream_batch = n
-        padded = tuple(pad_tail(r, stream_batch) for r in reads)
-        aux = jax.tree.map(lambda a: pad_tail(a, stream_batch), aux)
+    it = iter(batches)
+    for idx in itertools.count():
+        with span("stream.pull"):
+            item = next(it, _END)
+        if item is _END:
+            break
+        with span("stream.pad"):
+            reads, aux = split_batch(item, n_arrays)
+            # Shape only — never np.asarray here: a multi-host global
+            # array is not fully addressable, and materializing a device
+            # array just for its row count would force a sync anyway.
+            r0 = reads[0]
+            n = int(r0.shape[0]) if hasattr(r0, "shape") \
+                else int(np.asarray(r0).shape[0])
+            if stream_batch is None:
+                stream_batch = n
+            padded = tuple(pad_tail(r, stream_batch) for r in reads)
+            aux = jax.tree.map(lambda a: pad_tail(a, stream_batch), aux)
         # The clock starts at the first *dispatch*: pulling the first
         # batch from the iterator (read simulation / FASTQ decode) and
         # padding it are host-side setup, not stream time.
         if t0 is None:
-            t0 = time.time()
+            t0 = time.perf_counter()
         # Async dispatch: the host returns immediately and moves on to
         # simulate/transfer the next batch while the device works.
-        res = dispatch(*padded, n, aux)
+        with span("stream.dispatch"):
+            res = dispatch(*padded, n, aux)
         n_items += n
         n_batches += 1
         if prev is not None and on_result is not None:
-            on_result(*prev)
+            with span("stream.retire"):
+                on_result(*prev)
         prev = (idx, res, n)
     if prev is not None and on_result is not None:
-        on_result(*prev)
+        with span("stream.retire"):
+            on_result(*prev)
     if res is not None:
-        jax.block_until_ready(res)
-    seconds = 0.0 if t0 is None else time.time() - t0
+        with span("stream.drain"):
+            jax.block_until_ready(res)
+    seconds = 0.0 if t0 is None else time.perf_counter() - t0
     return n_items, n_batches, seconds, res

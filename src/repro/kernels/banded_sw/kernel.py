@@ -38,6 +38,10 @@ from repro.core.dp_fallback import band_center
 from repro.core.scoring import Scoring
 from repro.kernels._util import first_index, prefix_scan
 
+#: Name of every launch of this family: its HLO instruction name
+#: (``banded_sw.N``) and its op name in a device profile.
+NAME = "banded_sw"
+
 DEFAULT_BLOCK = 128
 NEG = -(1 << 20)
 
@@ -230,6 +234,7 @@ def banded_sw_pallas(
         ],
         out_specs=[pl.BlockSpec((block, 1), lambda i: (i, 0))] * 2,
         out_shape=[jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 2,
+        name=NAME,
         interpret=interpret,
     )(read, win)
     return score[:, 0], end[:, 0]

@@ -82,6 +82,10 @@ from repro.kernels._util import (
 )
 from repro.kernels.light_align.kernel import align_block
 
+#: Name of every launch of this family: its HLO instruction name
+#: (``candidate_pair_align.N``) and its op name in a device profile.
+NAME = "candidate_pair_align"
+
 DEFAULT_BLOCK = 16     # batch rows per grid step (C candidates x 2 mates each)
 NEG_BIG = -(1 << 20)   # masked-candidate score sentinel
 MM_BIG = 1 << 20       # masked-candidate Hamming sentinel
@@ -326,6 +330,7 @@ def candidate_align_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 12,
+        name=NAME,
         interpret=interpret,
     )(sdma1, sdma2, off1, off2, valid1, valid2, reads1, reads2, ref_lines)
     return tuple(o[:, 0] for o in outs)

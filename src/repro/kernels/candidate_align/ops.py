@@ -87,40 +87,45 @@ def candidate_pair_align(
 
     valid1 = pos1 != INVALID_LOC
     valid2 = pos2 != INVALID_LOC
-    if packed_ref:
-        # Same scalar clamp as gather_windows_packed; the DMA fetches whole
-        # words, the kernel unpacks and cuts the per-row base offset.
-        n_words, hi = packed_gather_coords(ref.shape[0], W)
+    # The reference as the kernel's DMA source: cast and edge-padded
+    # (or word-padded), then cut into 128-lane lines — remade on every
+    # call, so it is scoped on its own.
+    with jax.named_scope("ref_layout"):
+        if packed_ref:
+            # Same scalar clamp as gather_windows_packed; the DMA fetches whole
+            # words, the kernel unpacks and cuts the per-row base offset.
+            n_words, hi = packed_gather_coords(ref.shape[0], W)
 
-        def prep(pos, valid):
-            s = jnp.clip(jnp.where(valid, pos - E, 0), 0, hi)
-            return s // BASES_PER_WORD, s % BASES_PER_WORD
+            def prep(pos, valid):
+                s = jnp.clip(jnp.where(valid, pos - E, 0), 0, hi)
+                return s // BASES_PER_WORD, s % BASES_PER_WORD
 
-        # Back-pad with the last word so word reads past Lw-1 see the same
-        # value the oracle's index clamp produces.
-        words = jax.lax.bitcast_convert_type(ref, jnp.int32)
-        ref_arr = jnp.concatenate(
-            [words, jnp.broadcast_to(words[-1:], (n_words,))])
-        win_elems = n_words
-    else:
-        # Edge-pad a full window width of boundary bases on each side and
-        # clamp starts with the shared saturating clamp
-        # (`clamp_window_starts`), so a contiguous DMA reproduces
-        # gather_ref_windows' per-element index clamp for EVERY int32
-        # start — including the negative starts merge_read_starts emits
-        # for reads near the reference origin.
-        L = ref.shape[0]
-        r32 = ref.astype(jnp.int32)
-        ref_arr = jnp.concatenate([
-            jnp.broadcast_to(r32[:1], (W,)), r32,
-            jnp.broadcast_to(r32[-1:], (W - 1,)),
-        ])
+            # Back-pad with the last word so word reads past Lw-1 see the same
+            # value the oracle's index clamp produces.
+            words = jax.lax.bitcast_convert_type(ref, jnp.int32)
+            ref_arr = jnp.concatenate(
+                [words, jnp.broadcast_to(words[-1:], (n_words,))])
+            win_elems = n_words
+        else:
+            # Edge-pad a full window width of boundary bases on each side and
+            # clamp starts with the shared saturating clamp
+            # (`clamp_window_starts`), so a contiguous DMA reproduces
+            # gather_ref_windows' per-element index clamp for EVERY int32
+            # start — including the negative starts merge_read_starts emits
+            # for reads near the reference origin.
+            L = ref.shape[0]
+            r32 = ref.astype(jnp.int32)
+            ref_arr = jnp.concatenate([
+                jnp.broadcast_to(r32[:1], (W,)), r32,
+                jnp.broadcast_to(r32[-1:], (W - 1,)),
+            ])
 
-        def prep(pos, valid):
-            s = clamp_window_starts(pos, valid, L, W, E)
-            return s + (W - E), jnp.zeros_like(s)
+            def prep(pos, valid):
+                s = clamp_window_starts(pos, valid, L, W, E)
+                return s + (W - E), jnp.zeros_like(s)
 
-        win_elems = W
+            win_elems = W
+        ref_lines = to_lines(ref_arr, lines_spanned(win_elems))
 
     # Line layout (kernels/_util.py): each window DMAs the lines covering
     # element `e` onward; `off` is its lane in the first line (packed:
@@ -132,7 +137,6 @@ def candidate_pair_align(
 
     sdma1, off1 = tables(pos1, valid1)
     sdma2, off2 = tables(pos2, valid2)
-    ref_lines = to_lines(ref_arr, lines_spanned(win_elems))
 
     # Chunk the launch so the scalar-prefetch DMA tables (SMEM, 2*rows*C*4
     # bytes per launch) stay bounded for arbitrarily large batches; every

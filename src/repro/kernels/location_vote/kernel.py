@@ -30,6 +30,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.seedmap import INVALID_LOC
 from repro.kernels._util import LANES
 
+#: Name of every launch of this family: its HLO instruction name
+#: (``location_vote.N``) and its op name in a device profile.
+NAME = "location_vote"
+
 DEFAULT_BLOCK = 64     # reads per grid step
 N_BANKS = 2            # ping-pong VMEM diagonal-row banks
 
@@ -144,6 +148,7 @@ def location_vote_pallas(
         functools.partial(_location_vote_kernel, M=M, vote_bin=vote_bin),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.int32)] * 3,
+        name=NAME,
         interpret=interpret,
     )(n_rows, diag)
     return tuple(o[:, 0] for o in outs)

@@ -74,6 +74,10 @@ from repro.core.seedmap import INVALID_LOC
 from repro.kernels._util import LANES, cut_lanes, lines_spanned
 from repro.kernels.xxhash.kernel import xxhash32_lanes
 
+#: Name of every launch of this family: its HLO instruction name
+#: (``pair_frontend.N``) and its op name in a device profile.
+NAME = "pair_frontend"
+
 DEFAULT_BLOCK = 8        # batch rows per grid step (2*S row DMAs each)
 HASH_BLOCK = 128         # rows per seed_buckets grid step
 MAX_SEED_WORDS = 4       # 16-byte hash input: seed_len <= 64
@@ -133,6 +137,7 @@ def seed_buckets_pallas(
         in_specs=[pl.BlockSpec((R, block), lambda i: (0, i))],
         out_specs=pl.BlockSpec((S, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((S, n), jnp.int32),
+        name=NAME,
         interpret=interpret,
     )(reads.T).T
 
@@ -338,6 +343,7 @@ def pair_frontend_pallas(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, C), jnp.int32)] * 2
         + [jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 3,
+        name=NAME,
         interpret=interpret,
     )(sdma1, sdma2, table)
     pos1, pos2, n, nh1, nh2 = outs
@@ -381,6 +387,7 @@ def merge_filter_pallas(
                    row_spec(1), row_spec(1), row_spec(1)],
         out_shape=[jax.ShapeDtypeStruct((B, C), jnp.int32)] * 2
         + [jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 3,
+        name=NAME,
         interpret=interpret,
     )(locs1, locs2)
     pos1, pos2, n, nh1, nh2 = outs

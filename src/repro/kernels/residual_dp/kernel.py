@@ -56,6 +56,10 @@ from repro.kernels._util import (
 )
 from repro.kernels.banded_sw.kernel import NEG, dp_block
 
+#: Name of every launch of this family: its HLO instruction name
+#: (``residual_pair_dp.N``) and its op name in a device profile.
+NAME = "residual_pair_dp"
+
 DEFAULT_BLOCK = 32     # work items (failed mates) per grid step
 N_BANKS = 2            # ping-pong VMEM window banks
 
@@ -189,6 +193,7 @@ def residual_dp_pallas(
         ),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.int32)] * 3,
+        name=NAME,
         interpret=interpret,
     )(sdma, n_items, reads, off, ref_lines)
     return tuple(o[:, 0] for o in outs)

@@ -87,39 +87,44 @@ def residual_pair_dp(
 
     N, R = reads1.shape
     W = R + 2 * dp_pad
-    if packed_ref:
-        # Same scalar clamp as gather_windows_packed; the DMA fetches
-        # whole words, the kernel unpacks and cuts the per-item offset.
-        n_words, hi = packed_gather_coords(ref.shape[0], W)
+    # The reference as the kernel's DMA source: cast and edge-padded
+    # (or word-padded), then cut into 128-lane lines — remade on every
+    # call, so it is scoped on its own.
+    with jax.named_scope("ref_layout"):
+        if packed_ref:
+            # Same scalar clamp as gather_windows_packed; the DMA fetches
+            # whole words, the kernel unpacks and cuts the per-item offset.
+            n_words, hi = packed_gather_coords(ref.shape[0], W)
 
-        def prep(pos):
-            s = jnp.clip(jnp.where(pos != INVALID_LOC, pos - dp_pad, 0),
-                         0, hi)
-            return s // BASES_PER_WORD, s % BASES_PER_WORD
+            def prep(pos):
+                s = jnp.clip(jnp.where(pos != INVALID_LOC, pos - dp_pad, 0),
+                             0, hi)
+                return s // BASES_PER_WORD, s % BASES_PER_WORD
 
-        words = jax.lax.bitcast_convert_type(ref, jnp.int32)
-        ref_arr = jnp.concatenate(
-            [words, jnp.broadcast_to(words[-1:], (n_words,))])
-        win_elems = n_words
-    else:
-        # Edge-pad a full window width of boundary bases on each side and
-        # clamp starts with the shared saturating clamp
-        # (`clamp_window_starts`), so a contiguous DMA reproduces
-        # gather_ref_windows' per-element index clamp for EVERY int32
-        # start — including the negative starts merge_read_starts emits
-        # for reads near the reference origin.
-        L = ref.shape[0]
-        r32 = ref.astype(jnp.int32)
-        ref_arr = jnp.concatenate([
-            jnp.broadcast_to(r32[:1], (W,)), r32,
-            jnp.broadcast_to(r32[-1:], (W - 1,)),
-        ])
+            words = jax.lax.bitcast_convert_type(ref, jnp.int32)
+            ref_arr = jnp.concatenate(
+                [words, jnp.broadcast_to(words[-1:], (n_words,))])
+            win_elems = n_words
+        else:
+            # Edge-pad a full window width of boundary bases on each side and
+            # clamp starts with the shared saturating clamp
+            # (`clamp_window_starts`), so a contiguous DMA reproduces
+            # gather_ref_windows' per-element index clamp for EVERY int32
+            # start — including the negative starts merge_read_starts emits
+            # for reads near the reference origin.
+            L = ref.shape[0]
+            r32 = ref.astype(jnp.int32)
+            ref_arr = jnp.concatenate([
+                jnp.broadcast_to(r32[:1], (W,)), r32,
+                jnp.broadcast_to(r32[-1:], (W - 1,)),
+            ])
 
-        def prep(pos):
-            s = clamp_window_starts(pos, pos != INVALID_LOC, L, W, dp_pad)
-            return s + (W - dp_pad), jnp.zeros_like(s)
+            def prep(pos):
+                s = clamp_window_starts(pos, pos != INVALID_LOC, L, W, dp_pad)
+                return s + (W - dp_pad), jnp.zeros_like(s)
 
-        win_elems = W
+            win_elems = W
+        ref_lines = to_lines(ref_arr, lines_spanned(win_elems))
 
     # Line layout (kernels/_util.py), as in candidate_align: the first
     # covering line, and the window's offset in it (packed: 16 * word
@@ -131,7 +136,6 @@ def residual_pair_dp(
 
     sd1, off1 = tables(pos1)
     sd2, off2 = tables(pos2)
-    ref_lines = to_lines(ref_arr, lines_spanned(win_elems))
 
     # ---- single-mate-aware item compaction ------------------------------
     # Slot layout is row-major, mate-minor: slot 2*r + m is (row r, mate

@@ -51,7 +51,7 @@ from repro.core import (
 from repro.compile_cache import enable_compile_cache
 from repro.core.seedmap import INVALID_LOC
 from repro.data.pipeline import ReadStreamConfig, read_pairs_for_step
-from repro.engine import ExecutionConfig, LongReadConfig, Mapper
+from repro.engine import ExecutionConfig, LongReadConfig, Mapper, spans
 
 ACC_KEYS = ("mapped1", "mapped2", "correct1", "correct2",
             "pair_mapped", "pair_correct")
@@ -159,6 +159,9 @@ def serve(ref_len: int = 500_000, batch: int = 512, batches: int = 10,
                             pipe_cfg, t_index, mapper=mapper, chaos=chaos)
     else:
         raise ValueError(f"unknown loop {loop!r}; expected stream|legacy")
+    # The program's span table and step-trace counts (engine.spans):
+    # where the host time went, and whether a step recompiled.
+    out["spans"] = spans.snapshot()
     if verbose:
         print(json.dumps(out, indent=1), flush=True)
     return out
@@ -297,6 +300,7 @@ def serve_long(ref_len: int = 500_000, batch: int = 64, batches: int = 10,
         "correct_of_mapped": a["correct"] / max(a["mapped"], 1),
         **sr.fractions,
     }
+    out["spans"] = spans.snapshot()
     if verbose:
         print(json.dumps(out, indent=1), flush=True)
     return out
@@ -341,6 +345,7 @@ def serve_frontdoor(ref_len: int = 500_000, batch: int = 256,
                              long_frac=long_frac,
                              max_queue_rows=max_queue_rows,
                              deadline_s=deadline_s, rng=rng, seed=seed)}
+    out["spans"] = spans.snapshot()
     if verbose:
         print(json.dumps(out, indent=1), flush=True)
     return out
@@ -575,6 +580,7 @@ def save_index(path: str, ref_len: int = 500_000, batch: int = 512,
         "store_mb": store_size_bytes(path) / 1e6,
         "layout": type(mapper.index).__name__,
     }
+    out["spans"] = spans.snapshot()
     if verbose:
         print(json.dumps(out, indent=1), flush=True)
     return out

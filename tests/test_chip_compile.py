@@ -9,6 +9,11 @@ slices, primitives and casts that Mosaic refuses, so these compiles are
 the guard that the kernels still lower.  Nothing runs: results are the
 interpret-mode and oracle tests' business.
 
+Two lowerings of the whole fused stream step (pairs and long reads)
+pin the names a device profile is read by: every stage's
+`jax.named_scope` in the op locations, and each kernel family's stable
+``name=`` on its custom calls.
+
 The topology is described inside a module fixture, never at import, so
 every test worker collects the same tests and only the worker that runs
 this file loads the TPU library.  The persistent compilation cache is
@@ -19,6 +24,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,3 +165,88 @@ def test_location_vote_compiles(sds):
     _assert_compiles(
         lambda d, n: lv.location_vote_pallas(d, n, lr.vote_bin, M),
         sds((lv.LAUNCH_ROWS, Mp)), sds((1,)))
+
+
+# ------------------------------------------------ names a profile reads ---
+def _fused_step_text(sds, lane: str) -> str:
+    """StableHLO, with locations, of a session's fused stream step for
+    ``lane`` on the described chip: every family on its Pallas kernel, a
+    small unpacked session (lowering only, nothing compiles)."""
+    import dataclasses
+
+    from repro.core.seedmap import LinedSeedMap, SeedMapConfig
+    from repro.engine import ExecutionConfig, Mapper, plan
+    from repro.engine.stats import LONG_STAT_KEYS, STAT_KEYS
+    from repro.launch.serve import (
+        ACC_KEYS, _make_accuracy_reduce, _make_vote_accuracy_reduce)
+
+    cfg = PipelineConfig(frontend_backend="pallas", light_backend="pallas",
+                         residual_backend="pallas", packed_ref=False)
+    lr = LongReadConfig(pipe=cfg, vote_backend="pallas")
+    smc = dataclasses.replace(SeedMapConfig(table_bits=14), padded_cap=K)
+    state = (LinedSeedMap(lines=_lines(sds, (1 << 14) * K, K, K),
+                          config=smc), sds((100_000,), jnp.uint8))
+    mapper = Mapper(state=state, state_shardings=None,
+                    raw_step=plan.raw_pipeline_step(cfg), pipe_cfg=cfg,
+                    exec_cfg=ExecutionConfig(stream_batch=64),
+                    sm_config=smc, index=None, lr_cfg=lr,
+                    raw_long_step=plan.raw_long_read_step(lr))
+    i32 = sds(())
+    if lane == "pairs":
+        step = mapper._fused_step(_make_accuracy_reduce(cfg.max_gap), lane)
+        carry = ({k: i32 for k in STAT_KEYS}, {k: i32 for k in ACC_KEYS})
+        batch = (sds((64, R), jnp.uint8), sds((64, R), jnp.uint8), i32,
+                 (sds((64,)), sds((64,))))
+    else:
+        step = mapper._fused_step(_make_vote_accuracy_reduce(lr.vote_bin),
+                                  lane)
+        carry = ({k: i32 for k in LONG_STAT_KEYS},
+                 {k: i32 for k in ("mapped", "correct")})
+        batch = (sds((8, 2000), jnp.uint8), i32, (sds((8,)),))
+    return step.lower(state, carry, *batch).as_text(debug_info=True)
+
+
+def _kernel_names(text: str) -> set:
+    return set(re.findall(r'kernel_name = "([^"]+)"', text))
+
+
+def _has_scope(text: str, scope: str) -> bool:
+    """A location under ``scope``: nested jits lower to functions of
+    their own, whose locations start at the scope (``"ref_layout/..."``)."""
+    return re.search(rf'["/]{re.escape(scope)}/', text) is not None
+
+
+def test_pairs_step_carries_stage_scopes_and_kernel_names(sds):
+    text = _fused_step_text(sds, "pairs")
+    for scope in ("frontend", "light_align", "residual_dp", "assemble",
+                  "ref_layout", "stage_stats", "reduce"):
+        assert _has_scope(text, scope), scope
+    assert _kernel_names(text) == {"pair_frontend", "candidate_pair_align",
+                                   "residual_pair_dp"}
+
+
+def test_both_aligners_scope_their_reference_layout(sds):
+    from repro.kernels.candidate_align.ops import candidate_pair_align
+    from repro.kernels.residual_dp.ops import residual_pair_dp
+
+    ref, reads = sds((100_000,), jnp.uint8), sds((64, R))
+    cands = sds((64, C))
+    text = jax.jit(lambda *a: candidate_pair_align(
+        *a, CFG.max_gap, backend="pallas")).lower(
+            ref, reads, reads, cands, cands).as_text(debug_info=True)
+    assert _has_scope(text, "ref_layout")
+    rows = sds((64,))
+    text = jax.jit(lambda *a: residual_pair_dp(
+        *a, CFG.dp_pad, band=CFG.band(), backend="pallas")).lower(
+            ref, reads, reads, rows, rows, sds((64,), jnp.bool_),
+            sds((64,), jnp.bool_)).as_text(debug_info=True)
+    assert _has_scope(text, "ref_layout")
+
+
+def test_long_step_carries_stage_scopes_and_kernel_names(sds):
+    text = _fused_step_text(sds, "long")
+    for scope in ("lr.frontend", "lr.vote", "lr.anchor_dp", "assemble",
+                  "stage_stats", "reduce"):
+        assert _has_scope(text, scope), scope
+    assert _kernel_names(text) == {"pair_frontend", "location_vote",
+                                   "banded_sw"}
