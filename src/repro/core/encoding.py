@@ -7,6 +7,10 @@ paper's 2-bit encoding (§7.4: "These SRAM FIFOs use 2-bit encoding").
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -98,6 +102,43 @@ def packed_gather_coords(n_ref_words: int, length: int) -> tuple[int, int]:
     # bound so the jitted scalar stays in int32 range.
     hi = min(n_ref_words * BASES_PER_WORD - length - 1, 2**31 - 1)
     return n_words, hi
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["bases", "lines"],
+                   meta_fields=["pad", "nl", "packed"])
+@dataclasses.dataclass(frozen=True)
+class LinedRef:
+    """A session's reference with the aligners' DMA source built once.
+
+    ``bases`` is the resolved reference (uint8 bases, or the packed
+    uint32 words with ``packed``); the jnp oracles, the long-read lane
+    and the index store read it.  ``lines`` is the int32 layout the
+    candidate_align and residual_dp kernels DMA their windows from
+    (`kernels._util.reference_lines`): unpacked, ``pad`` copies of the
+    first base before the reference and ``pad - 1`` of the last after
+    it; packed, ``pad`` copies of the last word after it; then cut into
+    128-lane lines padded for DMAs of up to ``nl`` lines.  One layout
+    serves every kernel whose window needs at most ``pad`` elements of
+    padding and ``nl`` lines.  ``pad``, ``nl`` and ``packed`` are static.
+    """
+
+    bases: jnp.ndarray
+    lines: jnp.ndarray
+    pad: int
+    nl: int
+    packed: bool
+
+    @property
+    def dtype(self):
+        """The dtype of ``bases``: how a step tells the two flavours apart."""
+        return self.bases.dtype
+
+
+def ref_bases(ref):
+    """The reference array itself: ``ref.bases`` of a `LinedRef`, else
+    ``ref``."""
+    return ref.bases if isinstance(ref, LinedRef) else ref
 
 
 def gather_windows_packed(ref_words: jnp.ndarray, starts: jnp.ndarray,

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dp_fallback import NEG, gotoh_semiglobal_banded
-from repro.core.encoding import gather_windows_packed
+from repro.core.encoding import gather_windows_packed, ref_bases
 from repro.core.light_align import gather_ref_windows
 from repro.core.pair_filter import paired_adjacency_filter
 from repro.core.pipeline import PipelineConfig
@@ -187,9 +187,11 @@ def map_long_impl(
     engine's pre-built long-read step (`repro.engine.plan`) and the
     one-shot `map_long_reads` close over.  ``ref`` is the (L,) uint8 base
     array or, like the short-read pipeline, the (Lw,) uint32 2-bit
-    packing; ``sm`` the CSR `SeedMap` (staged front end) or the
+    packing, plain or as a session's `LinedRef` (the lane reads its
+    ``bases``); ``sm`` the CSR `SeedMap` (staged front end) or the
     kernel-layout `PaddedSeedMap`.
     """
+    ref = ref_bases(ref)
     p = cfg.pipe
     delta = cfg.pair_delta()
     # Stages run under `jax.named_scope`s (`lr.frontend`, `lr.vote`,

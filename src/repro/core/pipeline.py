@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.compat import warn_deprecated
-from repro.core.encoding import pack_2bit
+from repro.core.encoding import pack_2bit, ref_bases
 from repro.core.dp_fallback import NEG
 from repro.core.pair_filter import CandidateSet, paired_adjacency_filter
 from repro.core.query import padded_rows_device, query_read_batch
@@ -241,7 +241,7 @@ def stage_stats(res: MapResult) -> dict:
 
 
 def _best_candidate_light(
-    ref: jnp.ndarray,          # (L,) uint8 bases, or (Lw,) uint32 words
+    ref,                       # (L,) u8 bases, (Lw,) u32 words or LinedRef
     reads1: jnp.ndarray,       # (B, R) mate 1, reference orientation
     reads2: jnp.ndarray,       # (B, R) mate 2, reference orientation
     cands: CandidateSet,
@@ -287,7 +287,8 @@ def _residual_dp_stage(ref, reads1, reads2_fwd, pair, passed, light_ok,
     serve step (`core.genpairx_step`).
 
     ``ref`` is whatever flavor the caller resolved (uint8 bases, or the
-    2-bit packed uint32 words with ``packed=True``).  Returns
+    2-bit packed uint32 words with ``packed=True``), plain or as a
+    session's `LinedRef`.  Returns
     ``(score1, score2, dp_done, dp_overflow, dp_mate1, dp_mate2)``, all
     ``(B,)``: scores are the assembled per-row fallback scores (light
     score for passing mates, DP score for re-aligned ones; NEG
@@ -361,7 +362,9 @@ def map_pairs_impl(
 
     ``ref`` is the (L,) uint8 base array; with ``cfg.packed_ref=True`` it
     may instead be the (Lw,) uint32 2-bit packing (`pack_2bit`), which
-    skips the in-step repack.
+    skips the in-step repack.  Either may come as a session's `LinedRef`
+    (what a kernel-backend `Mapper` holds), whose aligner layout was
+    built once; it is passed to the aligners unchanged.
 
     ``sm`` is the CSR `SeedMap`, the kernel-layout `PaddedSeedMap`
     (`to_padded`) or its device line layout `LinedSeedMap` (`to_lined`,
@@ -423,7 +426,8 @@ def map_pairs_impl(
         packed = cfg.packed(default=False)
         ref_words = None
         if packed:
-            ref_words = ref if ref.dtype == jnp.uint32 else pack_2bit(ref)
+            ref_words = (ref if ref.dtype == jnp.uint32
+                         else pack_2bit(ref_bases(ref)))
         pair = _best_candidate_light(ref_words if packed else ref,
                                      reads1, reads2_fwd, cands, cfg, packed)
         b_pos1, b_pos2 = pair.pos1, pair.pos2

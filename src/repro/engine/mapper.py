@@ -11,7 +11,9 @@ pre-engine entry points re-did per call:
     = the pipeline's per-seed location cap) on the kernel backends, the
     bucket-range `ShardedSeedMap` on the sharded-index mesh plan;
   * place everything on devices (replicated or sharded per the
-    `ExecutionConfig`) and jit the one step the session dispatches to.
+    `ExecutionConfig`), lay out the kernel aligners' reference lines
+    (`LinedRef`) from the placed reference, and jit the one step the
+    session dispatches to.
 
 ``mapper.map`` is the synchronous one-batch call; ``mapper.map_stream``
 is the async double-buffered host loop (`engine.stream`) — one fused
@@ -30,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.encoding import pack_2bit
+from repro.core.encoding import pack_2bit, ref_bases
 from repro.core.long_read import (
     LongReadResult,
     long_stage_stat_counts,
@@ -67,6 +69,7 @@ from repro.engine.stream import (
     run_stream,
     split_batch,
 )
+from repro.kernels._util import lined_ref
 
 _DONATE_MSG = ".*donated.*"   # XLA's unusable-donation note, expected on CPU
 
@@ -84,12 +87,26 @@ def _place_state(index, ref_arr, cfg: PipelineConfig, mesh) -> tuple:
     A kernel front end reads the padded rows in their line layout
     (`to_lined`, a host reshape), so a genome-scale table goes to the
     device once, dense; host arrays are placed here, not per dispatch.
+    A kernel aligner DMAs its windows from the reference's int32 line
+    layout, which is built here from the placed reference and held as a
+    `LinedRef` (span ``session.ref_layout``), so no step rebuilds it.
     """
     if isinstance(index, PaddedSeedMap) and cfg.frontend_backend != "jnp":
         index = to_lined(index)
     where = NamedSharding(mesh, P()) if mesh is not None else None
     with span("session.place"):
-        return jax.device_put((index, ref_arr), where)
+        index, ref = jax.device_put((index, ref_arr), where)
+    if cfg.light_backend != "jnp" or cfg.residual_backend != "jnp":
+        with span("session.ref_layout"):
+            ref = _session_ref(ref, cfg)
+    return index, ref
+
+
+def _session_ref(ref, cfg: PipelineConfig):
+    """The placed reference as a `LinedRef` padded for both aligners'
+    windows (light: R + 2E; DP: R + 2 dp_pad): one device op."""
+    widths = (cfg.read_len + 2 * cfg.max_gap, cfg.read_len + 2 * cfg.dp_pad)
+    return lined_ref(ref, cfg.packed_ref, widths)
 
 
 class Mapper:
@@ -244,7 +261,8 @@ class Mapper:
                 "saving a shard_index session is not supported; save a "
                 "replicated-plan session (CSR layout) and load the store "
                 "into the sharded ExecutionConfig instead")
-        return save_store(path, index=self.index, ref=self._state[1],
+        return save_store(path, index=self.index,
+                          ref=ref_bases(self._state[1]),
                           pipe_cfg=self.pipe_cfg, sm_config=self.sm_config,
                           lr_cfg=self.lr_cfg,
                           tune_entries=self._tune_entries)
@@ -301,8 +319,9 @@ class Mapper:
         Safe between stream dispatches: the session state is *passed* to
         the jitted steps (never closed over), so a store with the same
         array shapes/dtypes and the same resolved configs just replaces
-        ``self._state`` — every compiled step (and the fused-step cache)
-        stays valid, and the very next dispatch serves the new index.  A
+        ``self._state`` (the aligners' reference lines are laid out
+        anew) — every compiled step (and the fused-step cache) stays
+        valid, and the very next dispatch serves the new index.  A
         store with different shapes or configs rebuilds the session
         in-place with a warning (compiled steps retrace on next use; do
         not rebuild mid-stream — `map_stream` captures its step once).
@@ -327,7 +346,8 @@ class Mapper:
                     and payload.sm_config == self.sm_config
                     and payload.lr_cfg == self.lr_cfg
                     and type(payload.index) is type(self.index))
-        old_leaves = jax.tree.leaves((self.index, self._state[1]))
+        old_leaves = jax.tree.leaves((self.index,
+                                      ref_bases(self._state[1])))
         new_leaves = jax.tree.leaves((payload.index, payload.ref))
         same_shapes = same_cfg and len(old_leaves) == len(new_leaves) \
             and all(np.asarray(o).shape == np.asarray(n).shape

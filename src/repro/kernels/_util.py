@@ -13,10 +13,13 @@ Location-Table row from a flat array.  Every gathered table is instead
 laid out as 128-lane *lines* (`to_lines`, a free reshape of the dense
 flat array — no footprint growth): a kernel DMAs the whole lines a span
 touches (`lines_spanned`) and cuts the span out in VMEM with a per-row
-lane shift (`cut_lanes`).
+lane shift (`cut_lanes`).  The aligners' reference is laid out by
+`reference_lines`, per call from a plain array or once per session as a
+`LinedRef`.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -24,7 +27,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.encoding import BASES_PER_WORD
+from repro.core.encoding import (
+    BASES_PER_WORD,
+    LinedRef,
+    packed_gather_coords,
+)
 
 LANES = 128          # lanes per line of a line-layout table
 _SUBLANES = 8        # line count granularity of an (8, 128)-tiled table
@@ -135,16 +142,86 @@ def clamp_window_starts(pos: jnp.ndarray, valid: jnp.ndarray, ref_len: int,
     is clamped to ``[lead - width, ref_len - 1 + lead]`` — exactly the
     range where `gather_ref_windows`' per-element index clamp saturates
     the whole window to all-``ref[0]`` / all-``ref[ref_len-1]`` anyway —
-    so a contiguous DMA against a ``width``-lead edge-padded reference
-    (DMA start ``result + (width - lead)``) reproduces the oracle's
+    so a contiguous DMA against a reference edge-padded by ``pad >=
+    width`` (DMA start ``result + (pad - lead)``) reproduces the oracle's
     window for EVERY int32 start, including the negative starts
     `merge_read_starts` emits near the reference origin and the
     negative-diagonal vote positions of the long-read lane.  Shared by
-    the candidate_align / residual_dp unpacked preps and the long-read
-    diagonal windows, so kernel and oracle cannot diverge at the edges.
+    the aligners' unpacked window tables (`window_lines`) and the
+    long-read diagonal windows, so kernel and oracle cannot diverge at
+    the edges.
     """
     return jnp.clip(jnp.where(valid, pos, 0),
                     lead - width, ref_len - 1 + lead).astype(jnp.int32)
+
+
+def window_elems(n_ref: int, packed: bool, width: int) -> int:
+    """Elements of the aligners' DMA source one ``width``-base window
+    spans: its bases, or the packed words `packed_gather_coords` fetches."""
+    return packed_gather_coords(n_ref, width)[0] if packed else width
+
+
+@functools.partial(jax.jit, static_argnames=("packed", "pad", "nl"))
+def reference_lines(ref: jnp.ndarray, packed: bool, pad: int,
+                    nl: int) -> jnp.ndarray:
+    """The candidate_align / residual_dp kernels' DMA source, in lines.
+
+    Unpacked: the bases cast to int32 and edge-padded with ``pad`` copies
+    of the first base in front and ``pad - 1`` of the last behind, so a
+    window clamped by `clamp_window_starts` is one contiguous DMA that
+    reproduces the oracle's per-element index clamp.  Packed: the words
+    back-padded with ``pad`` copies of the last word, so word reads past
+    the end see what the oracle's clamp produces.  Then `to_lines` for
+    DMAs of up to ``nl`` lines.  The one layout code: an op given a
+    plain reference builds it per call, a session once (`LinedRef`).
+    """
+    with jax.named_scope("ref_layout"):
+        if packed:
+            words = jax.lax.bitcast_convert_type(ref, jnp.int32)
+            flat = jnp.concatenate(
+                [words, jnp.broadcast_to(words[-1:], (pad,))])
+        else:
+            r32 = ref.astype(jnp.int32)
+            flat = jnp.concatenate([
+                jnp.broadcast_to(r32[:1], (pad,)), r32,
+                jnp.broadcast_to(r32[-1:], (pad - 1,)),
+            ])
+        return to_lines(flat, nl)
+
+
+def lined_ref(ref: jnp.ndarray, packed: bool, widths) -> LinedRef:
+    """``ref`` with the DMA source for windows of every width in
+    ``widths``: padded for the widest, with the most lines any spans."""
+    elems = [window_elems(ref.shape[0], packed, w) for w in widths]
+    pad, nl = max(elems), max(lines_spanned(e) for e in elems)
+    return LinedRef(bases=ref, lines=reference_lines(ref, packed=packed,
+                                                     pad=pad, nl=nl),
+                    pad=pad, nl=nl, packed=packed)
+
+
+def window_lines(ref: LinedRef, pos: jnp.ndarray, valid: jnp.ndarray,
+                 width: int, lead: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(first line, lane offset) of each window in ``ref.lines``.
+
+    The window of a start ``pos`` is ``width`` bases from ``pos - lead``;
+    ``valid`` masks INVALID_LOC slots.  Unpacked, the start is clamped by
+    `clamp_window_starts` and shifted by the layout's front pad; packed,
+    clamped as `gather_windows_packed` clamps it, and the offset is 16 x
+    the word's lane plus the base in the word.
+    """
+    elems = window_elems(ref.bases.shape[0], ref.packed, width)
+    assert elems <= ref.pad and lines_spanned(elems) <= ref.nl, (
+        "reference layout too narrow for the window", width, ref.pad, ref.nl)
+    if ref.packed:
+        _, hi = packed_gather_coords(ref.bases.shape[0], width)
+        s = jnp.clip(jnp.where(valid, pos - lead, 0), 0, hi)
+        e = s // BASES_PER_WORD
+        off = (e % LANES) * BASES_PER_WORD + s % BASES_PER_WORD
+    else:
+        s = clamp_window_starts(pos, valid, ref.bases.shape[0], width, lead)
+        e = s + (ref.pad - lead)
+        off = e % LANES
+    return (e // LANES).astype(jnp.int32), off.astype(jnp.int32)
 
 
 def pad_rows(x: jnp.ndarray, total: int) -> jnp.ndarray:

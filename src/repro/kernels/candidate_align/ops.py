@@ -14,16 +14,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.encoding import BASES_PER_WORD, packed_gather_coords
+from repro.core.encoding import LinedRef, ref_bases
 from repro.core.scoring import Scoring
 from repro.core.seedmap import INVALID_LOC
 from repro.kernels._util import (
-    LANES,
     chunked_launch,
-    clamp_window_starts,
-    lines_spanned,
+    lined_ref,
     pad_rows,
-    to_lines,
+    window_elems,
+    window_lines,
 )
 from repro.kernels.backend import resolve_backend
 from repro.kernels.candidate_align.kernel import (
@@ -44,7 +43,7 @@ from repro.kernels.candidate_align.ref import (
                      "prescreen_top", "packed_ref", "block", "backend"),
 )
 def candidate_pair_align(
-    ref: jnp.ndarray,        # (L,) uint8 bases, or (Lw,) uint32 packed words
+    ref,                     # (L,) u8 bases, (Lw,) u32 words or LinedRef
     reads1: jnp.ndarray,     # (B, R) mate 1, reference orientation
     reads2: jnp.ndarray,     # (B, R) mate 2, reference orientation
     pos1: jnp.ndarray,       # (B, C) candidate starts, INVALID_LOC padded
@@ -67,6 +66,10 @@ def candidate_pair_align(
     interpret-mode kernels on CPU.  The override is read at trace time, so
     set it before the first call in a process.
 
+    ``ref`` is a plain reference, whose line layout the kernel backends
+    build in the call (`reference_lines`), or a session's `LinedRef`,
+    whose layout was built once; the jnp oracle reads its ``bases``.
+
     ``block=None`` resolves to the hand-picked family default
     (`DEFAULT_BLOCK`); the autotuner (`repro.tune`) threads per-shape
     winners here through `PipelineConfig.light_block`.
@@ -75,8 +78,8 @@ def candidate_pair_align(
     block = block or DEFAULT_BLOCK
     if backend == "jnp":
         return candidate_pair_align_ref(
-            ref, reads1, reads2, pos1, pos2, max_gap, scoring, threshold,
-            mode, prescreen_top, packed_ref)
+            ref_bases(ref), reads1, reads2, pos1, pos2, max_gap, scoring,
+            threshold, mode, prescreen_top, packed_ref)
 
     B, R = reads1.shape
     C = pos1.shape[1]
@@ -87,56 +90,14 @@ def candidate_pair_align(
 
     valid1 = pos1 != INVALID_LOC
     valid2 = pos2 != INVALID_LOC
-    # The reference as the kernel's DMA source: cast and edge-padded
-    # (or word-padded), then cut into 128-lane lines — remade on every
-    # call, so it is scoped on its own.
-    with jax.named_scope("ref_layout"):
-        if packed_ref:
-            # Same scalar clamp as gather_windows_packed; the DMA fetches whole
-            # words, the kernel unpacks and cuts the per-row base offset.
-            n_words, hi = packed_gather_coords(ref.shape[0], W)
-
-            def prep(pos, valid):
-                s = jnp.clip(jnp.where(valid, pos - E, 0), 0, hi)
-                return s // BASES_PER_WORD, s % BASES_PER_WORD
-
-            # Back-pad with the last word so word reads past Lw-1 see the same
-            # value the oracle's index clamp produces.
-            words = jax.lax.bitcast_convert_type(ref, jnp.int32)
-            ref_arr = jnp.concatenate(
-                [words, jnp.broadcast_to(words[-1:], (n_words,))])
-            win_elems = n_words
-        else:
-            # Edge-pad a full window width of boundary bases on each side and
-            # clamp starts with the shared saturating clamp
-            # (`clamp_window_starts`), so a contiguous DMA reproduces
-            # gather_ref_windows' per-element index clamp for EVERY int32
-            # start — including the negative starts merge_read_starts emits
-            # for reads near the reference origin.
-            L = ref.shape[0]
-            r32 = ref.astype(jnp.int32)
-            ref_arr = jnp.concatenate([
-                jnp.broadcast_to(r32[:1], (W,)), r32,
-                jnp.broadcast_to(r32[-1:], (W - 1,)),
-            ])
-
-            def prep(pos, valid):
-                s = clamp_window_starts(pos, valid, L, W, E)
-                return s + (W - E), jnp.zeros_like(s)
-
-            win_elems = W
-        ref_lines = to_lines(ref_arr, lines_spanned(win_elems))
-
-    # Line layout (kernels/_util.py): each window DMAs the lines covering
-    # element `e` onward; `off` is its lane in the first line (packed:
-    # 16 * word lane + base-in-word).
-    def tables(pos, valid):
-        e, base = prep(pos, valid)
-        off = (e % LANES) * (BASES_PER_WORD if packed_ref else 1) + base
-        return (e // LANES).astype(jnp.int32), off.astype(jnp.int32)
-
-    sdma1, off1 = tables(pos1, valid1)
-    sdma2, off2 = tables(pos2, valid2)
+    # The kernel DMAs each window from the reference's line layout
+    # (kernels/_util.py): a session's, built once, or laid out here.
+    if not isinstance(ref, LinedRef):
+        ref = lined_ref(ref, packed_ref, (W,))
+    assert ref.packed == packed_ref, (ref.packed, packed_ref)
+    win_elems = window_elems(ref.bases.shape[0], packed_ref, W)
+    sdma1, off1 = window_lines(ref, pos1, valid1, W, E)
+    sdma2, off2 = window_lines(ref, pos2, valid2, W, E)
 
     # Chunk the launch so the scalar-prefetch DMA tables (SMEM, 2*rows*C*4
     # bytes per launch) stay bounded for arbitrarily large batches; every
@@ -150,7 +111,7 @@ def candidate_pair_align(
             valid1.astype(jnp.int32), valid2.astype(jnp.int32)))
     parts = [
         candidate_align_pallas(
-            ref_lines, reads1[s:s + rows], reads2[s:s + rows],
+            ref.lines, reads1[s:s + rows], reads2[s:s + rows],
             # SMEM start tables flattened 1-D (row-major (rows, C))
             sdma1[s:s + rows].reshape(-1), sdma2[s:s + rows].reshape(-1),
             *(x[s:s + rows] for x in (off1, off2, valid1, valid2)),

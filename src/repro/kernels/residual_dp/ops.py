@@ -23,16 +23,15 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.encoding import BASES_PER_WORD, packed_gather_coords
+from repro.core.encoding import LinedRef, ref_bases
 from repro.core.scoring import Scoring
 from repro.core.seedmap import INVALID_LOC
 from repro.kernels._util import (
-    LANES,
     chunked_launch,
-    clamp_window_starts,
-    lines_spanned,
+    lined_ref,
     pad_rows,
-    to_lines,
+    window_elems,
+    window_lines,
 )
 from repro.kernels.backend import resolve_backend
 from repro.kernels.banded_sw.kernel import NEG
@@ -53,7 +52,7 @@ from repro.kernels.residual_dp.ref import (
                      "backend"),
 )
 def residual_pair_dp(
-    ref: jnp.ndarray,        # (L,) uint8 bases, or (Lw,) uint32 packed words
+    ref,                     # (L,) u8 bases, (Lw,) u32 words or LinedRef
     reads1: jnp.ndarray,     # (N, R) mate 1, reference orientation
     reads2: jnp.ndarray,     # (N, R) mate 2, reference orientation
     pos1: jnp.ndarray,       # (N,) best-candidate starts, INVALID_LOC padded
@@ -72,9 +71,11 @@ def residual_pair_dp(
     ``backend="auto"`` resolves through ``kernels/backend.py``
     (``REPRO_BACKEND`` honored).  ``band`` is the half-width around the
     window's center diagonal (``None`` or ``>= R + 2*dp_pad``: exact full
-    DP, the `gotoh_semiglobal` equivalence anchor).  ``block=None``
-    resolves to `DEFAULT_BLOCK`; the autotuner (`repro.tune`) threads
-    per-shape winners here through `PipelineConfig.residual_block`.
+    DP, the `gotoh_semiglobal` equivalence anchor).  ``ref`` is a plain
+    reference, laid out in the call, or a session's `LinedRef`, as in
+    `candidate_pair_align`.  ``block=None`` resolves to `DEFAULT_BLOCK`;
+    the autotuner (`repro.tune`) threads per-shape winners here through
+    `PipelineConfig.residual_block`.
     """
     backend = resolve_backend(backend, family="residual_dp")
     block = block or DEFAULT_BLOCK
@@ -82,60 +83,19 @@ def residual_pair_dp(
     need2 = need2.astype(bool)
     if backend == "jnp":
         return residual_pair_dp_ref(
-            ref, reads1, reads2, pos1, pos2, need1, need2, dp_pad, band,
-            scoring, packed_ref)
+            ref_bases(ref), reads1, reads2, pos1, pos2, need1, need2,
+            dp_pad, band, scoring, packed_ref)
 
     N, R = reads1.shape
     W = R + 2 * dp_pad
-    # The reference as the kernel's DMA source: cast and edge-padded
-    # (or word-padded), then cut into 128-lane lines — remade on every
-    # call, so it is scoped on its own.
-    with jax.named_scope("ref_layout"):
-        if packed_ref:
-            # Same scalar clamp as gather_windows_packed; the DMA fetches
-            # whole words, the kernel unpacks and cuts the per-item offset.
-            n_words, hi = packed_gather_coords(ref.shape[0], W)
-
-            def prep(pos):
-                s = jnp.clip(jnp.where(pos != INVALID_LOC, pos - dp_pad, 0),
-                             0, hi)
-                return s // BASES_PER_WORD, s % BASES_PER_WORD
-
-            words = jax.lax.bitcast_convert_type(ref, jnp.int32)
-            ref_arr = jnp.concatenate(
-                [words, jnp.broadcast_to(words[-1:], (n_words,))])
-            win_elems = n_words
-        else:
-            # Edge-pad a full window width of boundary bases on each side and
-            # clamp starts with the shared saturating clamp
-            # (`clamp_window_starts`), so a contiguous DMA reproduces
-            # gather_ref_windows' per-element index clamp for EVERY int32
-            # start — including the negative starts merge_read_starts emits
-            # for reads near the reference origin.
-            L = ref.shape[0]
-            r32 = ref.astype(jnp.int32)
-            ref_arr = jnp.concatenate([
-                jnp.broadcast_to(r32[:1], (W,)), r32,
-                jnp.broadcast_to(r32[-1:], (W - 1,)),
-            ])
-
-            def prep(pos):
-                s = clamp_window_starts(pos, pos != INVALID_LOC, L, W, dp_pad)
-                return s + (W - dp_pad), jnp.zeros_like(s)
-
-            win_elems = W
-        ref_lines = to_lines(ref_arr, lines_spanned(win_elems))
-
-    # Line layout (kernels/_util.py), as in candidate_align: the first
-    # covering line, and the window's offset in it (packed: 16 * word
-    # lane + base-in-word).
-    def tables(pos):
-        e, base = prep(pos)
-        off = (e % LANES) * (BASES_PER_WORD if packed_ref else 1) + base
-        return (e // LANES).astype(jnp.int32), off.astype(jnp.int32)
-
-    sd1, off1 = tables(pos1)
-    sd2, off2 = tables(pos2)
+    # The kernel DMAs each window from the reference's line layout
+    # (kernels/_util.py): a session's, built once, or laid out here.
+    if not isinstance(ref, LinedRef):
+        ref = lined_ref(ref, packed_ref, (W,))
+    assert ref.packed == packed_ref, (ref.packed, packed_ref)
+    win_elems = window_elems(ref.bases.shape[0], packed_ref, W)
+    sd1, off1 = window_lines(ref, pos1, pos1 != INVALID_LOC, W, dp_pad)
+    sd2, off2 = window_lines(ref, pos2, pos2 != INVALID_LOC, W, dp_pad)
 
     # ---- single-mate-aware item compaction ------------------------------
     # Slot layout is row-major, mate-minor: slot 2*r + m is (row r, mate
@@ -161,7 +121,7 @@ def residual_pair_dp(
     ins = tuple(pad_rows(x, total) for x in (sd_c, item_reads, off_c))
     parts = [
         residual_dp_pallas(
-            ref_lines, ins[0][s:s + rows],
+            ref.lines, ins[0][s:s + rows],
             jnp.clip(n_items - s, 0, rows).astype(jnp.int32)[None],
             ins[1][s:s + rows], ins[2][s:s + rows],
             dp_pad, band, scoring, packed_ref, win_elems, block,

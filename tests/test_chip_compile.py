@@ -176,6 +176,7 @@ def _fused_step_text(sds, lane: str) -> str:
 
     from repro.core.seedmap import LinedSeedMap, SeedMapConfig
     from repro.engine import ExecutionConfig, Mapper, plan
+    from repro.engine.mapper import _session_ref
     from repro.engine.stats import LONG_STAT_KEYS, STAT_KEYS
     from repro.launch.serve import (
         ACC_KEYS, _make_accuracy_reduce, _make_vote_accuracy_reduce)
@@ -184,8 +185,12 @@ def _fused_step_text(sds, lane: str) -> str:
                          residual_backend="pallas", packed_ref=False)
     lr = LongReadConfig(pipe=cfg, vote_backend="pallas")
     smc = dataclasses.replace(SeedMapConfig(table_bits=14), padded_cap=K)
+    # The session's reference: its aligner lines built once (`LinedRef`).
+    ref = jax.eval_shape(functools.partial(_session_ref, cfg=cfg),
+                         sds((100_000,), jnp.uint8))
     state = (LinedSeedMap(lines=_lines(sds, (1 << 14) * K, K, K),
-                          config=smc), sds((100_000,), jnp.uint8))
+                          config=smc),
+             jax.tree.map(lambda x: sds(x.shape, x.dtype), ref))
     mapper = Mapper(state=state, state_shardings=None,
                     raw_step=plan.raw_pipeline_step(cfg), pipe_cfg=cfg,
                     exec_cfg=ExecutionConfig(stream_batch=64),
@@ -219,8 +224,11 @@ def _has_scope(text: str, scope: str) -> bool:
 def test_pairs_step_carries_stage_scopes_and_kernel_names(sds):
     text = _fused_step_text(sds, "pairs")
     for scope in ("frontend", "light_align", "residual_dp", "assemble",
-                  "ref_layout", "stage_stats", "reduce"):
+                  "stage_stats", "reduce"):
         assert _has_scope(text, scope), scope
+    # The session holds the aligners' reference lines: no step lays
+    # them out.
+    assert not _has_scope(text, "ref_layout")
     assert _kernel_names(text) == {"pair_frontend", "candidate_pair_align",
                                    "residual_pair_dp"}
 
