@@ -47,13 +47,15 @@ from repro.core.encoding import gather_windows_packed, ref_bases
 from repro.core.light_align import gather_ref_windows
 from repro.core.pair_filter import paired_adjacency_filter
 from repro.core.pipeline import PipelineConfig
-from repro.core.query import QueryResult, padded_rows_device, query_read_batch
+from repro.core.query import QueryResult, query_read_batch
 from repro.core.seeding import seed_read_batch
 from repro.core.seedmap import (
     INVALID_LOC,
+    LinedCSRSeedMap,
     LinedSeedMap,
     PaddedSeedMap,
     SeedMap,
+    frontend_layout,
 )
 from repro.kernels.backend import resolve_backend
 
@@ -176,7 +178,7 @@ def _anchor_windows(ref: jnp.ndarray, position: jnp.ndarray,
 
 
 def map_long_impl(
-    sm: SeedMap | PaddedSeedMap | LinedSeedMap,
+    sm: SeedMap | PaddedSeedMap | LinedSeedMap | LinedCSRSeedMap,
     ref: jnp.ndarray,
     reads: jnp.ndarray,
     cfg: LongReadConfig = LongReadConfig(),
@@ -188,8 +190,8 @@ def map_long_impl(
     one-shot `map_long_reads` close over.  ``ref`` is the (L,) uint8 base
     array or, like the short-read pipeline, the (Lw,) uint32 2-bit
     packing, plain or as a session's `LinedRef` (the lane reads its
-    ``bases``); ``sm`` the CSR `SeedMap` (staged front end) or the
-    kernel-layout `PaddedSeedMap`.
+    ``bases``); ``sm`` the CSR `SeedMap` or any layout
+    `core.pipeline.map_pairs_impl` takes.
     """
     ref = ref_bases(ref)
     p = cfg.pipe
@@ -225,11 +227,9 @@ def map_long_impl(
             cands = paired_adjacency_filter(q1, q2, delta, p.max_candidates)
             pos1, n_cand = cands.pos1, cands.n
         else:
-            rows = (sm if isinstance(sm, LinedSeedMap)
-                    else sm.rows if isinstance(sm, PaddedSeedMap)
-                    else padded_rows_device(sm, p.max_locs_per_seed))
             fe = segment_pair_frontend(
-                rows, reads, cfg.segment_len, cfg.segment_stride, p.seed_len,
+                frontend_layout(sm, p.max_locs_per_seed), reads,
+                cfg.segment_len, cfg.segment_stride, p.seed_len,
                 p.seeds_per_read, sm.config.hash_seed, delta, p.max_candidates,
                 block=p.frontend_block, backend=fe_backend)
             pos1, n_cand = fe.pos1, fe.n

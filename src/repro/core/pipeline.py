@@ -63,14 +63,16 @@ from repro.compat import warn_deprecated
 from repro.core.encoding import pack_2bit, ref_bases
 from repro.core.dp_fallback import NEG
 from repro.core.pair_filter import CandidateSet, paired_adjacency_filter
-from repro.core.query import padded_rows_device, query_read_batch
+from repro.core.query import query_read_batch
 from repro.core.scoring import Scoring
 from repro.core.seeding import seed_read_batch
 from repro.core.seedmap import (
     INVALID_LOC,
+    LinedCSRSeedMap,
     LinedSeedMap,
     PaddedSeedMap,
     SeedMap,
+    frontend_layout,
 )
 from repro.kernels.backend import resolve_backend
 
@@ -121,9 +123,8 @@ class PipelineConfig:
     # Backend for the fused front end (steps 1-3: seeding + SeedMap query
     # + Paired-Adjacency filter as one `pair_frontend` op).  Same
     # resolution rules; the staged seeding/query/pair_filter modules are
-    # the "jnp" oracle.  On the kernel backends `map_pairs` needs the
-    # padded-row Location Table: pass a `PaddedSeedMap` (preferred), or a
-    # CSR `SeedMap` which is re-laid-out in-jit at test scales.
+    # the "jnp" oracle.  The kernel backends gather rows from a line
+    # layout of the Location Table (`core.seedmap.frontend_layout`).
     frontend_backend: str = "auto"
     # Run the whole pipeline (candidate windows + DP fallback windows)
     # against the 2-bit packed reference: 4x less HBM window traffic, the
@@ -348,7 +349,7 @@ def _residual_dp_stage(ref, reads1, reads2_fwd, pair, passed, light_ok,
 
 
 def map_pairs_impl(
-    sm: SeedMap | PaddedSeedMap | LinedSeedMap,
+    sm: SeedMap | PaddedSeedMap | LinedSeedMap | LinedCSRSeedMap,
     ref: jnp.ndarray,
     reads1: jnp.ndarray,
     reads2: jnp.ndarray,
@@ -367,10 +368,10 @@ def map_pairs_impl(
     built once; it is passed to the aligners unchanged.
 
     ``sm`` is the CSR `SeedMap`, the kernel-layout `PaddedSeedMap`
-    (`to_padded`) or its device line layout `LinedSeedMap` (`to_lined`,
-    what a kernel-backend session holds).  The kernel front-end backends
-    gather rows from the padded rows; handing them a CSR map re-lays it
-    out in-jit (`padded_rows_device` — test scales only).  The padded row width
+    (`to_padded`) or a device line layout a kernel-backend session holds
+    (`LinedCSRSeedMap` or `LinedSeedMap`).  The kernel front-end
+    backends gather rows from a line layout; a CSR map is cut into lines
+    in-jit (`frontend_layout`).  A padded or lined layout's row width
     caps locations per seed, superseding ``cfg.max_locs_per_seed``.
     """
     B, R = reads1.shape
@@ -404,11 +405,9 @@ def map_pairs_impl(
                 q1, q2, cfg.delta, cfg.max_candidates
             )
         else:
-            rows = (sm if isinstance(sm, LinedSeedMap)
-                    else sm.rows if isinstance(sm, PaddedSeedMap)
-                    else padded_rows_device(sm, cfg.max_locs_per_seed))
             fe = pair_frontend(
-                rows, reads1, reads2_fwd, cfg.seed_len, cfg.seeds_per_read,
+                frontend_layout(sm, cfg.max_locs_per_seed), reads1,
+                reads2_fwd, cfg.seed_len, cfg.seeds_per_read,
                 sm.config.hash_seed, cfg.delta, cfg.max_candidates,
                 block=cfg.frontend_block, backend=fe_backend)
             had_hits = (fe.n_hits1 > 0) & (fe.n_hits2 > 0)

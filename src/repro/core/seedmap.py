@@ -12,10 +12,16 @@ Index-filtering threshold (§5.2): buckets with more than `max_locations`
 entries are physically removed from the Location Table (the paper filters
 them out of SeedMap); queries to them return empty.
 
-A second, TPU-kernel-friendly layout (`PaddedSeedMap`) stores bucket-major
-fixed-width rows so the Pallas gather kernel (`kernels/seed_gather`) can
-stream whole rows HBM->VMEM with statically-shaped DMAs — the analogue of
-the paper's channel-striped NMSL layout.
+The Pallas row gather (`kernels/pair_frontend`) reads one of two device
+layouts, both 128-lane lines because Mosaic DMAs only whole tiles:
+
+  - `LinedCSRSeedMap`: the CSR tables, the locations cut into lines; a
+    row starts at ``offsets[b]`` and is masked at its count.  T*4 bytes
+    of offsets plus the locations.
+  - `LinedSeedMap`: the bucket-major fixed-width rows of `PaddedSeedMap`
+    (INVALID_LOC padded, row b at ``b*K``) in lines.  T*K*4 bytes.
+
+`engine.mapper.index_layout` picks one from the sizes.
 """
 from __future__ import annotations
 
@@ -29,6 +35,9 @@ import numpy as np
 from repro.core.hashing import xxhash32_words_np
 
 INVALID_LOC = np.int32(2**31 - 1)  # sentinel: sorts after every real position
+
+#: seed positions `build_seedmap` hashes per host pass
+BUILD_CHUNK = 1 << 24
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +87,23 @@ class LinedSeedMap(NamedTuple):
     config: SeedMapConfig   # static; padded_cap is the row width K
 
 
+class LinedCSRSeedMap(NamedTuple):
+    """A CSR `SeedMap` as the Pallas row gather (`kernels/pair_frontend`)
+    DMAs it: the Seed Table as is, the Location Table as 128-lane lines.
+
+    Bucket ``b``'s row is elements ``[offsets[b], offsets[b] + min(count,
+    K))`` of the flattened lines, K = ``config.padded_cap``: a row starts
+    at any lane, so the gather fetches the two lines a K <= 128 row can
+    touch, cuts the row at its lane offset and masks the lanes past its
+    count.  The layout costs the CSR bytes plus a few lines of padding,
+    where the padded table costs T*K*4.
+    """
+
+    offsets: jnp.ndarray    # int32[T + 1]
+    lines: jnp.ndarray      # int32[n, 128], the locations
+    config: SeedMapConfig   # static; padded_cap is the per-seed cap K
+
+
 jax.tree_util.register_static(SeedMapConfig)
 
 
@@ -91,6 +117,41 @@ def to_lined(psm: PaddedSeedMap) -> LinedSeedMap:
     return LinedSeedMap(
         lines=to_lines(psm.rows.reshape(-1), lines_spanned(K, K)),
         config=dataclasses.replace(psm.config, padded_cap=K))
+
+
+def to_lined_csr(sm: SeedMap, cap: int) -> LinedCSRSeedMap:
+    """CSR `SeedMap` -> `LinedCSRSeedMap` at the per-seed cap ``cap``
+    (host arrays stay on the host; the locations are padded by the lines
+    a row's DMA may run past the last one)."""
+    from repro.kernels._util import lines_spanned, to_lines
+
+    return LinedCSRSeedMap(
+        offsets=sm.offsets,
+        lines=to_lines(sm.locations, lines_spanned(cap, 1)),
+        config=dataclasses.replace(sm.config, padded_cap=cap))
+
+
+def padded_to_csr(psm: PaddedSeedMap) -> SeedMap:
+    """`PaddedSeedMap` -> the CSR `SeedMap` of its rows: every bucket's
+    first ``counts[b]`` locations.  A query at the padded row width K is
+    bit-identical on both (the rows already hold at most K)."""
+    rows, counts = np.asarray(psm.rows), np.asarray(psm.counts)
+    offsets = np.zeros(counts.shape[0] + 1, dtype=np.int32)
+    np.cumsum(counts, out=offsets[1:])
+    filled = np.arange(rows.shape[1], dtype=np.int32) < counts[:, None]
+    return SeedMap(offsets=offsets, locations=rows[filled],
+                   config=psm.config)
+
+
+def frontend_layout(sm, cap: int):
+    """What the kernel front end gathers rows from: a placed lined layout
+    as is, a `PaddedSeedMap`'s rows, or a CSR `SeedMap` in lines at the
+    per-seed cap ``cap`` (inside a jit, one pad of the locations)."""
+    if isinstance(sm, (LinedSeedMap, LinedCSRSeedMap)):
+        return sm
+    if isinstance(sm, PaddedSeedMap):
+        return sm.rows
+    return to_lined_csr(sm, cap)
 
 
 def packed_words_all_positions(ref: np.ndarray, seed_len: int) -> np.ndarray:
@@ -132,27 +193,50 @@ def build_seedmap(ref: np.ndarray, config: SeedMapConfig = SeedMapConfig()) -> S
     bucket into the temporary seed-locations table, (3) concatenate into the
     Location Table, (4) record per-bucket offsets in the Seed Table; then
     apply the index-filtering threshold.
+
+    The seeds are hashed `BUILD_CHUNK` positions at a time into one
+    uint64 key per position, ``bucket << 32 | position``: sorting the keys
+    groups positions by bucket, ascending within one, so the host holds 8
+    bytes a position plus the tables (a whole-array hash and a stable
+    argsort held ~63).
     """
     ref = np.asarray(ref, dtype=np.uint8)
-    words = packed_words_all_positions(ref, config.seed_len)
-    hashes = xxhash32_words_np(words, seed=config.hash_seed)
-    buckets = (hashes & np.uint32(config.table_size - 1)).astype(np.int64)
-    positions = np.arange(len(buckets), dtype=np.int32)
-    order = np.argsort(buckets, kind="stable")  # stable: positions stay sorted
-    sorted_buckets = buckets[order]
-    sorted_pos = positions[order]
-    counts = np.bincount(sorted_buckets, minlength=config.table_size)
+    n_pos = ref.shape[0] - config.seed_len + 1
+    if n_pos <= 0:
+        raise ValueError("reference shorter than seed length")
+    mask = np.uint32(config.table_size - 1)
+    keys = np.empty(n_pos, dtype=np.uint64)
+    for lo in range(0, n_pos, BUILD_CHUNK):
+        hi = min(lo + BUILD_CHUNK, n_pos)
+        words = packed_words_all_positions(
+            ref[lo:hi + config.seed_len - 1], config.seed_len)
+        buckets = xxhash32_words_np(words, seed=config.hash_seed) & mask
+        keys[lo:hi] = buckets.astype(np.uint64) << np.uint64(32)
+        keys[lo:hi] |= np.arange(lo, hi, dtype=np.uint64)
+    keys.sort()
+    # Bucket sizes from the sorted keys' runs, chunk by chunk (a bucket's
+    # run may cross a chunk edge, hence the accumulate).
+    counts = np.zeros(config.table_size, dtype=np.int64)
+    for lo in range(0, n_pos, BUILD_CHUNK):
+        b = (keys[lo:lo + BUILD_CHUNK] >> np.uint64(32)).astype(np.int64)
+        first = np.flatnonzero(np.diff(b, prepend=-1))
+        counts[b[first]] += np.diff(first, append=b.shape[0])
     # Index-filtering threshold: physically remove over-full buckets.
     dropped = counts > config.max_locations
-    if dropped.any():
-        keep = ~dropped[sorted_buckets]
-        sorted_pos = sorted_pos[keep]
-        counts = np.where(dropped, 0, counts)
+    counts[dropped] = 0
+    locations = np.empty(int(counts.sum()), dtype=np.int32)
+    at = 0
+    for lo in range(0, n_pos, BUILD_CHUNK):
+        k = keys[lo:lo + BUILD_CHUNK]
+        k = k[~dropped[(k >> np.uint64(32)).astype(np.int64)]]
+        locations[at:at + k.shape[0]] = k & np.uint64(0xFFFFFFFF)
+        at += k.shape[0]
+    del keys
     offsets = np.zeros(config.table_size + 1, dtype=np.int32)
     np.cumsum(counts, out=offsets[1:])
     # Host arrays: the session that consumes the index places it (CSR,
     # padded or lined) on its devices once, in the layout it needs.
-    return SeedMap(offsets=offsets, locations=sorted_pos, config=config)
+    return SeedMap(offsets=offsets, locations=locations, config=config)
 
 
 def to_padded(sm: SeedMap, cap: int | None = None) -> PaddedSeedMap:

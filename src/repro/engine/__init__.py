@@ -3,7 +3,8 @@
 The paper's pipeline (§4.1, Fig. 3) is one dataflow; this package is its
 one front door.  ``Mapper.build`` / ``Mapper.from_index`` construct the
 canonical device-resident state exactly once — 2-bit packed reference,
-`PaddedSeedMap` layout, resolved kernel backends, mesh/sharding placement
+SeedMap layout (padded or CSR lines, from sizes), resolved kernel
+backends, mesh/sharding placement
 — in the spirit of the persistent-service mappers GenPairX is benchmarked
 against (BWA-MEM2's reusable index handle; GenDP's fixed dataflow
 programmed once, driven many times).  ``mapper.map`` dispatches to a
@@ -20,7 +21,7 @@ service front end.
 
 ``engine.index_store`` is the fleet persistence layer: ``Mapper.save`` /
 ``Mapper.load`` round-trip the fully resolved session (packed reference,
-padded SeedMap, resolved configs, tune snapshot) through a versioned
+padded or CSR SeedMap, resolved configs, tune snapshot) through a versioned
 checksummed on-disk store so workers cold-start without rebuilding the
 index, ``Mapper.swap_index`` / ``FrontDoor.reload_index`` hot-swap a new
 index release into a live session, and ``engine.multihost.map_stream``

@@ -7,9 +7,12 @@ pre-engine entry points re-did per call:
   * resolve kernel backends for every family (env override, auto rule);
   * resolve the ``packed_ref`` tri-state and 2-bit pack the reference;
   * pick the SeedMap layout the step consumes — the CSR map on the staged
-    jnp oracle path, the bucket-major `PaddedSeedMap` relayout (row width
-    = the pipeline's per-seed location cap) on the kernel backends, the
-    bucket-range `ShardedSeedMap` on the sharded-index mesh plan;
+    jnp oracle path; on the kernel backends, from sizes alone
+    (`index_layout`), the bucket-major padded rows (row width = the
+    pipeline's per-seed location cap) where they fit in half the
+    device's memory, else the CSR tables, either cut into 128-lane lines
+    at placement; the bucket-range `ShardedSeedMap` on the sharded-index
+    mesh plan;
   * place everything on devices (replicated or sharded per the
     `ExecutionConfig`), lay out the kernel aligners' reference lines
     (`LinedRef`) from the placed reference, and jit the one step the
@@ -43,11 +46,15 @@ from repro.core.pipeline import (
     stage_stat_counts,
 )
 from repro.core.seedmap import (
+    LinedCSRSeedMap,
+    LinedSeedMap,
     PaddedSeedMap,
     SeedMap,
     SeedMapConfig,
     build_seedmap,
+    padded_to_csr,
     to_lined,
+    to_lined_csr,
     to_padded,
 )
 from repro.engine.config import (
@@ -56,7 +63,7 @@ from repro.engine.config import (
     resolved_pipeline,
 )
 from repro.engine import plan
-from repro.engine.spans import note_trace, span
+from repro.engine.spans import note_trace, set_counter, span
 from repro.engine.stats import (
     LONG_STAT_KEYS,
     STAT_KEYS,
@@ -81,21 +88,59 @@ _DONATE_MSG = ".*donated.*"   # XLA's unusable-donation note, expected on CPU
 _FUSED_CACHE_MAX = 8
 
 
+#: ``session.index_layout`` counter value of each placed index type
+_LAYOUT_NAMES = {SeedMap: "csr", PaddedSeedMap: "padded",
+                 LinedCSRSeedMap: "csr_lines", LinedSeedMap: "padded_lines"}
+
+
+def index_layout(table_size: int, cap: int, bytes_limit: int | None) -> str:
+    """The kernel front end's index layout, from sizes alone.
+
+    ``"padded"`` (bucket-major rows, T*cap int32) when that table fits in
+    half of ``bytes_limit``, the least device memory of the session's
+    devices (no limit known, as on a CPU: it fits); else ``"csr"``,
+    whose locations grow with the genome and not with T*cap.
+    """
+    padded = table_size * cap * 4
+    if bytes_limit is None or 2 * padded <= bytes_limit:
+        return "padded"
+    return "csr"
+
+
+def _bytes_limit(mesh) -> int | None:
+    """Least ``bytes_limit`` of the devices a session places on."""
+    devices = (mesh.devices.flat if mesh is not None
+               else jax.devices()[:1])
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in devices]
+    return None if None in limits else min(limits)
+
+
 def _place_state(index, ref_arr, cfg: PipelineConfig, mesh) -> tuple:
     """The replicated plan's device state ``(index, ref)``, placed once.
 
-    A kernel front end reads the padded rows in their line layout
-    (`to_lined`, a host reshape), so a genome-scale table goes to the
-    device once, dense; host arrays are placed here, not per dispatch.
+    A kernel front end reads the index in a line layout (a host reshape:
+    `to_lined` of padded rows, `to_lined_csr` of the CSR tables at the
+    pipeline's per-seed cap), so a genome-scale table goes to the device
+    once, dense; host arrays are placed here, not per dispatch.  The
+    placed layout and its device bytes are the ``spans`` counters
+    ``session.index_layout`` and ``session.index_bytes``; the serve
+    driver's JSON reports carry them under ``spans``, so a report says
+    which layout its session placed and how large.
     A kernel aligner DMAs its windows from the reference's int32 line
     layout, which is built here from the placed reference and held as a
     `LinedRef` (span ``session.ref_layout``), so no step rebuilds it.
     """
-    if isinstance(index, PaddedSeedMap) and cfg.frontend_backend != "jnp":
-        index = to_lined(index)
+    if cfg.frontend_backend != "jnp":
+        if isinstance(index, PaddedSeedMap):
+            index = to_lined(index)
+        elif isinstance(index, SeedMap):
+            index = to_lined_csr(index, cfg.max_locs_per_seed)
     where = NamedSharding(mesh, P()) if mesh is not None else None
     with span("session.place"):
         index, ref = jax.device_put((index, ref_arr), where)
+    set_counter("session.index_layout", _LAYOUT_NAMES[type(index)])
+    set_counter("session.index_bytes",
+                sum(int(x.nbytes) for x in jax.tree.leaves(index)))
     if cfg.light_backend != "jnp" or cfg.residual_backend != "jnp":
         with span("session.ref_layout"):
             ref = _session_ref(ref, cfg)
@@ -166,12 +211,14 @@ class Mapper:
         """Build a session from an existing index + reference.
 
         ``sm`` is a CSR `SeedMap` or an already-relaid `PaddedSeedMap`
-        (the index-store load path): a padded map is taken as-is and its
-        row width becomes the session's ``max_locs_per_seed`` — the two
-        flavors build bit-identical sessions.  ``ref`` may be the (L,)
-        uint8 base array or the (Lw,) uint32 2-bit packing; whichever
-        flavor the resolved plan needs that is missing is derived here,
-        once.
+        (the index-store load path): a padded map's row width becomes
+        the session's ``max_locs_per_seed``, and a kernel session whose
+        device cannot hold padded rows (`index_layout`) takes its rows
+        as CSR tables — every flavor builds a bit-identical session.  A
+        host padded table is made only where the layout is padded.
+        ``ref`` may be the (L,) uint8 base array or the (Lw,) uint32
+        2-bit packing; whichever flavor the resolved plan needs that is
+        missing is derived here, once.
         """
         pipe_cfg = pipe_cfg or PipelineConfig()
         exec_cfg = exec_cfg or ExecutionConfig()
@@ -210,23 +257,26 @@ class Mapper:
                         " pass the uint8 base array")
                 ref_arr = ref
             if isinstance(sm, PaddedSeedMap):
-                # An already-padded map is taken as-is; its row width IS
-                # the per-seed location cap, so the resolved config (and
-                # the long-read lane / tune bucket keys derived from it)
-                # must agree with it.
+                # An already-padded map's row width IS the per-seed
+                # location cap, so the resolved config (and the
+                # long-read lane / tune bucket keys derived from it) must
+                # agree with it.
                 cap = int(sm.rows.shape[1])
                 if cap != cfg.max_locs_per_seed:
                     cfg = dataclasses.replace(cfg, max_locs_per_seed=cap)
-                index = sm
-            elif cfg.frontend_backend == "jnp":
-                # The staged oracle path queries the CSR tables directly
-                # (bit-exact `map_pairs` legacy).
-                index = sm
-            else:
-                # Kernel front end: one host-side CSR->padded relayout at
-                # the pipeline's per-seed cap, instead of the in-jit
-                # `padded_rows_device` fallback on every trace.
-                index = to_padded(sm, cap=cfg.max_locs_per_seed)
+            index = sm
+            if cfg.frontend_backend != "jnp":
+                # Kernel front end: padded rows where they fit the
+                # device, else the CSR tables (`index_layout`); either
+                # is cut into lines at placement.  The staged oracle
+                # path takes the map as given.
+                layout = index_layout(sm.config.table_size,
+                                      cfg.max_locs_per_seed,
+                                      _bytes_limit(mesh))
+                if layout == "csr" and isinstance(sm, PaddedSeedMap):
+                    index = padded_to_csr(sm)
+                elif layout == "padded" and isinstance(sm, SeedMap):
+                    index = to_padded(sm, cap=cfg.max_locs_per_seed)
             shardings = None
             if mesh is not None:
                 repl = NamedSharding(mesh, P())

@@ -20,7 +20,11 @@ the number of events.  Events outside any span are not recorded.
 side effect inside the body, it runs only when JAX traces, so its count
 says how often that step was (re)compiled.
 
-``snapshot()`` returns both tables; ``reset()`` empties them.  The
+``set_counter(name, value)`` records the latest value of a quantity
+the program knows at a point, such as the session's index layout and
+its device bytes at placement (a later placement replaces it).
+
+``snapshot()`` returns the tables; ``reset()`` empties them.  The
 names each part of the program opens are listed in docs/ENGINE.md
 ("Tracing").
 """
@@ -57,6 +61,7 @@ class _Table:
         self._spans: dict[str, list] = {}       # name -> [count, seconds]
         self._events: dict[str, list] = {}      # name -> [count, starts, ends]
         self._traces: dict[str, int] = {}
+        self._counters: dict[str, object] = {}
 
     def stack(self) -> list:
         if not hasattr(self._local, "stack"):
@@ -90,6 +95,10 @@ class _Table:
         with self._lock:
             self._traces[key] = self._traces.get(key, 0) + 1
 
+    def set_counter(self, name: str, value) -> None:
+        with self._lock:
+            self._counters[name] = value
+
     def snapshot(self) -> dict:
         with self._lock:
             spans = {k: {"count": c, "seconds": s}
@@ -97,13 +106,15 @@ class _Table:
             spans.update({k: {"count": c, "seconds": sum(
                 e - s for s, e in zip(starts, ends))}
                 for k, (c, starts, ends) in self._events.items()})
-            return {"spans": spans, "traces": dict(self._traces)}
+            return {"spans": spans, "traces": dict(self._traces),
+                    "counters": dict(self._counters)}
 
     def reset(self) -> None:
         with self._lock:
             self._spans.clear()
             self._events.clear()
             self._traces.clear()
+            self._counters.clear()
 
 
 _TABLE = _Table()
@@ -128,14 +139,20 @@ def note_trace(key: str) -> None:
     _TABLE.note_trace(key)
 
 
+def set_counter(name: str, value) -> None:
+    """Record ``value`` (a number or a name) as the latest of ``name``."""
+    _TABLE.set_counter(name, value)
+
+
 def snapshot() -> dict:
-    """``{"spans": {name: {"count", "seconds"}}, "traces": {key: n}}``;
-    compile events appear under ``"<span>/<event>"``."""
+    """``{"spans": {name: {"count", "seconds"}}, "traces": {key: n},
+    "counters": {name: value}}``; compile events appear under
+    ``"<span>/<event>"``."""
     return _TABLE.snapshot()
 
 
 def reset() -> None:
-    """Empty the span table and the trace counts."""
+    """Empty the span table, the trace counts and the counters."""
     _TABLE.reset()
 
 

@@ -12,8 +12,10 @@ the per-read hit counts are written back.
 Two kernels, one op
 -------------------
 The row-gather DMAs are aimed by scalar-prefetch tables of *flattened row
-offsets* (`bucket * K`), and scalar-prefetch operands must exist before
-the launch, so the fused op runs as two back-to-back kernels:
+starts* (``bucket * K`` in padded rows, ``offsets[bucket]`` in CSR
+lines, where a count table rides along), and scalar-prefetch operands
+must exist before the launch, so the fused op runs as two back-to-back
+kernels:
 
   1. `seed_buckets_pallas` — in-VMEM seed extraction + 2-bit packing +
      xxHash32 (reusing `kernels/xxhash`'s `xxhash32_lanes` hashing unit,
@@ -24,7 +26,9 @@ the launch, so the fused op runs as two back-to-back kernels:
      Δ-adjacency filter and front-compaction -> `CandidateSet` arrays.
 
 Only the tiny `(B, S)` int32 bucket tensor (4 B/seed — exactly the
-paper's centralized-buffer traffic, §5.2) crosses HBM between the two.
+paper's centralized-buffer traffic, §5.2) crosses HBM between the two
+(with a CSR index, plus one gather of each bucket's two Seed-Table
+offsets, `ops.py`).
 
 In-VMEM sorted merge
 --------------------
@@ -56,9 +60,11 @@ Location-Table HBM traffic of step g+1 hides behind the sort/filter
 compute of step g.
 
 Mosaic DMAs a slice of an HBM table only at tile boundaries, so the
-table is the padded rows as dense 128-lane lines (`kernels/_util.py`):
-each seed's DMA fetches the line holding its row, and the kernel cuts
-the row at its lane offset.
+table is dense 128-lane lines (`kernels/_util.py`): each seed's DMA
+fetches the lines holding its row — one for a padded row, which starts
+at a multiple of K; two for a CSR row, which starts at any lane — and
+the kernel cuts the row at its lane offset.  A CSR row's lanes at or
+past its count belong to the next bucket and are set to INVALID_LOC.
 """
 from __future__ import annotations
 
@@ -232,25 +238,25 @@ def _merge_filter_rows(n_rows: int, locs_of, outs, **kw):
 
 
 # ------------------------------------------------- fused gather + filter --
-def _frontend_kernel(
-    # scalar prefetch: (B*S,) int32 flattened-row-offset tables, SMEM
-    sdma1_ref, sdma2_ref,
-    # inputs
-    table_any,                   # (n, 128) int32 ANY/HBM: row-table lines
-    # outputs
-    pos1_ref, pos2_ref,          # (BLK, C) int32
-    n_ref, nh1_ref, nh2_ref,     # (BLK, 1) int32
-    # scratch
-    loc,                         # (N_BANKS, 2, S, BLK*nl, 128) int32 VMEM
-    sems,                        # (N_BANKS, 2) DMA semaphores
-    *,
-    S: int, K: int, nl: int, seed_offs: tuple, delta: int, cap: int,
-):
+def _frontend_kernel(*refs, S: int, K: int, nl: int, counted: bool,
+                     seed_offs: tuple, delta: int, cap: int):
+    """Refs, in order: the scalar-prefetch (B*S,) int32 tables in SMEM —
+    each mate's flattened row starts and, when ``counted``, its row
+    counts —; the (n, 128) int32 row-table lines in HBM (ANY); the
+    outputs (pos1, pos2 (BLK, C); n, nh1, nh2 (BLK, 1)); the scratch
+    location banks (N_BANKS, 2, S, BLK*nl, 128) int32 VMEM and their
+    (N_BANKS, 2) DMA semaphores."""
+    n_tables = 4 if counted else 2
+    starts = refs[0:2]
+    counts = refs[2:4] if counted else None
+    table_any = refs[n_tables]
+    pos1_ref, pos2_ref, n_ref, nh1_ref, nh2_ref = refs[n_tables + 1:
+                                                      n_tables + 6]
+    loc, sems = refs[n_tables + 6:]
     BLK = pos1_ref.shape[0]
     g = pl.program_id(0)
     nsteps = pl.num_programs(0)
     bank = jax.lax.rem(g, N_BANKS)
-    starts = (sdma1_ref, sdma2_ref)
 
     # ---- ping-pong row streaming HBM -> VMEM (candidate_align protocol) --
     # Row (r, s)'s `nl` covering lines land in rows [r*nl, r*nl + nl).
@@ -286,16 +292,24 @@ def _frontend_kernel(
 
     _wait_step(g, bank)          # this step's rows are now resident
 
+    # A padded row starts at a multiple of K; a CSR row at any lane.
+    align = 1 if counted else math.gcd(K, LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+
     def row_locs(mate, r):
         """(1, S*K) seed-major locations of row r: each seed's row cut at
-        its lane offset out of its covering lines."""
+        its lane offset out of its covering lines, and, counted, the
+        lanes past its count (the next bucket's) set to INVALID_LOC."""
         cuts = []
         for s in range(S):
             lines = loc[bank, mate, s, pl.ds(r * nl, nl), :]   # (nl, 128)
             line = jnp.concatenate([lines[q:q + 1] for q in range(nl)],
                                    axis=1) if nl > 1 else lines
-            st = starts[mate][(g * BLK + r) * S + s]
-            cuts.append(cut_lanes(line, st % LANES, K, math.gcd(K, LANES)))
+            i = (g * BLK + r) * S + s
+            row = cut_lanes(line, starts[mate][i] % LANES, K, align)
+            if counted:
+                row = jnp.where(lane < counts[mate][i], row, INVALID_LOC)
+            cuts.append(row)
         return jnp.concatenate(cuts, axis=1)
 
     _merge_filter_rows(
@@ -305,18 +319,24 @@ def _frontend_kernel(
 
 
 def pair_frontend_pallas(
-    table: jnp.ndarray,          # (n, 128) int32 padded-row-table lines
-    sdma1: jnp.ndarray,          # (B*S,) int32 row offsets (bucket * K)
+    table: jnp.ndarray,          # (n, 128) int32 row-table lines
+    sdma1: jnp.ndarray,          # (B*S,) int32 flattened row starts
     sdma2: jnp.ndarray,
     seed_offs: tuple,            # static per-seed read offsets
     K: int,
     delta: int,
     max_candidates: int,
+    counts: tuple | None = None,  # CSR: ((B*S,), (B*S,)) int32 row counts
     block: int = DEFAULT_BLOCK,
     interpret: bool = False,
 ):
     """B must be a multiple of `block` (ops.py pads and chunks launches to
     <= LAUNCH_ROWS rows so the SMEM DMA tables stay bounded).
+
+    Padded lines (``counts`` None): row starts are ``bucket * K`` and
+    rows are INVALID_LOC padded.  CSR lines: row starts are
+    ``offsets[bucket]``, and the lanes at or past each row's count are
+    masked.
 
     Returns (pos1, pos2) (B, C) and (n, n_hits1, n_hits2) (B,) int32.
     """
@@ -324,10 +344,12 @@ def pair_frontend_pallas(
     B = sdma1.shape[0] // S
     assert B % block == 0, (B, block)
     C = max_candidates
-    nl = lines_spanned(K, K)
+    counted = counts is not None
+    nl = lines_spanned(K, 1 if counted else K)
+    tables = (sdma1, sdma2) + (tuple(counts) if counted else ())
     row_spec = lambda cols: pl.BlockSpec((block, cols), lambda i, *_: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(tables),
         grid=(B // block,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[row_spec(C), row_spec(C),
@@ -339,13 +361,14 @@ def pair_frontend_pallas(
     )
     outs = pl.pallas_call(
         functools.partial(_frontend_kernel, S=S, K=K, nl=nl,
-                          seed_offs=tuple(seed_offs), delta=delta, cap=C),
+                          counted=counted, seed_offs=tuple(seed_offs),
+                          delta=delta, cap=C),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, C), jnp.int32)] * 2
         + [jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 3,
         name=NAME,
         interpret=interpret,
-    )(sdma1, sdma2, table)
+    )(*tables, table)
     pos1, pos2, n, nh1, nh2 = outs
     return pos1, pos2, n[:, 0], nh1[:, 0], nh2[:, 0]
 

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the fused pipeline front end.
 
 `pair_frontend` is the one-call steps-1-3 hot path: seed hashing +
-padded-row SeedMap lookup + sorted merge + Paired-Adjacency filter +
-front compaction, behind the standard ``backend`` switch resolved by
+SeedMap row gather (CSR or padded lines) + sorted merge +
+Paired-Adjacency filter + front compaction, behind the standard ``backend`` switch resolved by
 `kernels/backend.py`.  The jnp backend is the bit-exact staged oracle
 (`ref.py`, which routes through `core.seeding` / `core.query` /
 `core.pair_filter`); the pallas/interpret backends run the two fused
@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.seeding import seed_offsets_tuple
-from repro.core.seedmap import LinedSeedMap
+from repro.core.seedmap import LinedCSRSeedMap, LinedSeedMap, SeedMap
 from repro.kernels._util import (
     chunked_launch,
     lines_spanned,
@@ -51,7 +51,7 @@ from repro.kernels.pair_frontend.ref import (
                      "max_candidates", "block", "backend"),
 )
 def pair_frontend(
-    rows,                    # (T, K) int32 padded rows, or a LinedSeedMap
+    index,                   # LinedCSRSeedMap, LinedSeedMap or (T, K) rows
     reads1: jnp.ndarray,     # (B, R) mate 1, reference orientation
     reads2: jnp.ndarray,     # (B, R) mate 2, reference orientation
     seed_len: int,
@@ -64,11 +64,12 @@ def pair_frontend(
 ) -> FrontendResult:
     """Fused front end for a batch of read pairs.
 
-    ``rows`` is the bucket-major padded Location Table (`to_padded(sm).rows`
-    or the in-jit CSR derivation in `core/pipeline.py`), or the same table
-    as a `LinedSeedMap` — the layout the kernels DMA from, which a session
-    places once (laying out (T, K) rows in-jit is a relayout: test scales
-    only).  Its row width K caps the locations per seed.  Both reads are
+    ``index`` is a device layout a session places once — the CSR tables
+    in lines (`LinedCSRSeedMap`) or the bucket-major padded rows in
+    lines (`LinedSeedMap`) — or plain (T, K) padded rows
+    (`to_padded(sm).rows`; laying those out is a relayout in-jit: test
+    scales only).  Its row width K (``config.padded_cap``) caps the
+    locations per seed.  Both reads are
     expected in reference orientation (mate 2 pre-revcomp'd, as
     everywhere in the pipeline).
     ``block=None`` resolves to `DEFAULT_BLOCK`; the autotuner
@@ -77,19 +78,26 @@ def pair_frontend(
     """
     backend = resolve_backend(backend, family="pair_frontend")
     block = block or DEFAULT_BLOCK
-    if isinstance(rows, LinedSeedMap):
-        T, K = rows.config.table_size, rows.config.padded_cap
-        table = rows.lines
-        rows = table.reshape(-1)[:T * K].reshape(T, K)   # jnp oracle only
+    csr = isinstance(index, LinedCSRSeedMap)
+    if isinstance(index, (LinedCSRSeedMap, LinedSeedMap)):
+        T, K = index.config.table_size, index.config.padded_cap
+        table = index.lines
     else:
-        T, K = rows.shape
+        T, K = index.shape
         table = None
     if backend == "jnp":
-        return pair_frontend_ref(rows, reads1, reads2, seed_len,
+        if csr:        # the oracle queries the CSR tables (`query_csr`)
+            oracle = SeedMap(offsets=index.offsets,
+                             locations=table.reshape(-1),
+                             config=index.config)
+        else:
+            oracle = (table.reshape(-1)[:T * K].reshape(T, K)
+                      if table is not None else index)
+        return pair_frontend_ref(oracle, reads1, reads2, seed_len,
                                  seeds_per_read, hash_seed, delta,
-                                 max_candidates)
+                                 max_candidates, cap=K)
     if table is None:
-        table = to_lines(rows.reshape(-1), lines_spanned(K, K))
+        table = to_lines(index.reshape(-1), lines_spanned(K, K))
     interpret = backend == "interpret"
     B, R = reads1.shape
     offs = seed_offsets_tuple(R, seed_len, seeds_per_read)
@@ -103,18 +111,36 @@ def pair_frontend(
         interpret=interpret)[:n]
 
     # -- kernel 2: row gather + merge + filter ----------------------------
-    # Scalar-prefetch tables hold flattened row offsets into the line
-    # table, 1-D per launch; padding rows aim at bucket 0 (a safe
-    # in-bounds DMA) and are sliced off below.
+    # Scalar-prefetch tables hold flattened row starts into the line
+    # table (and, CSR, row counts), 1-D per launch; padding rows aim at
+    # element 0 (a safe in-bounds DMA) and are sliced off below.
     total, rows_per = chunked_launch(B, block, LAUNCH_ROWS)
-    sdma1 = pad_rows(buckets[:B] * K, total)
-    sdma2 = pad_rows(buckets[B:] * K, total)
+
+    def tables(x):
+        return (pad_rows(x[:B], total).reshape(-1),
+                pad_rows(x[B:], total).reshape(-1))
+
+    cnt = None
+    if csr:
+        # Both ends of every seed's bucket in one gather from the Seed
+        # Table; the tables made from them stay under the scope.
+        with jax.named_scope("index_offsets"):
+            ends = index.offsets[jnp.stack([buckets, buckets + 1])]
+            count = jnp.minimum(ends[1] - ends[0], K)
+            # An empty bucket may start at the table's end: aim it at 0.
+            sdma1, sdma2 = tables(jnp.where(count > 0, ends[0], 0))
+            cnt = tables(count)
+    else:
+        sdma1, sdma2 = tables(buckets * K)
+    per = rows_per * len(offs)
     parts = [
         pair_frontend_pallas(
-            table, sdma1[s:s + rows_per].reshape(-1),
-            sdma2[s:s + rows_per].reshape(-1), offs, K,
-            delta, max_candidates, block=block, interpret=interpret)
-        for s in range(0, total, rows_per)
+            table, sdma1[s:s + per], sdma2[s:s + per], offs, K, delta,
+            max_candidates,
+            counts=None if cnt is None else (cnt[0][s:s + per],
+                                             cnt[1][s:s + per]),
+            block=block, interpret=interpret)
+        for s in range(0, total * len(offs), per)
     ]
     outs = [jnp.concatenate(cols) if len(parts) > 1 else cols[0]
             for cols in zip(*parts)]
@@ -124,7 +150,7 @@ def pair_frontend(
 
 
 def segment_pair_frontend(
-    rows,                    # (T, K) int32 padded rows, or a LinedSeedMap
+    index,                   # as `pair_frontend`
     reads: jnp.ndarray,      # (B, L) long reads, reference orientation
     segment_len: int,
     segment_stride: int,
@@ -155,7 +181,7 @@ def segment_pair_frontend(
     B, S, R = segs.shape
     r1 = segs[:, :-1].reshape(B * (S - 1), R)
     r2 = segs[:, 1:].reshape(B * (S - 1), R)
-    return pair_frontend(rows, r1, r2, seed_len, seeds_per_read, hash_seed,
+    return pair_frontend(index, r1, r2, seed_len, seeds_per_read, hash_seed,
                          delta, max_candidates, block=block, backend=backend)
 
 

@@ -2,7 +2,8 @@
 
 This is the pipeline front end (steps 1-3 of `map_pairs`) exactly as the
 core modules write it: Partitioned Seeding (`core.seeding`), padded-row
-SeedMap lookup (`core.query`-style row gather + `merge_read_starts`), and
+SeedMap lookup (a padded-row gather, or `core.query.query_csr` on the CSR
+tables, + `merge_read_starts`), and
 Paired-Adjacency Filtering (`core.pair_filter`).  The Pallas kernels in
 `kernel.py` must match this path bit-for-bit; `map_pairs` results are
 pinned against it.
@@ -18,8 +19,9 @@ from typing import NamedTuple
 import jax.numpy as jnp
 
 from repro.core.pair_filter import paired_adjacency_filter
-from repro.core.query import QueryResult, merge_read_starts
+from repro.core.query import QueryResult, merge_read_starts, query_csr
 from repro.core.seeding import extract_seeds, hash_seeds, seed_offsets
+from repro.core.seedmap import SeedMap
 
 
 class FrontendResult(NamedTuple):
@@ -48,19 +50,24 @@ def seed_buckets_ref(reads: jnp.ndarray, seed_len: int, seeds_per_read: int,
     return (hashes & jnp.uint32(table_size - 1)).astype(jnp.int32)
 
 
-def query_rows(rows: jnp.ndarray, buckets: jnp.ndarray,
-               offsets: jnp.ndarray) -> QueryResult:
-    """Padded-row lookup + sorted merge for one mate.
+def query_rows(index, buckets: jnp.ndarray, offsets: jnp.ndarray,
+               cap: int | None = None) -> QueryResult:
+    """SeedMap lookup + sorted merge for one mate.
 
-    rows: (T, K) int32 INVALID_LOC-padded location rows (`to_padded` / the
-    in-jit CSR derivation); buckets: (B, S) int32; offsets: (S,) int32.
+    index: (T, K) int32 INVALID_LOC-padded location rows (`to_padded` /
+    the in-jit CSR derivation), or a CSR `SeedMap`, queried as
+    `query_csr` at ``cap`` locations a seed; buckets: (B, S) int32;
+    offsets: (S,) int32.
     """
-    locs = rows[buckets]                       # (B, S, K)
+    if isinstance(index, SeedMap):
+        locs, _ = query_csr(index, buckets.astype(jnp.uint32), cap)
+    else:
+        locs = index[buckets]                  # (B, S, K)
     return merge_read_starts(locs, offsets)
 
 
 def pair_frontend_ref(
-    rows: jnp.ndarray,       # (T, K) int32 padded location rows
+    index,                   # (T, K) int32 padded rows, or a CSR SeedMap
     reads1: jnp.ndarray,     # (B, R) mate 1, reference orientation
     reads2: jnp.ndarray,     # (B, R) mate 2, reference orientation (revcomp'd)
     seed_len: int,
@@ -68,15 +75,17 @@ def pair_frontend_ref(
     hash_seed: int,
     delta: int,
     max_candidates: int,
+    cap: int | None = None,  # locations a seed on a CSR index
 ) -> FrontendResult:
-    """Staged front end: seeding -> padded lookup -> merge -> Δ filter."""
-    T = rows.shape[0]
+    """Staged front end: seeding -> lookup -> merge -> Δ filter."""
+    T = (index.config.table_size if isinstance(index, SeedMap)
+         else index.shape[0])
     R = reads1.shape[1]
     offs = seed_offsets(R, seed_len, seeds_per_read)
     b1 = seed_buckets_ref(reads1, seed_len, seeds_per_read, hash_seed, T)
     b2 = seed_buckets_ref(reads2, seed_len, seeds_per_read, hash_seed, T)
-    q1 = query_rows(rows, b1, offs)
-    q2 = query_rows(rows, b2, offs)
+    q1 = query_rows(index, b1, offs, cap)
+    q2 = query_rows(index, b2, offs, cap)
     cands = paired_adjacency_filter(q1, q2, delta, max_candidates)
     return FrontendResult(pos1=cands.pos1, pos2=cands.pos2, n=cands.n,
                           n_hits1=q1.n_hits, n_hits2=q2.n_hits)

@@ -12,7 +12,9 @@ interpret-mode and oracle tests' business.
 Two lowerings of the whole fused stream step (pairs and long reads)
 pin the names a device profile is read by: every stage's
 `jax.named_scope` in the op locations, and each kernel family's stable
-``name=`` on its custom calls.
+``name=`` on its custom calls.  The CSR row gather compiles at the
+shapes of chip 0 of a whole-genome deployment (chr1-chr3, 2^28
+buckets), with its ``index_offsets`` scope.
 
 The topology is described inside a module fixture, never at import, so
 every test worker collects the same tests and only the worker that runs
@@ -120,6 +122,45 @@ def test_pair_frontend_compiles(sds):
                                                 C),
         _lines(sds, (1 << TABLE_BITS) * K, K, K),
         sds((rows * S,)), sds((rows * S,)))
+
+
+#: chip 0 of a four-chip whole-genome deployment: chr1-chr3 on 2^28 buckets
+CHR1TO3_LEN = 689_445_510
+CHR1TO3_TABLE_BITS = 28
+
+
+def test_pair_frontend_csr_compiles(sds):
+    """The CSR row gather: four scalar-prefetch tables (each mate's row
+    starts and counts) and two-line row DMAs from chr1-chr3's locations."""
+    rows = pf.LAUNCH_ROWS
+    tables = [sds((rows * S,)) for _ in range(4)]
+    _assert_compiles(
+        lambda t, a, b, c, d: pf.pair_frontend_pallas(
+            t, a, b, OFFS, K, CFG.delta, C, counts=(c, d)),
+        _lines(sds, CHR1TO3_LEN, K), *tables)
+
+
+def test_csr_frontend_op_carries_index_offsets(sds):
+    """The whole CSR front-end op at the chr1-chr3 configuration's
+    shapes (2^28 buckets, B = 4096) compiles; its Seed Table lookup is
+    under the ``index_offsets`` scope and its kernels keep their name."""
+    import dataclasses
+
+    from repro.core.seedmap import LinedCSRSeedMap, SeedMapConfig
+    from repro.kernels.pair_frontend.ops import pair_frontend
+
+    smc = dataclasses.replace(
+        SeedMapConfig(table_bits=CHR1TO3_TABLE_BITS), padded_cap=K)
+    index = LinedCSRSeedMap(offsets=sds(((1 << CHR1TO3_TABLE_BITS) + 1,)),
+                            lines=_lines(sds, CHR1TO3_LEN, K), config=smc)
+    reads = sds((4096, R), jnp.uint8)
+    lowered = jax.jit(lambda idx, a, b: pair_frontend(
+        idx, a, b, CFG.seed_len, S, 0, CFG.delta, C,
+        backend="pallas")).lower(index, reads, reads)
+    text = lowered.as_text(debug_info=True)
+    assert _has_scope(text, "index_offsets")
+    assert _kernel_names(text) == {"pair_frontend"}
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 def test_merge_filter_compiles(sds):

@@ -4,7 +4,7 @@ Covers the ISSUE-4 acceptance points that run on one device:
   * `Mapper.map` is bit-identical to pre-refactor `map_pairs` on both the
     jnp-oracle and interpret-kernel backends;
   * CSR `SeedMap` -> `PaddedSeedMap` relayout round-trips (property test
-    vs the in-jit `padded_rows_device` derivation `map_pairs` uses);
+    vs the in-jit `padded_rows_device` derivation);
   * ragged tail batches flow through `map_stream` as padding + an
     `n_valid` mask, and the device-side stage totals/reductions exclude
     the padded rows;

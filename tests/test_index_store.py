@@ -17,7 +17,10 @@ Pins the ISSUE-9 acceptance points that run on one device:
     no accepted request lost;
   * `engine.multihost.map_stream` degrades to the single-host loop at
     ``process_count() == 1`` (the two-process path is
-    tests/test_multihost.py).
+    tests/test_multihost.py);
+  * a kernel session on a device too small for padded rows saves and
+    loads its CSR lines through the "csr" layout, and a padded store
+    loads into such a session converted to CSR.
 """
 import json
 import os
@@ -337,3 +340,62 @@ def test_save_store_rejects_unknown_index(world, tmp_path):
     with pytest.raises(TypeError, match="cannot persist"):
         save_store(tmp_path / "x", index=object(), ref=np.asarray(ref),
                    pipe_cfg=mapper.pipe_cfg, sm_config=mapper.sm_config)
+
+
+# ------------------------------------------------- CSR kernel sessions ---
+def _interpret_cfg():
+    """The front end, which reads the index layout, on its kernel."""
+    return PipelineConfig(frontend_backend="interpret", light_backend="jnp",
+                          residual_backend="jnp")
+
+
+@pytest.fixture
+def small_device(monkeypatch):
+    """A device whose memory cannot hold the padded rows (2^15 buckets x
+    32 x 4 B = 4 MiB, over half of 4 MiB): kernel sessions take CSR."""
+    monkeypatch.setattr("repro.engine.mapper._bytes_limit",
+                        lambda mesh: 4 * 2**20)
+
+
+def test_csr_kernel_session_round_trips(world, tmp_path, small_device):
+    """A CSR kernel session saves the store's "csr" layout and loads
+    back into the same CSR lines, mapping bit-identically."""
+    from repro.core.seedmap import LinedCSRSeedMap, SeedMap
+
+    ref, sim, _ = world
+    sm = build_seedmap(ref, SeedMapConfig(table_bits=TB))
+    mapper = Mapper.from_index(sm, ref, _interpret_cfg())
+    assert isinstance(mapper._state[0], LinedCSRSeedMap)
+    store = tmp_path / "store"
+    mapper.save(store)
+    with open(store / MANIFEST) as f:
+        assert json.load(f)["layout"] == "csr"
+    loaded = Mapper.load(store)
+    assert isinstance(loaded.index, SeedMap)
+    assert isinstance(loaded._state[0], LinedCSRSeedMap)
+    _assert_same(mapper.map(sim.reads1[:4], sim.reads2[:4]),
+                 loaded.map(sim.reads1[:4], sim.reads2[:4]))
+    assert loaded.pipe_cfg == mapper.pipe_cfg
+
+
+def test_padded_store_loads_into_a_csr_session(world, tmp_path,
+                                               monkeypatch):
+    """A store saved in the padded layout loads into a session that
+    cannot hold padded rows: converted to CSR at the same row width, it
+    maps as the padded session that saved it."""
+    from repro.core.seedmap import LinedCSRSeedMap
+
+    ref, sim, _ = world
+    sm = build_seedmap(ref, SeedMapConfig(table_bits=TB))
+    padded = Mapper.from_index(to_padded(sm, cap=16), ref, _interpret_cfg())
+    store = tmp_path / "store"
+    padded.save(store)
+    with open(store / MANIFEST) as f:
+        assert json.load(f)["layout"] == "padded"
+    monkeypatch.setattr("repro.engine.mapper._bytes_limit",
+                        lambda mesh: 2**20)
+    loaded = Mapper.load(store)
+    assert isinstance(loaded._state[0], LinedCSRSeedMap)
+    assert loaded.pipe_cfg.max_locs_per_seed == 16
+    _assert_same(padded.map(sim.reads1[:4], sim.reads2[:4]),
+                 loaded.map(sim.reads1[:4], sim.reads2[:4]))

@@ -6,7 +6,8 @@
     are counted once in the seconds (interval union);
   * the trace counter bumps once per (re)trace of a jitted body, not per
     call;
-  * ``reset()`` empties every table;
+  * ``reset()`` empties every table; a counter keeps its latest value;
+  * placing a session's index sets the layout and device-byte counters;
   * a 3-batch interpret-mode `map_stream` opens one ``stream.dispatch``
     per batch, its warm-up holds the step's trace and lowering, the
     fused step traced once, and its results match ``mapper.map``;
@@ -158,8 +159,17 @@ def test_reset_empties_every_table():
     with spans.span("s"):
         jax.jit(_fresh_fn(17.0))(jnp.arange(2.0)).block_until_ready()
     spans.note_trace("k")
+    spans.set_counter("c", 3)
     spans.reset()
-    assert spans.snapshot() == {"spans": {}, "traces": {}}
+    assert spans.snapshot() == {"spans": {}, "traces": {}, "counters": {}}
+
+
+def test_counter_keeps_the_latest_value():
+    spans.set_counter("session.index_bytes", 10)
+    spans.set_counter("session.index_bytes", 7)
+    spans.set_counter("session.index_layout", "csr_lines")
+    assert spans.snapshot()["counters"] == {
+        "session.index_bytes": 7, "session.index_layout": "csr_lines"}
 
 
 # ------------------------------------------------------- the engine ------
@@ -229,3 +239,26 @@ def test_frontdoor_opens_door_spans(tiny_world):
     assert table["door.dispatch"]["count"] == 3
     assert table["door.retire"]["count"] == 3
     assert table["door.form_batch"]["count"] == 3
+
+
+@pytest.mark.parametrize("limit,layout", [(None, "padded_lines"),
+                                          (2**20, "csr_lines")])
+def test_placement_counts_index_layout_and_bytes(tiny_world, monkeypatch,
+                                                 limit, layout):
+    """Placing a kernel session's index sets the layout and device-byte
+    counters: padded lines where the rows fit the device, else the
+    offsets plus the CSR location lines."""
+    ref, sm, _ = tiny_world
+    monkeypatch.setattr("repro.engine.mapper._bytes_limit",
+                        lambda mesh: limit)
+    mapper = Mapper.from_index(sm, ref, PipelineConfig(
+        frontend_backend="interpret"))
+    index = mapper._state[0]
+    counters = spans.snapshot()["counters"]
+    assert counters["session.index_layout"] == layout
+    assert counters["session.index_bytes"] == sum(
+        int(x.nbytes) for x in jax.tree.leaves(index))
+    if layout == "csr_lines":
+        assert counters["session.index_bytes"] == (
+            sm.offsets.nbytes + index.lines.nbytes)
+        assert index.lines.size >= sm.locations.size
