@@ -70,9 +70,10 @@ FAMILIES = ("pair_frontend", "candidate_align", "residual_dp",
             "location_vote")
 
 #: Launch-block grids per family (the hand-picked default is always a
-#: candidate; see each family's kernel.py DEFAULT_BLOCK).
+#: candidate; see each family's kernel.py DEFAULT_BLOCK).  The front
+#: end puts a read on each lane, so its blocks are multiples of 128.
 BLOCK_GRID = {
-    "pair_frontend": (8, 16, 32),
+    "pair_frontend": (128, 256),
     "candidate_align": (8, 16, 32),
     "residual_dp": (16, 32, 64),
     "location_vote": (32, 64, 128),

@@ -170,6 +170,46 @@ def test_merge_filter_compiles(sds):
         sds((rows, S * K)), sds((rows, S * K)))
 
 
+def _frontend_entry(sds, entry: str, block: int):
+    """(fn, args) of one front-end kernel entry at chr1 (padded,
+    merge-only) or chr1-chr3 (CSR) launch shapes."""
+    rows = pf.LAUNCH_ROWS
+    kw = dict(block=block)
+    if entry == "padded":
+        return (lambda t, a, b: pf.pair_frontend_pallas(
+            t, a, b, OFFS, K, CFG.delta, C, **kw),
+            (_lines(sds, (1 << TABLE_BITS) * K, K, K), sds((rows * S,)),
+             sds((rows * S,))))
+    if entry == "csr":
+        return (lambda t, a, b, c, d: pf.pair_frontend_pallas(
+            t, a, b, OFFS, K, CFG.delta, C, counts=(c, d), **kw),
+            (_lines(sds, CHR1TO3_LEN, K),
+             *[sds((rows * S,)) for _ in range(4)]))
+    return (lambda a, b: pf.merge_filter_pallas(a, b, OFFS, K, CFG.delta, C,
+                                                **kw),
+            (sds((rows, S * K)), sds((rows, S * K))))
+
+
+FRONTEND_ENTRIES = ["padded", "csr", "merge"]
+
+
+@pytest.mark.parametrize("entry", FRONTEND_ENTRIES)
+def test_frontend_compiles_at_tuned_block(sds, entry):
+    """Each front-end entry at the tuner's other block (256 reads a step,
+    `repro.tune.BLOCK_GRID`)."""
+    fn, args = _frontend_entry(sds, entry, 256)
+    _assert_compiles(fn, *args)
+
+
+@pytest.mark.parametrize("entry", FRONTEND_ENTRIES)
+def test_frontend_refuses_block_off_lanes(sds, entry):
+    """Reads lie along lanes: a block that is not a multiple of 128 is
+    refused before Mosaic sees it."""
+    fn, args = _frontend_entry(sds, entry, 64)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        jax.jit(fn).lower(*args)
+
+
 @pytest.mark.parametrize("packed,prescreen_top", [
     (False, 0), (True, 0), (False, 4)],
     ids=["unpacked", "packed", "prescreen4"])

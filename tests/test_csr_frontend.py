@@ -58,15 +58,21 @@ def _genome_world(seed: int, n_ref=40_000, table_bits=12, max_locations=60,
 
 
 # ------------------------------------------------ seeded random genomes --
-@pytest.mark.parametrize("seed,K", [(1, 8), (2, 32), (3, 16)])
-def test_csr_gather_matches_oracle_and_padded(seed, K):
-    _, sm, r1, r2 = _genome_world(seed)
+@pytest.mark.parametrize("seed,K,block,n", [
+    pytest.param(1, 8, 4, 12, id="1-8"),
+    pytest.param(2, 32, 4, 12, id="2-32"),
+    pytest.param(3, 16, 4, 12, id="3-16"),
+    # a lane-wide block over a batch that is not a multiple of it
+    pytest.param(7, 32, 128, 130, id="7-32-block128"),
+])
+def test_csr_gather_matches_oracle_and_padded(seed, K, block, n):
+    _, sm, r1, r2 = _genome_world(seed, n=n)
     csr = to_lined_csr(sm, K)
-    got = pair_frontend(csr, r1, r2, backend="interpret", block=4, **FE)
+    got = pair_frontend(csr, r1, r2, backend="interpret", block=block, **FE)
     _assert_same(got, pair_frontend(csr, r1, r2, backend="jnp", **FE),
                  "vs oracle")
     _assert_same(got, pair_frontend(to_lined(to_padded(sm, cap=K)), r1, r2,
-                                    backend="interpret", block=4, **FE),
+                                    backend="interpret", block=block, **FE),
                  "vs padded")
     assert int(np.asarray(got.n).sum()) > 0
 
@@ -137,8 +143,13 @@ def _edge_table(T: int, K: int, rng) -> SeedMap:
                    config=cfg)
 
 
-@pytest.mark.parametrize("K", [4, 8, 32])
-def test_edge_table_matches_oracle_and_padded(K):
+@pytest.mark.parametrize("K,block", [
+    pytest.param(4, 4, id="4"), pytest.param(8, 4, id="8"),
+    pytest.param(32, 4, id="32"),
+    # rows of 0, 1, K - 1, K and more than K locations, 128 reads a step
+    pytest.param(32, 128, id="32-block128"),
+])
+def test_edge_table_matches_oracle_and_padded(K, block):
     rng = np.random.default_rng(K)
     T = 64
     sm = _edge_table(T, K, rng)
@@ -152,11 +163,11 @@ def test_edge_table_matches_oracle_and_padded(K):
     assert {0, 1, K - 1, K} <= set(counts[b].tolist())
     assert (counts[b] > K).any()
     csr = to_lined_csr(sm, K)
-    got = pair_frontend(csr, r1, r2, backend="interpret", block=4, **FE)
+    got = pair_frontend(csr, r1, r2, backend="interpret", block=block, **FE)
     _assert_same(got, pair_frontend(csr, r1, r2, backend="jnp", **FE),
                  "vs oracle")
     _assert_same(got, pair_frontend(to_lined(to_padded(sm, cap=K)), r1, r2,
-                                    backend="interpret", block=4, **FE),
+                                    backend="interpret", block=block, **FE),
                  "vs padded")
 
 

@@ -108,14 +108,26 @@ def _frontend_world(s, k, c, seed, t=64, b=12, r=64, seed_len=16,
     return jnp.asarray(rows), jnp.asarray(reads1), jnp.asarray(reads2)
 
 
-@pytest.mark.parametrize("s,k,delta,c", [
-    (1, 4, 30, 2), (2, 4, 0, 4), (3, 8, 30, 4), (3, 4, 500, 8), (2, 8, 5, 2),
+@pytest.mark.parametrize("s,k,delta,c,world", [
+    pytest.param(*case, {}, id="-".join(map(str, case))) for case in
+    [(1, 4, 30, 2), (2, 4, 0, 4), (3, 8, 30, 4), (3, 4, 500, 8), (2, 8, 5, 2)]
+] + [
+    # a lane-wide block over a batch that is not a multiple of it, at the
+    # chr1 merge width M = 96 (not a power of two: 32 sentinel sublanes)
+    pytest.param(3, 32, 500, 8, dict(block=128, b=130), id="block128-b130"),
+    # M = 192 > 128: a 256-sublane sorting network
+    pytest.param(3, 64, 60, 4, {}, id="m192"),
+    # locations under the seed offsets (24, 48): negative starts
+    pytest.param(3, 8, 30, 4, dict(lo_hi=40), id="negative-starts"),
 ])
-def test_fused_frontend_matches_staged_oracle(s, k, delta, c):
-    rows, r1, r2 = _frontend_world(s, k, c, seed=s * 100 + k + delta + c)
+def test_fused_frontend_matches_staged_oracle(s, k, delta, c, world):
+    world = dict(world)
+    block = world.pop("block", 4)
+    rows, r1, r2 = _frontend_world(s, k, c, seed=s * 100 + k + delta + c,
+                                   **world)
     kw = dict(seed_len=16, seeds_per_read=s, hash_seed=0, delta=delta,
               max_candidates=c)
-    got = pair_frontend(rows, r1, r2, backend="interpret", block=4, **kw)
+    got = pair_frontend(rows, r1, r2, backend="interpret", block=block, **kw)
     want = pair_frontend(rows, r1, r2, backend="jnp", **kw)
     _assert_same(got, want, f"S={s} K={k} d={delta} C={c}")
 
@@ -166,6 +178,67 @@ def test_merge_filter_matches_staged(s=3, k=4):
         want = frontend_merge_filter(jnp.asarray(locs1), jnp.asarray(locs2),
                                      offs, delta, c, backend="jnp")
         _assert_same(got, want, f"delta={delta} C={c}")
+
+
+def _edge_locs(case, rng, b, s, k, offs):
+    """(locs1, locs2) (b, s, k) int32 for one merge/filter edge case."""
+    if case == "same-start":        # every seed of every read: one start
+        base = rng.integers(100, 1000, (b, 1, 1))
+        l1 = base + np.asarray(offs)[None, :, None] + np.zeros((1, 1, k), int)
+        return l1, l1 + rng.integers(-3, 4, (b, 1, 1))
+    if case == "clip":
+        # every read-1 start 5; read 2: the first seed no hits, the rest
+        # starting under 5 - Δ (negative) but two near 5: lo + occ runs
+        # past M - 1 and must clip there, to the sentinel, not probe a
+        # sublane past M
+        l1 = 5 + np.asarray(offs)[None, :, None] + np.zeros((b, 1, k), int)
+        l2 = np.stack([np.full((b, k), INVALID_LOC, np.int64)] + [
+            rng.integers(0, off - 15, (b, k)) for off in offs[1:]], axis=1)
+        l2[:, -1, -2:] = 5 + offs[-1] + rng.integers(-3, 4, (b, 2))
+        return l1, l2
+    if case == "overflow":          # dense: far more than C survivors
+        return rng.integers(0, 40, (b, s, k)), rng.integers(0, 40, (b, s, k))
+    if case == "negative":          # locations under the seed offsets
+        return rng.integers(0, 30, (b, s, k)), rng.integers(0, 30, (b, s, k))
+    l1 = rng.integers(0, 150, (b, s, k))
+    l2 = rng.integers(0, 150, (b, s, k))
+    l1[rng.random((b, s, k)) < 0.3] = INVALID_LOC
+    l1[::3] = INVALID_LOC           # "mixed": every third read no hits
+    l2[1::4] = INVALID_LOC
+    return l1, l2
+
+
+@pytest.mark.parametrize("case,s,k,delta,c,block,b", [
+    ("same-start", 3, 32, 500, 8, 4, 9),
+    ("clip", 3, 8, 10, 4, 4, 8),
+    ("clip", 3, 32, 10, 8, 128, 130),
+    ("overflow", 3, 8, 50, 4, 4, 12),
+    ("negative", 3, 8, 10, 4, 4, 12),
+    ("mixed", 3, 64, 60, 8, 4, 12),          # M = 192 > 128
+    ("mixed", 3, 32, 60, 8, 128, 130),       # batch not a multiple of 128
+])
+def test_merge_filter_edge_cases(case, s, k, delta, c, block, b):
+    """The post-query entry against the oracle where the lane-layout
+    merge/filter could slip: equal starts, the probe's clip at M - 1,
+    overflow past C, negative starts, rows with no hits, M past one
+    vreg column and a lane-wide block."""
+    rng = np.random.default_rng(len(case) * 100 + k + b)
+    offs = tuple(int(o) for o in seed_offsets_np(64, 16, s))
+    l1, l2 = (jnp.asarray(x.astype(np.int32))
+              for x in _edge_locs(case, rng, b, s, k, offs))
+    got = frontend_merge_filter(l1, l2, offs, delta, c, block=block,
+                                backend="interpret")
+    want = frontend_merge_filter(l1, l2, offs, delta, c, backend="jnp")
+    _assert_same(got, want, case)
+    n = np.asarray(want.n)
+    if case in ("clip", "overflow", "same-start"):
+        assert (n > 0).all()
+    if case == "overflow":
+        assert (n == c).all()
+    if case == "same-start":
+        assert (n == 1).all()
+    if case == "mixed":
+        assert (np.asarray(want.n_hits1)[::3] == 0).all() and n.any()
 
 
 def test_cap_exceeds_merge_width():

@@ -38,7 +38,7 @@ def world():
     return ref, sm, sim
 
 
-def _entries_for(batch, *, prescreen=4, packed=True, fe_block=8,
+def _entries_for(batch, *, prescreen=4, packed=True, fe_block=128,
                  la_block=16, rd_block=32):
     """Hand-made cache entries keyed for this session's resolved
     backends/buckets (CPU CI: every family resolves to jnp)."""
@@ -80,7 +80,7 @@ def test_mapper_build_picks_up_tuned_knobs(world, tmp_path):
     cfg = mapper.pipe_cfg
     assert cfg.prescreen_top == 4 and cfg.prescreen() == 4
     assert cfg.packed_ref is True
-    assert cfg.frontend_block == 8
+    assert cfg.frontend_block == 128
     assert cfg.light_block == 16
     assert cfg.residual_block == 32
     # ...and the tuned session still maps: same positions as an untuned
@@ -229,6 +229,30 @@ def test_tune_session_winners_never_lose_to_staged(world, tmp_path):
             key, e)
     # and the written cache is immediately consumable
     assert load_cache(tmp_path / "tc.json") == entries
+
+
+def test_frontend_block_sweep_runs_chip_blocks(world, tmp_path):
+    """The front end's block sweep offers only blocks the chip takes (a
+    read on each lane: multiples of 128, the default among them), and
+    every one of them runs: none is dropped as a failed candidate."""
+    from repro.kernels._util import LANES
+    from repro.kernels.pair_frontend.kernel import DEFAULT_BLOCK
+    from repro.tune import BLOCK_GRID
+
+    grid = BLOCK_GRID["pair_frontend"]
+    assert DEFAULT_BLOCK in grid
+    assert all(b % LANES == 0 for b in grid)
+    ref, sm, _ = world
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        entries = tune_session(ref, sm, exec_cfg=ExecutionConfig(
+                                   backend="interpret"),
+                               batch=32, reps=1, seed=1,
+                               families=("pair_frontend",),
+                               path=tmp_path / "tc.json")
+    (entry,) = entries.values()
+    assert entry["params"] in ({"backend": "jnp"},
+                               *({"block": b} for b in grid))
 
 
 # ------------------------------------------- no fallback on the chip ---
