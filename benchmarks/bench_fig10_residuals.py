@@ -12,19 +12,22 @@ of the reference.
 """
 from __future__ import annotations
 
-import numpy as np
+import jax
+import jax.numpy as jnp
 
 from benchmarks.common import reads_for, row, time_fn
-from repro.core import PipelineConfig, map_pairs, stage_stats
-import jax.numpy as jnp
+from repro.core import PipelineConfig, map_pairs_impl, stage_stats
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 
 def run() -> list[dict]:
     cfg = PipelineConfig()
     ref, sm, ref_j, sim = reads_for(300_000, 2048, 0.007, ins_rate=6e-4,
                                     del_rate=6e-4, seed=17)
-    res = map_pairs(sm, ref_j, jnp.asarray(sim.reads1),
-                    jnp.asarray(sim.reads2), cfg)
+    res = map_pairs_jit(sm, ref_j, jnp.asarray(sim.reads1),
+                        jnp.asarray(sim.reads2), cfg)
     st = {k: float(v) for k, v in stage_stats(res).items()}
     light = st["light_mapped"]
     mapped_no_full_dp = light + st["dp_mapped"]

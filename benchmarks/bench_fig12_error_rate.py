@@ -8,10 +8,14 @@ pairs/s of the jitted pipeline.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from benchmarks.common import reads_for, row, time_fn
-from repro.core import PipelineConfig, map_pairs, stage_stats
+from repro.core import PipelineConfig, map_pairs_impl, stage_stats
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 RATES = (0.0005, 0.001, 0.002, 0.005, 0.01)
 
@@ -25,9 +29,9 @@ def run() -> list[dict]:
             300_000, 1024, e * 0.8, ins_rate=e * 0.1, del_rate=e * 0.1,
             seed=23)
         r1, r2 = jnp.asarray(sim.reads1), jnp.asarray(sim.reads2)
-        res = map_pairs(sm, ref_j, r1, r2, cfg)
+        res = map_pairs_jit(sm, ref_j, r1, r2, cfg)
         st = {k: float(v) for k, v in stage_stats(res).items()}
-        t = time_fn(lambda r1=r1, r2=r2: map_pairs(sm, ref_j, r1, r2, cfg))
+        t = time_fn(lambda r1=r1, r2=r2: map_pairs_jit(sm, ref_j, r1, r2, cfg))
         tput = 1024 / t  # MPair/s-scale-free: pairs per us
         base_tput = base_tput or tput
         rows.append(row(

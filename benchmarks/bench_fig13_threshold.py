@@ -11,17 +11,21 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row
 from repro.core import (
-    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap, map_pairs,
-    simulate_pairs,
+    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap,
+    map_pairs_impl, simulate_pairs,
 )
 from repro.core.pipeline import M_LIGHT
 from repro.core.seedmap import INVALID_LOC
 from repro.core.simulate import repetitive_reference
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 THRESHOLDS = (4, 16, 64, 500)
 
@@ -37,8 +41,8 @@ def run() -> list[dict]:
     for thr in THRESHOLDS:
         sm = build_seedmap(ref, SeedMapConfig(table_bits=19,
                                               max_locations=thr))
-        res = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                        jnp.asarray(sim.reads2), cfg)
+        res = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                            jnp.asarray(sim.reads2), cfg)
         pos = np.asarray(res.pos1)
         method = np.asarray(res.method)
         mapped = (pos != INVALID_LOC) & (method == M_LIGHT)

@@ -11,13 +11,16 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import reads_for, row, time_fn
-from repro.core import PipelineConfig, map_pairs
+from repro.core import PipelineConfig, map_pairs_impl
 from repro.core.baseline import map_single_end
 from repro.core.dp_fallback import gotoh_semiglobal
 from repro.core.light_align import gather_ref_windows, light_align
 from repro.core.pair_filter import paired_adjacency_filter
 from repro.core.query import query_read_batch
 from repro.core.seeding import seed_read_batch
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 
 def run() -> list[dict]:
@@ -61,7 +64,7 @@ def run() -> list[dict]:
 
     # ---- end-to-end: GenPair vs full-DP baseline --------------------------
     t_pair = time_fn(
-        lambda: map_pairs(sm, ref_j, reads1, reads2, cfg))
+        lambda: map_pairs_jit(sm, ref_j, reads1, reads2, cfg))
     t_base = time_fn(
         lambda: (map_single_end(sm, ref_j, reads1, cfg),
                  map_single_end(sm, ref_j, (3 - reads2)[:, ::-1], cfg)))

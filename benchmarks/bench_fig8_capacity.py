@@ -10,12 +10,16 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import reads_for, row, time_fn
-from repro.core import PipelineConfig, map_pairs
+from repro.core import PipelineConfig, map_pairs_impl
 from repro.core.seedmap import INVALID_LOC
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 
 def _recall(res, sim, tol=8):
@@ -31,16 +35,16 @@ def run() -> list[dict]:
     rows = []
     for K in (4, 16, 32, 64):
         cfg = PipelineConfig(max_locs_per_seed=K)
-        t = time_fn(lambda cfg=cfg: map_pairs(sm, ref_j, r1, r2, cfg))
-        res = map_pairs(sm, ref_j, r1, r2, cfg)
+        t = time_fn(lambda cfg=cfg: map_pairs_jit(sm, ref_j, r1, r2, cfg))
+        res = map_pairs_jit(sm, ref_j, r1, r2, cfg)
         rows.append(row(f"fig8/K_locs_{K}", t,
                         recall=round(_recall(res, sim), 4),
                         rel_cost=round(t / rows[0]["us_per_call"], 2)
                         if rows else 1.0))
     for C in (2, 8, 16):
         cfg = PipelineConfig(max_candidates=C)
-        t = time_fn(lambda cfg=cfg: map_pairs(sm, ref_j, r1, r2, cfg))
-        res = map_pairs(sm, ref_j, r1, r2, cfg)
+        t = time_fn(lambda cfg=cfg: map_pairs_jit(sm, ref_j, r1, r2, cfg))
+        res = map_pairs_jit(sm, ref_j, r1, r2, cfg)
         rows.append(row(f"fig8/C_cands_{C}", t,
                         recall=round(_recall(res, sim), 4)))
     rows.append(row("fig8/paper_note", 0.0,

@@ -1,7 +1,7 @@
 """Fused banded residual DP vs the staged unbanded fallback (step 5).
 
 The staged baseline is the seed repo's residual stage exactly as
-`map_pairs` wrote it out before the fusion: materialize both mates'
+the pipeline wrote it out before the fusion: materialize both mates'
 ``(cap, R + 2*dp_pad)`` reference windows in HBM and run the unbanded
 `gotoh_semiglobal` over every lane of both mates — regardless of which
 mate actually failed Light Alignment.  The fused path is one

@@ -9,17 +9,21 @@ baseline, (2) the 500-location index filter costs ~nothing.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row
 from repro.core import (
-    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap, map_pairs,
-    simulate_pairs,
+    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap,
+    map_pairs_impl, simulate_pairs,
 )
 from repro.core.baseline import map_single_end
 from repro.core.seedmap import INVALID_LOC
 from repro.core.simulate import repetitive_reference
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 
 def _prf(pos, true, mapped, tol=8):
@@ -43,7 +47,7 @@ def run() -> list[dict]:
     for tag, max_loc in (("with_filter", 500), ("no_filter", 1 << 30)):
         sm = build_seedmap(ref, SeedMapConfig(table_bits=19,
                                               max_locations=max_loc))
-        res = map_pairs(sm, ref_j, r1, r2, cfg)
+        res = map_pairs_jit(sm, ref_j, r1, r2, cfg)
         pos = np.asarray(res.pos1)
         p, r, f1 = _prf(pos, sim.true_start1, pos != INVALID_LOC)
         rows.append(row(f"table7/genpair_{tag}", 0.0,

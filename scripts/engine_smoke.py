@@ -1,10 +1,8 @@
-"""CI engine smoke: quickstart + a short map_stream serve, shim-clean.
+"""CI engine smoke: the quickstart + a short map_stream serve.
 
-Runs the two engine front-door entry points end to end (under whatever
+Runs the two engine entry points end to end (under whatever
 ``REPRO_BACKEND`` the job sets — CI uses the interpret-mode kernels) and
-asserts that no pre-engine deprecation shim (`map_pairs`, the
-`distributed.make_*` factories) was hit anywhere on the way: the engine
-paths must resolve everything through `repro.engine` itself.
+asserts that neither raised a ``DeprecationWarning``.
 
   PYTHONPATH=src REPRO_BACKEND=interpret python scripts/engine_smoke.py
 """
@@ -22,11 +20,9 @@ def main():
         runpy.run_path("examples/quickstart.py", run_name="__main__")
         sys.argv = ARGS
         runpy.run_module("repro.launch.serve", run_name="__main__")
-    shim = [w for w in caught
-            if issubclass(w.category, DeprecationWarning)
-            and "Mapper" in str(w.message)]
-    assert not shim, [str(w.message) for w in shim]
-    print("engine smoke: no deprecation-shim warnings")
+    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)]
+    assert not dep, [str(w.message) for w in dep]
+    print("engine smoke: no deprecation warnings")
 
 
 if __name__ == "__main__":

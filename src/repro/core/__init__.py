@@ -9,7 +9,6 @@ from repro.core.pair_filter import CandidateSet, paired_adjacency_filter
 from repro.core.pipeline import (
     MapResult,
     PipelineConfig,
-    map_pairs,
     map_pairs_impl,
     stage_stat_counts,
     stage_stats,
@@ -41,7 +40,7 @@ from repro.core.simulate import (
 __all__ = [
     "encode_str", "pack_2bit", "revcomp", "unpack_2bit", "xxhash32_words",
     "LightAlignResult", "light_align", "CandidateSet",
-    "paired_adjacency_filter", "MapResult", "PipelineConfig", "map_pairs",
+    "paired_adjacency_filter", "MapResult", "PipelineConfig",
     "map_pairs_impl", "stage_stat_counts", "stage_stats",
     "QueryResult", "query_csr", "query_read_batch", "Scoring",
     "SeedSet", "hash_seeds", "seed_read_batch", "INVALID_LOC", "PaddedSeedMap",

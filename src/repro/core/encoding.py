@@ -98,7 +98,7 @@ def packed_gather_coords(n_ref_words: int, length: int) -> tuple[int, int]:
     n_words = length // BASES_PER_WORD + 2
     # int32 positions address <=2^31-1 bases: at full-genome scale (3.1 Gbp)
     # real coordinates are per-chromosome (chrom, int32 offset) as in the
-    # paper; the dry-run's flattened coordinate space clamps the gather
+    # paper; a flattened coordinate space past 2^31 clamps the gather
     # bound so the jitted scalar stays in int32 range.
     hi = min(n_ref_words * BASES_PER_WORD - length - 1, 2**31 - 1)
     return n_words, hi

@@ -1,15 +1,15 @@
 """GenPair online pipeline (§4.1, Fig. 3): the paper's four steps end to end.
 
 This module is the *math* of the pipeline — one jit-able function over
-fixed-shape batches (`map_pairs_impl`).  The front door for running it is
-the session-style engine API in `repro/engine`: ``Mapper.build(...)``
-resolves the reference flavor (2-bit packed or not), the SeedMap layout
-(CSR vs `PaddedSeedMap`) and the kernel backends exactly once, then
-``mapper.map`` / ``mapper.map_stream`` dispatch to a pre-jitted step built
-from this module — the same code on one device and on a mesh (see
-docs/ENGINE.md).  The legacy one-shot entry `map_pairs` survives as a thin
-deprecation shim: it warns once and delegates to the same implementation,
-re-resolving everything per call.
+fixed-shape batches (`map_pairs_impl`: the front end, then
+`map_candidates` for light alignment, the DP fallback and the assembly;
+the sharded-index serve step runs its own front end and the same
+`map_candidates`).  The way in is the session-style engine API in
+`repro/engine`: ``Mapper.build(...)`` resolves the reference flavor
+(2-bit packed or not), the SeedMap layout (CSR vs `PaddedSeedMap`) and
+the kernel backends exactly once, then ``mapper.map`` /
+``mapper.map_stream`` dispatch to a pre-jitted step built from this
+module — the same code on one device and on a mesh (see docs/ENGINE.md).
 
 Each pipeline step maps onto a kernel family (all behind the shared
 backend layer, `repro/kernels/backend.py`):
@@ -53,13 +53,11 @@ Method codes (MapResult.method):
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.compat import warn_deprecated
 from repro.core.encoding import pack_2bit, ref_bases
 from repro.core.dp_fallback import NEG
 from repro.core.pair_filter import CandidateSet, paired_adjacency_filter
@@ -128,12 +126,12 @@ class PipelineConfig:
     frontend_backend: str = "auto"
     # Run the whole pipeline (candidate windows + DP fallback windows)
     # against the 2-bit packed reference: 4x less HBM window traffic, the
-    # paper's SRAM encoding (§7.4).  Tri-state: None keeps each entry
-    # point's historical default (map_pairs: unpacked; the genome-scale
-    # serve step: packed); True/False force the flavor everywhere.  The
-    # two gather flavors clamp out-of-range windows differently, so flips
-    # may change scores for candidates in the outer E bases of the
-    # reference.
+    # paper's SRAM encoding (§7.4).  Tri-state: None takes the plan's
+    # default (`map_pairs_impl` and the replicated-index plan: unpacked;
+    # the sharded-index serve plan: packed); True/False force the flavor
+    # everywhere.  The two gather flavors clamp out-of-range windows
+    # differently, so flips may change scores for candidates in the
+    # outer E bases of the reference.
     packed_ref: bool | None = None
     # Per-family launch block sizes for the fused ops.  None resolves to
     # each family's hand-picked `DEFAULT_BLOCK` inside the op; the
@@ -269,11 +267,6 @@ def _best_candidate_light(
     )
 
 
-class _Seeded(NamedTuple):
-    q1_starts: jnp.ndarray
-    q2_starts: jnp.ndarray
-
-
 def _residual_dp_stage(ref, reads1, reads2_fwd, pair, passed, light_ok,
                        cfg: PipelineConfig, packed: bool):
     """Step 5: the fixed-capacity, single-mate-aware banded DP fallback.
@@ -284,8 +277,7 @@ def _residual_dp_stage(ref, reads1, reads2_fwd, pair, passed, light_ok,
     kernel (no ``(cap, R+2*dp_pad)`` tensors in HBM), the Gotoh scan is
     banded (``cfg.band()``), and only the mates whose Light Alignment
     failed are re-aligned — the passing mate of a residual row keeps its
-    light score.  Shared bit-for-bit by `map_pairs_impl` and the mesh
-    serve step (`core.genpairx_step`).
+    light score.  Part of `map_candidates`, which both plans' steps run.
 
     ``ref`` is whatever flavor the caller resolved (uint8 bases, or the
     2-bit packed uint32 words with ``packed=True``), plain or as a
@@ -348,6 +340,75 @@ def _residual_dp_stage(ref, reads1, reads2_fwd, pair, passed, light_ok,
     return dp_sc1, dp_sc2, dp_done, dp_overflow, dp_mate1, dp_mate2
 
 
+def map_candidates(
+    ref,                       # (L,) u8 bases, (Lw,) u32 words or LinedRef
+    reads1: jnp.ndarray,       # (B, R) mate 1, reference orientation
+    reads2_fwd: jnp.ndarray,   # (B, R) mate 2, reference orientation
+    had_hits: jnp.ndarray,     # (B,) bool both reads had SeedMap hits
+    cands: CandidateSet,
+    cfg: PipelineConfig,
+    packed: bool,
+) -> MapResult:
+    """Steps 4-5 and the assembly: a batch's `MapResult` from its
+    candidate set.
+
+    The one body behind both plans' steps: `map_pairs_impl` (replicated
+    index) and the sharded-index serve step (`core.genpairx_step`) each
+    run their own front end and then this.  ``packed`` is the resolved
+    reference flavor; a uint8 ``ref`` with ``packed=True`` is packed
+    here, in every call.
+    """
+    B = reads1.shape[0]
+    # `passed` is the front end's verdict: under its scope, the per-stage
+    # device metrics count it with the front end on both plans.
+    with jax.named_scope("frontend"):
+        passed = cands.n > 0          # >= 1 candidate survived the Δ filter
+
+    with jax.named_scope("light_align"):
+        # -- 4. Light Alignment over candidates (fused kernel) -----------
+        # With packed_ref both the candidate windows and the DP fallback
+        # windows gather from the 2-bit packed reference (4x less HBM window
+        # traffic).  Callers that already hold the packed words (uint32)
+        # should pass them directly — packing a uint8 ref in here costs a
+        # full reference read per jitted call, which at genome scale
+        # dwarfs the window-DMA saving.
+        if packed and ref.dtype != jnp.uint32:
+            ref = pack_2bit(ref_bases(ref))
+        pair = _best_candidate_light(ref, reads1, reads2_fwd, cands, cfg,
+                                     packed)
+        light_ok = passed & pair.ok1 & pair.ok2
+
+    with jax.named_scope("residual_dp"):
+        # -- 5. DP fallback on the fixed-capacity residual buffer --------
+        # One fused `residual_dp` op (cfg.residual_backend): compacted window
+        # gather + banded Gotoh of exactly the failed mates.
+        (dp_sc1, dp_sc2, dp_done, dp_overflow, dp_m1,
+         dp_m2) = _residual_dp_stage(ref, reads1, reads2_fwd, pair, passed,
+                                     light_ok, cfg, packed)
+
+    with jax.named_scope("assemble"):
+        method = jnp.full((B,), M_UNMAPPED, jnp.int32)
+        method = jnp.where(~had_hits, M_RESIDUAL_FULL, method)
+        method = jnp.where(had_hits & ~passed, M_RESIDUAL_FULL, method)
+        method = jnp.where(light_ok, M_LIGHT, method)
+        method = jnp.where(dp_done, M_DP, method)
+        method = jnp.where(dp_overflow, M_DP_OVERFLOW, method)
+
+        mapped = light_ok | dp_done
+        pos1 = jnp.where(mapped, pair.pos1, INVALID_LOC)
+        pos2 = jnp.where(mapped, pair.pos2, INVALID_LOC)
+        score1 = jnp.where(light_ok, pair.score1,
+                           jnp.where(dp_done, dp_sc1, NEG))
+        score2 = jnp.where(light_ok, pair.score2,
+                           jnp.where(dp_done, dp_sc2, NEG))
+        return MapResult(
+            pos1=pos1, pos2=pos2, score1=score1, score2=score2,
+            method=method, cigar1=pair.cigar1, cigar2=pair.cigar2,
+            had_hits=had_hits, passed_adjacency=passed, light_ok=light_ok,
+            dp_mate1=dp_m1, dp_mate2=dp_m2, n_valid=jnp.ones((B,), bool),
+        )
+
+
 def map_pairs_impl(
     sm: SeedMap | PaddedSeedMap | LinedSeedMap | LinedCSRSeedMap,
     ref: jnp.ndarray,
@@ -357,9 +418,10 @@ def map_pairs_impl(
 ) -> MapResult:
     """Map a batch of FR read pairs. reads2 is as-sequenced (reverse strand).
 
-    This is the traceable pipeline body — no jit, no warning — that both
-    the engine's pre-built steps (`repro.engine.plan`) and the legacy
-    `map_pairs` shim close over.
+    This is the traceable pipeline body — no jit — that the engine's
+    replicated-index steps (`repro.engine.plan`) close over: the front
+    end, then `map_candidates`.  Callers that want it jitted on its own
+    use ``jax.jit(map_pairs_impl, static_argnames=("cfg",))``.
 
     ``ref`` is the (L,) uint8 base array; with ``cfg.packed_ref=True`` it
     may instead be the (Lw,) uint32 2-bit packing (`pack_2bit`), which
@@ -374,7 +436,7 @@ def map_pairs_impl(
     in-jit (`frontend_layout`).  A padded or lined layout's row width
     caps locations per seed, superseding ``cfg.max_locs_per_seed``.
     """
-    B, R = reads1.shape
+    R = reads1.shape[1]
     assert R == cfg.read_len, (R, cfg.read_len)
     # Each stage runs under a `jax.named_scope` (`frontend`,
     # `light_align`, `residual_dp`, `assemble`), so the device ops it
@@ -388,7 +450,7 @@ def map_pairs_impl(
         # location tensor and the (B, S*K) sorted start lists stay in VMEM).
         # The staged core modules remain the bit-exact jnp path.  Imported at
         # call time for the same core-package circularity reason as the
-        # candidate_align import below.
+        # candidate_align import in `_best_candidate_light`.
         from repro.kernels.pair_frontend.ops import pair_frontend
 
         fe_backend = resolve_backend(cfg.frontend_backend,
@@ -412,80 +474,6 @@ def map_pairs_impl(
                 block=cfg.frontend_block, backend=fe_backend)
             had_hits = (fe.n_hits1 > 0) & (fe.n_hits2 > 0)
             cands = CandidateSet(pos1=fe.pos1, pos2=fe.pos2, n=fe.n)
-        passed = cands.n > 0
 
-    with jax.named_scope("light_align"):
-        # -- 4. Light Alignment over candidates (fused kernel) -----------
-        # With packed_ref both the candidate windows and the DP fallback
-        # windows gather from the 2-bit packed reference (4x less HBM window
-        # traffic, the serve step's flavor).  Callers that already hold the
-        # packed words (uint32) should pass them directly — packing a uint8
-        # ref in here costs a full reference read per jitted call, which at
-        # genome scale dwarfs the window-DMA saving.
-        packed = cfg.packed(default=False)
-        ref_words = None
-        if packed:
-            ref_words = (ref if ref.dtype == jnp.uint32
-                         else pack_2bit(ref_bases(ref)))
-        pair = _best_candidate_light(ref_words if packed else ref,
-                                     reads1, reads2_fwd, cands, cfg, packed)
-        b_pos1, b_pos2 = pair.pos1, pair.pos2
-        b_sc1, b_sc2 = pair.score1, pair.score2
-        light_ok = passed & pair.ok1 & pair.ok2
-        cig1, cig2 = pair.cigar1, pair.cigar2
-
-    with jax.named_scope("residual_dp"):
-        # -- 5. DP fallback on the fixed-capacity residual buffer --------
-        # One fused `residual_dp` op (cfg.residual_backend): compacted window
-        # gather + banded Gotoh of exactly the failed mates.
-        (dp_sc1, dp_sc2, dp_done, dp_overflow, dp_m1,
-         dp_m2) = _residual_dp_stage(
-            ref_words if packed else ref, reads1, reads2_fwd, pair, passed,
-            light_ok, cfg, packed)
-
-    with jax.named_scope("assemble"):
-        # -- assemble -----------------------------------------------------
-        method = jnp.full((B,), M_UNMAPPED, jnp.int32)
-        method = jnp.where(~had_hits, M_RESIDUAL_FULL, method)
-        method = jnp.where(had_hits & ~passed, M_RESIDUAL_FULL, method)
-        method = jnp.where(light_ok, M_LIGHT, method)
-        method = jnp.where(dp_done, M_DP, method)
-        method = jnp.where(dp_overflow, M_DP_OVERFLOW, method)
-
-        mapped = light_ok | dp_done
-        pos1 = jnp.where(mapped, b_pos1, INVALID_LOC)
-        pos2 = jnp.where(mapped, b_pos2, INVALID_LOC)
-        score1 = jnp.where(light_ok, b_sc1, jnp.where(dp_done, dp_sc1, NEG))
-        score2 = jnp.where(light_ok, b_sc2, jnp.where(dp_done, dp_sc2, NEG))
-        return MapResult(
-            pos1=pos1, pos2=pos2, score1=score1, score2=score2,
-            method=method, cigar1=cig1, cigar2=cig2, had_hits=had_hits,
-            passed_adjacency=passed, light_ok=light_ok, dp_mate1=dp_m1,
-            dp_mate2=dp_m2, n_valid=jnp.ones((B,), bool),
-        )
-
-
-_jitted_map_pairs = jax.jit(map_pairs_impl, static_argnames=("cfg",))
-
-
-def map_pairs(
-    sm: SeedMap | PaddedSeedMap,
-    ref: jnp.ndarray,
-    reads1: jnp.ndarray,
-    reads2: jnp.ndarray,
-    cfg: PipelineConfig = PipelineConfig(),
-) -> MapResult:
-    """Deprecated one-shot entry point: build a `repro.engine.Mapper` instead.
-
-    Every call re-resolves what a `Mapper` resolves once at build time
-    (kernel backends, the `packed_ref` tri-state, and — on the kernel
-    front-end backends — the CSR->padded SeedMap relayout, in-jit).  Kept
-    as a thin shim because it is the reference the engine is pinned
-    against bit-for-bit; warns once per process and delegates.
-    """
-    warn_deprecated(
-        "map_pairs",
-        "map_pairs re-resolves backends/layouts per call; build a session "
-        "once with repro.engine.Mapper.from_index(...) and use mapper.map / "
-        "mapper.map_stream instead")
-    return _jitted_map_pairs(sm, ref, reads1, reads2, cfg)
+    return map_candidates(ref, reads1, reads2_fwd, had_hits, cands, cfg,
+                          cfg.packed(default=False))

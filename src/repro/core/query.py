@@ -1,8 +1,8 @@
 """SeedMap Query (§4.4): retrieve candidate locations for hashed seeds.
 
 The single-device path is a vectorized CSR gather; the multi-device path
-(`sharded_query` in repro/core/distributed.py) is the NMSL analogue that
-stripes the tables across devices.  Locations are converted to *read start
+(`sharded_lookup` in repro/core/genpairx_step.py) is the NMSL analogue
+that stripes the tables across devices.  Locations are converted to *read start
 positions* (location - seed offset in the read) and the per-read lists of
 all seeds are merged sorted — exactly the sorted-merge the paper gets for
 free from its contiguous layout.

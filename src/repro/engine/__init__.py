@@ -26,10 +26,6 @@ checksummed on-disk store so workers cold-start without rebuilding the
 index, ``Mapper.swap_index`` / ``FrontDoor.reload_index`` hot-swap a new
 index release into a live session, and ``engine.multihost.map_stream``
 drives per-host generators through one fleet-wide SPMD dispatch.
-
-The pre-engine entry points — `core.pipeline.map_pairs` and the
-`core.distributed.make_*` factories — survive as thin deprecation shims
-over the same implementations (warn once, delegate).
 """
 from repro.core.long_read import LongReadConfig, LongReadResult
 from repro.core.pipeline import MapResult

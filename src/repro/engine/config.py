@@ -1,12 +1,9 @@
 """ExecutionConfig + the once-per-session resolution of scattered knobs.
 
-Before the engine, four entry points (`map_pairs`, the genome-scale serve
-step, the `distributed.make_*` factories, the hand-rolled `launch/serve`
-loop) each re-resolved kernel backends, the `packed_ref` tri-state and
-the SeedMap layout independently.  `resolved_pipeline` is that resolution
-done exactly once, at `Mapper` build time: the `PipelineConfig` it
+`resolved_pipeline` resolves the kernel backends and the `packed_ref`
+tri-state exactly once, at `Mapper` build time: the `PipelineConfig` it
 returns has concrete backend names and a concrete ``packed_ref`` bool, so
-nothing on the per-batch path consults the environment or an entry-point
+nothing on the per-batch path consults the environment or a plan
 default again.
 """
 from __future__ import annotations
@@ -28,10 +25,10 @@ class ExecutionConfig:
     batch_axes:   mesh axes the read batch shards over.
     model_axis:   mesh axis the SeedMap shards over (``shard_index``).
     shard_index:  bucket-shard the SeedMap along ``model_axis`` (the NMSL
-                  channel-striping serve plan, today's genome-scale
-                  `make_genpair_serve_step`); False replicates the index
-                  and runs data-parallel (today's
-                  `make_distributed_map_pairs`).  Requires ``mesh``.
+                  channel-striping serve plan,
+                  `core.genpairx_step.make_genpair_serve_step`); False
+                  replicates the index and runs data-parallel.
+                  Requires ``mesh``.
     stream_batch: fixed batch shape for `map_stream` (None: the first
                   batch's row count).  Ragged tail batches are padded up
                   to it and masked via `MapResult.n_valid`.
@@ -43,7 +40,7 @@ class ExecutionConfig:
     packed_ref:   overrides the `PipelineConfig.packed_ref` tri-state at
                   build (None: resolve the tri-state against the plan's
                   default — packed for the sharded-index serve plan,
-                  unpacked otherwise, the historical entry-point split).
+                  unpacked otherwise).
     long_read:    the session's long-read lane (`Mapper.map_long` /
                   ``map_long_stream``).  None builds the lane with the
                   default `LongReadConfig` on replicated-index plans;
@@ -54,8 +51,8 @@ class ExecutionConfig:
                   default location; False never tunes; None (default)
                   opts in only when the ``REPRO_TUNE_CACHE`` env var is
                   set — so sessions stay on the hand-picked defaults
-                  (and bit-stable vs. legacy entry points) unless tuning
-                  is asked for.  Cached winners fill only knobs the
+                  (and bit-stable against `map_pairs_impl`) unless
+                  tuning is asked for.  Cached winners fill only knobs the
                   configs left unset: explicit config > tune cache >
                   defaults.
     """
@@ -83,7 +80,6 @@ def resolved_pipeline(
     pipe_cfg: PipelineConfig,
     exec_cfg: ExecutionConfig | None = None,
     *,
-    packed_default: bool | None = None,
     tune_cache: dict | None = None,
 ) -> PipelineConfig:
     """Resolve every deferred `PipelineConfig` knob to a concrete value.
@@ -91,9 +87,8 @@ def resolved_pipeline(
     Returns a config whose ``light_backend`` / ``frontend_backend`` /
     ``residual_backend`` are concrete backend names (env override and
     auto rule applied now, not per trace) and whose ``packed_ref`` is a
-    concrete bool.
-    ``packed_default`` overrides the plan-derived tri-state default (the
-    dry-run resolves serve-flavored configs without an ExecutionConfig).
+    concrete bool: ``ExecutionConfig.packed_ref``, else the config's
+    own, else the plan's default (packed on the sharded-index plan).
     ``tune_cache`` — entries from `repro.tune` (`Mapper` loads them per
     `ExecutionConfig.tune`) — fills knobs the configs left unset
     *before* the backend/packed resolution, so explicit settings always
@@ -111,9 +106,7 @@ def resolved_pipeline(
     residual = exec_cfg.backend or pipe_cfg.residual_backend
     packed = exec_cfg.packed_ref
     if packed is None:
-        if packed_default is None:
-            packed_default = exec_cfg.shard_index
-        packed = pipe_cfg.packed(default=packed_default)
+        packed = pipe_cfg.packed(default=exec_cfg.shard_index)
     return dataclasses.replace(
         pipe_cfg,
         light_backend=resolve_backend(light, family="candidate_align"),
@@ -153,7 +146,6 @@ def resolved_long_read(
         packed_ref=pipe_cfg.packed_ref,
     )
     lane_pipe = resolved_pipeline(lane_pipe, exec_cfg,
-                                  packed_default=pipe_cfg.packed_ref,
                                   tune_cache=tune_cache)
     vote = exec_cfg.backend or lr.vote_backend
     return dataclasses.replace(

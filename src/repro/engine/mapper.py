@@ -1,8 +1,8 @@
 """The `Mapper` session: canonical device-resident state, built once.
 
 ``Mapper.build`` (reference -> index -> session) and ``Mapper.from_index``
-(existing CSR `SeedMap` -> session) do, exactly once, everything the
-pre-engine entry points re-did per call:
+(existing CSR `SeedMap` -> session) do, exactly once, everything no
+step should redo per call:
 
   * resolve kernel backends for every family (env override, auto rule);
   * resolve the ``packed_ref`` tri-state and 2-bit pack the reference;
@@ -173,7 +173,7 @@ class Mapper:
         self.sm_config = sm_config
         self.index = index           # the session's resolved index object
         self._step = plan.jit_step(
-            raw_step, len(state), mesh=exec_cfg.mesh,
+            raw_step, mesh=exec_cfg.mesh,
             state_shardings=state_shardings,
             batch_axes=exec_cfg.batch_axes)
         # The long-read lane shares the session state; absent (None) on
@@ -183,7 +183,7 @@ class Mapper:
         self._long_step = None
         if raw_long_step is not None:
             self._long_step = plan.jit_step(
-                raw_long_step, len(state), mesh=exec_cfg.mesh,
+                raw_long_step, mesh=exec_cfg.mesh,
                 state_shardings=state_shardings,
                 batch_axes=exec_cfg.batch_axes, n_batch_args=1)
         # LRU of fused stream steps, keyed (lane, reduce_fn), bounded at
@@ -232,30 +232,29 @@ class Mapper:
         packed_in = ref.dtype == jnp.uint32
         mesh = exec_cfg.mesh
 
+        if cfg.packed_ref:
+            ref_arr = ref if packed_in else pack_2bit(ref)
+        else:
+            if packed_in:
+                raise ValueError(
+                    "packed_ref resolved False but ref is uint32 words;"
+                    " pass the uint8 base array")
+            ref_arr = ref
         if exec_cfg.shard_index:
             from repro.core.distributed import shard_seedmap
             if not isinstance(sm, SeedMap):
                 raise TypeError("shard_index requires a CSR SeedMap")
-            ref_words = ref if packed_in else pack_2bit(ref)
             ssm = shard_seedmap(sm, mesh.shape[exec_cfg.model_axis])
             shardings = plan.serve_state_shardings(mesh,
                                                    exec_cfg.model_axis)
             state = tuple(jax.device_put(x, s) for x, s in
-                          zip((ssm.offsets, ssm.locations, ref_words),
+                          zip((ssm.offsets, ssm.locations, ref_arr),
                               shardings))
             raw = plan.raw_sharded_index_step(
                 mesh, cfg, sm.config, exec_cfg.batch_axes,
                 exec_cfg.model_axis)
             index = ssm
         else:
-            if cfg.packed_ref:
-                ref_arr = ref if packed_in else pack_2bit(ref)
-            else:
-                if packed_in:
-                    raise ValueError(
-                        "packed_ref resolved False but ref is uint32 words;"
-                        " pass the uint8 base array")
-                ref_arr = ref
             if isinstance(sm, PaddedSeedMap):
                 # An already-padded map's row width IS the per-seed
                 # location cap, so the resolved config (and the
@@ -428,8 +427,9 @@ class Mapper:
     def map(self, reads1, reads2) -> MapResult:
         """Map one fixed-shape batch of FR read pairs.
 
-        ``reads2`` as-sequenced (reverse strand), exactly the legacy
-        `map_pairs` contract; results are bit-identical to it.
+        ``reads2`` as-sequenced (reverse strand); on the replicated
+        plan, results are bit-identical to ``map_pairs_impl`` jitted on
+        the same index and reference flavor.
         """
         reads1 = jnp.asarray(reads1)
         reads2 = jnp.asarray(reads2)

@@ -11,41 +11,22 @@ through :func:`resolve_backend`, so the policy lives in exactly one place:
   - ``REPRO_BACKEND`` overrides the auto choice for *all* kernel families
     (CI uses ``REPRO_BACKEND=interpret`` to drive the whole pipeline
     through the interpret-mode kernels on CPU);
-  - ``REPRO_LIGHT_BACKEND`` is kept as a deprecated alias (it predates the
-    unified layer, when only the fused candidate aligner was overridable)
-    and is consulted only when ``REPRO_BACKEND`` is unset;
   - anything other than the four known names raises ``ValueError``.
 
-The ops wrappers are jitted with ``backend`` static, so the env vars are
-read at *trace* time: set them before the first call in a process (or
-call ``<op>.clear_cache()`` after changing them, as the tests do).
+The ops wrappers are jitted with ``backend`` static, so the env var is
+read at *trace* time: set it before the first call in a process (or
+call ``<op>.clear_cache()`` after changing it, as the tests do).
 """
 from __future__ import annotations
 
 import os
-import warnings
 
 import jax
 
 ENV_VAR = "REPRO_BACKEND"
-ENV_VAR_DEPRECATED = "REPRO_LIGHT_BACKEND"
 
 #: every backend an ops.py wrapper accepts after resolution
 BACKENDS = ("pallas", "interpret", "jnp")
-
-
-def _env_override() -> str | None:
-    val = os.environ.get(ENV_VAR)
-    if val:
-        return val
-    val = os.environ.get(ENV_VAR_DEPRECATED)
-    if val:
-        warnings.warn(
-            f"{ENV_VAR_DEPRECATED} is deprecated; set {ENV_VAR} instead "
-            "(same values, honored by every kernel family)",
-            DeprecationWarning, stacklevel=3)
-        return val
-    return None
 
 
 def resolve_backend(backend: str = "auto", family: str | None = None) -> str:
@@ -55,7 +36,7 @@ def resolve_backend(backend: str = "auto", family: str | None = None) -> str:
     every kernel family.  Returns one of :data:`BACKENDS`.
     """
     if backend == "auto":
-        backend = _env_override() or (
+        backend = os.environ.get(ENV_VAR) or (
             "pallas" if jax.default_backend() == "tpu" else "jnp")
     if backend not in BACKENDS:
         where = f" for kernel family {family!r}" if family else ""

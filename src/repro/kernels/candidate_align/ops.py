@@ -61,10 +61,9 @@ def candidate_pair_align(
 
     ``backend="auto"`` resolves through ``kernels/backend.py``: the Pallas
     kernel on TPU, the jnp oracle elsewhere, with the ``REPRO_BACKEND``
-    env var (or its deprecated ``REPRO_LIGHT_BACKEND`` alias) overriding
-    the auto choice — CI uses it to drive the whole pipeline through the
-    interpret-mode kernels on CPU.  The override is read at trace time, so
-    set it before the first call in a process.
+    env var overriding the auto choice — CI uses it to drive the whole
+    pipeline through the interpret-mode kernels on CPU.  The override is
+    read at trace time, so set it before the first call in a process.
 
     ``ref`` is a plain reference, whose line layout the kernel backends
     build in the call (`reference_lines`), or a session's `LinedRef`,

@@ -5,7 +5,7 @@ This is the *unfused* step-4 hot path exactly as `core/pipeline.py` and
 `(B, C, R+2E)` candidate reference window, light-align all `B*C`
 (read, window) rows per mate, mask invalid candidates, and argmax the
 summed pair score.  The Pallas kernel (`kernel.py`) must match this
-bit-for-bit; `map_pairs` results are pinned against it.  With
+bit-for-bit; the pipeline's results are pinned against it.  With
 ``0 < prescreen_top < C`` both paths align only the top-P candidate
 pairs ranked by summed zero-shift Hamming distance: here via
 ``lax.top_k`` + ``take_along_axis`` over the materialized windows, in

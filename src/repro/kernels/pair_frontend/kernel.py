@@ -1,6 +1,6 @@
 """Pallas TPU kernels: fused pipeline front end (§4, Fig. 3 steps 1-3).
 
-Fuses the memory-intensive front end of `map_pairs` — Partitioned Seeding
+Fuses the memory-intensive front end of the pipeline — Partitioned Seeding
 (2-bit packing + xxHash32, §4.3), the SeedMap padded-row lookup (§4.4) and
 Paired-Adjacency Filtering (§4.5) — so the per-read `(B, S*K)` sorted
 start lists and the `(B, S, K)` location tensor never round-trip through

@@ -1,11 +1,11 @@
 """Staged jnp oracle for the fused pair_frontend op.
 
-This is the pipeline front end (steps 1-3 of `map_pairs`) exactly as the
-core modules write it: Partitioned Seeding (`core.seeding`), padded-row
+This is the pipeline front end (steps 1-3 of `map_pairs_impl`) exactly as
+the core modules write it: Partitioned Seeding (`core.seeding`), padded-row
 SeedMap lookup (a padded-row gather, or `core.query.query_csr` on the CSR
 tables, + `merge_read_starts`), and
 Paired-Adjacency Filtering (`core.pair_filter`).  The Pallas kernels in
-`kernel.py` must match this path bit-for-bit; `map_pairs` results are
+`kernel.py` must match this path bit-for-bit; the pipeline's results are
 pinned against it.
 
 The oracle deliberately *routes through* `seeding.py` / `query.py` /

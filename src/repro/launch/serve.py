@@ -10,11 +10,6 @@ read simulation and H2D with the in-flight step, accumulates StageStats
 exactly once at the end.  Accuracy is validated per mate (``pos1`` vs
 ``true_start1`` and ``pos2`` vs ``true_start2``) and at pair level.
 
-``--loop legacy`` keeps the pre-engine loop — one blocking `map_pairs`
-call plus seven ``float()`` stage-stat syncs per batch — as the measured
-baseline; ``--compare`` runs both and writes the speedup JSON artifact CI
-uploads.
-
 ``--workload long`` serves the long-read lane instead: `serve_long`
 streams simulated PacBio-like batches through ``mapper.map_long_stream``
 with a device-side vote-accuracy reduction.
@@ -39,14 +34,12 @@ import json
 import os
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (
     PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap,
-    map_pairs_impl, random_reference, simulate_long_reads, simulate_pairs,
-    stage_stats,
+    random_reference, simulate_long_reads, simulate_pairs,
 )
 from repro.compile_cache import enable_compile_cache
 from repro.core.seedmap import INVALID_LOC
@@ -55,10 +48,6 @@ from repro.engine import ExecutionConfig, LongReadConfig, Mapper, spans
 
 ACC_KEYS = ("mapped1", "mapped2", "correct1", "correct2",
             "pair_mapped", "pair_correct")
-
-# Module-level jit so repeat legacy runs (compare_loops) share one compile.
-_legacy_step = jax.jit(map_pairs_impl, static_argnames=("cfg",))
-
 
 @functools.lru_cache(maxsize=None)
 def _make_accuracy_reduce(max_gap: int):
@@ -126,7 +115,7 @@ def _session_from_store(index_path, ref, table_bits, pipe_cfg, exec_cfg,
 def serve(ref_len: int = 500_000, batch: int = 512, batches: int = 10,
           table_bits: int = 20, sub_rate: float = 1e-3,
           pipe_cfg: PipelineConfig = PipelineConfig(),
-          seed: int = 0, verbose: bool = True, loop: str = "stream",
+          seed: int = 0, verbose: bool = True,
           index_path: str | None = None,
           chaos: str | None = None) -> dict:
     rng = np.random.default_rng(seed)
@@ -134,9 +123,6 @@ def serve(ref_len: int = 500_000, batch: int = 512, batches: int = 10,
     ref = random_reference(ref_len, rng)
     mapper = sm = None
     if index_path is not None:
-        if loop == "legacy":
-            raise ValueError("--index serves through the engine session; "
-                             "the legacy loop has no store path")
         mapper, t_index = _session_from_store(
             index_path, ref, table_bits, pipe_cfg,
             ExecutionConfig(stream_batch=batch))
@@ -148,17 +134,8 @@ def serve(ref_len: int = 500_000, batch: int = 512, batches: int = 10,
                               seed=seed)
     sim_cfg = ReadSimConfig(read_len=pipe_cfg.read_len, sub_rate=sub_rate)
 
-    if loop == "legacy":
-        if chaos:
-            raise ValueError("--chaos drives the fault-tolerant stream "
-                             "loop; the legacy loop has no drain path")
-        out = _serve_legacy(ref, sm, stream, sim_cfg, batch, batches,
-                            pipe_cfg, t_index)
-    elif loop == "stream":
-        out = _serve_stream(ref, sm, stream, sim_cfg, batch, batches,
-                            pipe_cfg, t_index, mapper=mapper, chaos=chaos)
-    else:
-        raise ValueError(f"unknown loop {loop!r}; expected stream|legacy")
+    out = _serve_stream(ref, sm, stream, sim_cfg, batch, batches, pipe_cfg,
+                        t_index, mapper=mapper, chaos=chaos)
     # The program's span table and step-trace counts (engine.spans):
     # where the host time went, and whether a step recompiled.
     out["spans"] = spans.snapshot()
@@ -179,7 +156,7 @@ def _serve_stream(ref, sm, stream, sim_cfg, batch, batches, pipe_cfg,
             sim = read_pairs_for_step(ref, stream, step, sim_cfg)
             yield sim.reads1, sim.reads2, (sim.true_start1, sim.true_start2)
 
-    # warmup/compile on batch 0 (the legacy loop warms the same way)
+    # warmup/compile on batch 0
     sim0 = read_pairs_for_step(ref, stream, 0, sim_cfg)
     warmup = (sim0.reads1, sim0.reads2,
               (sim0.true_start1, sim0.true_start2))
@@ -431,127 +408,6 @@ def frontdoor_trace(mapper: Mapper, ref, batch: int, batches: int, *,
     }
 
 
-def _serve_legacy(ref, sm, stream, sim_cfg, batch, batches, pipe_cfg,
-                  t_index) -> dict:
-    """The pre-engine host loop, kept verbatim as the measured baseline.
-
-    Strictly serial per batch: simulate -> blocking map -> seven
-    ``float()`` stage-stat host syncs -> host-side mate-1-only accuracy.
-    `map_stream` must beat this by >= 1.2x at batch 512 on CPU (CI
-    artifact); it is not wired through the deprecation shim so the
-    comparison isolates the loop, not warning overhead.
-    """
-    step_fn = _legacy_step
-    ref_j = jnp.asarray(ref)
-
-    sim0 = read_pairs_for_step(ref, stream, 0, sim_cfg)
-    res = step_fn(sm, ref_j, jnp.asarray(sim0.reads1),
-                  jnp.asarray(sim0.reads2), pipe_cfg)
-    res.pos1.block_until_ready()
-
-    n_pairs = 0
-    correct = 0
-    mapped = 0
-    agg: dict[str, float] = {}
-    t1 = time.time()
-    for step in range(batches):
-        sim = read_pairs_for_step(ref, stream, step, sim_cfg)
-        res = step_fn(sm, ref_j, jnp.asarray(sim.reads1),
-                      jnp.asarray(sim.reads2), pipe_cfg)
-        pos1 = np.asarray(res.pos1)
-        ok = pos1 != INVALID_LOC
-        mapped += int(ok.sum())
-        correct += int((np.abs(pos1[ok] - sim.true_start1[ok])
-                        <= pipe_cfg.max_gap).sum())
-        n_pairs += batch
-        for k, v in stage_stats(res).items():
-            agg[k] = agg.get(k, 0.0) + float(v)
-    dt = time.time() - t1
-    return {
-        "pairs": n_pairs,
-        "pairs_per_s": n_pairs / dt,
-        "mbp_per_s": n_pairs * 2 * pipe_cfg.read_len / dt / 1e6,
-        "index_build_s": t_index,
-        "loop": "legacy",
-        "mapped_frac": mapped / n_pairs,
-        "correct_of_mapped": correct / max(mapped, 1),
-        **{k: v / batches for k, v in agg.items()},
-    }
-
-
-def compare_loops(out_path: str | None = None, reps: int = 3,
-                  ref_len: int = 500_000, batch: int = 512,
-                  batches: int = 10, table_bits: int = 20,
-                  sub_rate: float = 1e-3,
-                  pipe_cfg: PipelineConfig = PipelineConfig(),
-                  seed: int = 0) -> dict:
-    """Run the legacy and stream loops on identical work; report speedup.
-
-    The acceptance gate for the engine host loop: ``stream`` must reach
-    >= 1.2x the legacy pairs/s at batch 512 on CPU.  Shared CI boxes
-    drift by tens of percent between phases (burst throttling), so the
-    harness (a) builds the index and compiles both loops ONCE up front —
-    no compile/build burn between timed regions — and (b) alternates
-    short timed runs in counterbalanced order, scoring the *median of
-    adjacent-pair ratios* rather than one back-to-back measurement.
-    Writes the JSON artifact CI uploads.
-    """
-    rng = np.random.default_rng(seed)
-    t0 = time.time()
-    ref = random_reference(ref_len, rng)
-    sm = build_seedmap(ref, SeedMapConfig(table_bits=table_bits))
-    t_index = time.time() - t0
-    stream = ReadStreamConfig(batch=batch, read_len=pipe_cfg.read_len,
-                              seed=seed)
-    sim_cfg = ReadSimConfig(read_len=pipe_cfg.read_len, sub_rate=sub_rate)
-    mapper = Mapper.from_index(
-        sm, ref, pipe_cfg, ExecutionConfig(stream_batch=batch))
-
-    run = {
-        "legacy": lambda: _serve_legacy(ref, sm, stream, sim_cfg, batch,
-                                        batches, pipe_cfg, t_index),
-        "stream": lambda: _serve_stream(ref, sm, stream, sim_cfg, batch,
-                                        batches, pipe_cfg, t_index,
-                                        mapper=mapper),
-    }
-    runs: dict[str, list] = {"legacy": [], "stream": []}
-    ratios = []
-    for rep in range(reps):
-        order = ("legacy", "stream") if rep % 2 == 0 else ("stream",
-                                                           "legacy")
-        pair = {}
-        for loop in order:
-            pair[loop] = run[loop]()
-            runs[loop].append(pair[loop])
-        ratios.append(pair["stream"]["pairs_per_s"]
-                      / max(pair["legacy"]["pairs_per_s"], 1e-9))
-    # Best-of runs are labelled as such: they may come from different
-    # reps, so the headline ratio is the median of SAME-rep pairs, not
-    # stream_best / legacy_best.
-    legacy = max(runs["legacy"], key=lambda r: r["pairs_per_s"])
-    streamed = max(runs["stream"], key=lambda r: r["pairs_per_s"])
-    result = {
-        "legacy_best": legacy,
-        "stream_best": streamed,
-        "legacy_runs_pairs_per_s": [r["pairs_per_s"]
-                                    for r in runs["legacy"]],
-        "stream_runs_pairs_per_s": [r["pairs_per_s"]
-                                    for r in runs["stream"]],
-        "per_rep_speedups": ratios,
-        "speedup_pairs_per_s": float(np.median(ratios)),
-    }
-    if out_path:
-        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
-        with open(out_path, "w") as f:
-            json.dump(result, f, indent=1)
-    print(json.dumps({"speedup_pairs_per_s": result["speedup_pairs_per_s"],
-                      "per_rep_speedups": ratios,
-                      "legacy_best_pairs_per_s": legacy["pairs_per_s"],
-                      "stream_best_pairs_per_s": streamed["pairs_per_s"]},
-                     indent=1), flush=True)
-    return result
-
-
 def save_index(path: str, ref_len: int = 500_000, batch: int = 512,
                table_bits: int = 20, sub_rate: float = 1e-3,
                pipe_cfg: PipelineConfig = PipelineConfig(),
@@ -595,12 +451,11 @@ def main():
     ap.add_argument("--sub-rate", type=float, default=None,
                     help="substitution rate; defaults per workload "
                          "(1e-3 short pairs, PacBio-like 0.01 long)")
-    ap.add_argument("--loop", choices=("stream", "legacy", "frontdoor"),
+    ap.add_argument("--loop", choices=("stream", "frontdoor"),
                     default="stream",
-                    help="host loop: pre-batched map_stream (default), "
-                         "the pre-engine baseline, or the "
-                         "continuous-batching front door (bursty ragged "
-                         "arrivals, two lanes interleaved)")
+                    help="host loop: pre-batched map_stream (default) or "
+                         "the continuous-batching front door (bursty "
+                         "ragged arrivals, two lanes interleaved)")
     ap.add_argument("--workload", choices=("pairs", "long"),
                     default="pairs",
                     help="short FR pairs (default) or the long-read lane")
@@ -614,12 +469,8 @@ def main():
                     help="--loop frontdoor: per-request deadline")
     ap.add_argument("--max-queue-rows", type=int, default=None,
                     help="--loop frontdoor: admission-control queue bound")
-    ap.add_argument("--compare", action="store_true",
-                    help="run legacy + stream loops and report the speedup")
-    ap.add_argument("--reps", type=int, default=3,
-                    help="--compare repetitions (median of per-rep ratios)")
     ap.add_argument("--out", default=None,
-                    help="write the result JSON here (--compare artifact)")
+                    help="write the result JSON here")
     ap.add_argument("--save-index", default=None, metavar="PATH",
                     help="build the index + session, persist the store "
                          "to PATH (engine.index_store) and exit")
@@ -654,9 +505,6 @@ def main():
             with open(args.out, "w") as f:
                 json.dump(out, f, indent=1)
         return
-    if args.compare:
-        compare_loops(out_path=args.out, reps=args.reps, **kwargs)
-        return
     if args.loop == "frontdoor":
         if args.chaos:
             raise SystemExit("--chaos composes with --loop stream; the "
@@ -674,8 +522,7 @@ def main():
         out = serve_long(read_len=args.read_len, index_path=args.index,
                          **kwargs)
     else:
-        out = serve(loop=args.loop, index_path=args.index,
-                    chaos=args.chaos, **kwargs)
+        out = serve(index_path=args.index, chaos=args.chaos, **kwargs)
     if args.health_out and out.get("health") is not None:
         os.makedirs(os.path.dirname(args.health_out) or ".", exist_ok=True)
         with open(args.health_out, "w") as f:

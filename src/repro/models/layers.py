@@ -162,7 +162,7 @@ def triangle_attention(q, k, v, block_q: int, block_k: int):
     """Causal blockwise attention, python-loop lower-triangle iteration.
 
     Used by the dry-run (unrolled mode): (1) cost_analysis counts every
-    block (lax.scan bodies are counted once — see roofline.py), and
+    block (lax.scan bodies are counted once), and
     (2) upper-triangle blocks are *skipped*, not masked — removing the 2x
     masked-FLOP overhead of the scan path (a beyond-paper §Perf win that
     also exists on real hardware).

@@ -3,16 +3,17 @@
 - interpret-mode Pallas kernel vs the unfused jnp oracle, both gather
   flavors (unpacked bases / 2-bit packed words), both light modes,
   prescreen on/off, INVALID_LOC-padded candidate rows;
-- a map_pairs end-to-end regression pinning MapResult (pos/score/method)
+- a jitted `map_pairs_impl` end-to-end regression pinning MapResult (pos/score/method)
   against the seed implementation's unfused math on a fixed RNG batch.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import (
-    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap, map_pairs,
-    random_reference, simulate_pairs,
+    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap,
+    map_pairs_impl, random_reference, simulate_pairs,
 )
 from repro.core.encoding import pack_2bit
 from repro.core.light_align import gather_ref_windows, light_align
@@ -23,6 +24,9 @@ from repro.core.seeding import seed_read_batch
 from repro.core.seedmap import INVALID_LOC
 from repro.kernels.candidate_align import candidate_pair_align
 from repro.kernels.light_align.kernel import count_align_block_calls
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 L, R, E = 5000, 100, 6
 
@@ -246,7 +250,8 @@ def _seed_best_candidate_light(ref, reads, starts, cfg):
 
 
 def test_map_pairs_regression_vs_seed_math():
-    """map_pairs through the fused op == the seed's unfused step-4 math."""
+    """The pipeline through the fused op == the seed's unfused step-4
+    math."""
     rng = np.random.default_rng(0)
     ref = random_reference(60_000, rng)
     cfg = PipelineConfig()
@@ -255,7 +260,7 @@ def test_map_pairs_regression_vs_seed_math():
                                                 del_rate=5e-4), seed=3)
     reads1, reads2 = jnp.asarray(sim.reads1), jnp.asarray(sim.reads2)
     ref_j = jnp.asarray(ref)
-    res = map_pairs(sm, ref_j, reads1, reads2, cfg)
+    res = map_pairs_jit(sm, ref_j, reads1, reads2, cfg)
 
     # Recompute the light stage with the seed implementation.
     reads2_fwd = (3 - reads2)[:, ::-1]
@@ -292,10 +297,10 @@ def test_map_pairs_interpret_backend_matches_jnp():
     sim = simulate_pairs(ref, 24, ReadSimConfig(sub_rate=2e-3), seed=5)
     reads1, reads2 = jnp.asarray(sim.reads1), jnp.asarray(sim.reads2)
     ref_j = jnp.asarray(ref)
-    res_jnp = map_pairs(sm, ref_j, reads1, reads2,
-                        PipelineConfig(light_backend="jnp"))
-    res_int = map_pairs(sm, ref_j, reads1, reads2,
-                        PipelineConfig(light_backend="interpret"))
+    res_jnp = map_pairs_jit(sm, ref_j, reads1, reads2,
+                            PipelineConfig(light_backend="jnp"))
+    res_int = map_pairs_jit(sm, ref_j, reads1, reads2,
+                            PipelineConfig(light_backend="interpret"))
     for f in ("pos1", "pos2", "score1", "score2", "method",
               "cigar1", "cigar2"):
         np.testing.assert_array_equal(
@@ -313,18 +318,19 @@ def test_map_pairs_packed_ref():
     sim = simulate_pairs(ref, 24, ReadSimConfig(sub_rate=2e-3), seed=4)
     reads1, reads2 = jnp.asarray(sim.reads1), jnp.asarray(sim.reads2)
     ref_j = jnp.asarray(ref)
-    res_pj = map_pairs(sm, ref_j, reads1, reads2,
-                       PipelineConfig(packed_ref=True, light_backend="jnp"))
-    res_pi = map_pairs(sm, ref_j, reads1, reads2,
-                       PipelineConfig(packed_ref=True,
-                                      light_backend="interpret"))
+    res_pj = map_pairs_jit(
+        sm, ref_j, reads1, reads2,
+        PipelineConfig(packed_ref=True, light_backend="jnp"))
+    res_pi = map_pairs_jit(sm, ref_j, reads1, reads2,
+                           PipelineConfig(packed_ref=True,
+                                          light_backend="interpret"))
     for f in ("pos1", "pos2", "score1", "score2", "method",
               "cigar1", "cigar2"):
         np.testing.assert_array_equal(
             np.asarray(getattr(res_pj, f)), np.asarray(getattr(res_pi, f)),
             err_msg=f"packed field {f}")
-    res_u = map_pairs(sm, ref_j, reads1, reads2,
-                      PipelineConfig(light_backend="jnp"))
+    res_u = map_pairs_jit(sm, ref_j, reads1, reads2,
+                          PipelineConfig(light_backend="jnp"))
     same = (np.asarray(res_pj.pos1) == np.asarray(res_u.pos1)).mean()
     assert same >= 0.95, f"packed flavor changed {1 - same:.1%} of positions"
     light = np.asarray(res_pj.method) == M_LIGHT
@@ -332,15 +338,16 @@ def test_map_pairs_packed_ref():
 
 
 def test_prescreen_keeps_mapping_in_map_pairs():
-    """prescreen_top now also works in map_pairs (was serve-step only)."""
+    """prescreen_top keeps the mapping in the replicated-index
+    pipeline."""
     rng = np.random.default_rng(2)
     ref = random_reference(40_000, rng)
     sm = build_seedmap(ref, SeedMapConfig(table_bits=14))
     sim = simulate_pairs(ref, 32, ReadSimConfig(sub_rate=2e-3), seed=9)
     reads1, reads2 = jnp.asarray(sim.reads1), jnp.asarray(sim.reads2)
     ref_j = jnp.asarray(ref)
-    base = map_pairs(sm, ref_j, reads1, reads2, PipelineConfig())
-    pre = map_pairs(sm, ref_j, reads1, reads2,
-                    PipelineConfig(prescreen_top=2))
+    base = map_pairs_jit(sm, ref_j, reads1, reads2, PipelineConfig())
+    pre = map_pairs_jit(sm, ref_j, reads1, reads2,
+                        PipelineConfig(prescreen_top=2))
     same = (np.asarray(base.pos1) == np.asarray(pre.pos1)).mean()
     assert same >= 0.95, f"prescreen changed {1 - same:.1%} of positions"

@@ -49,7 +49,7 @@ CFG = PipelineConfig()
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -63,11 +63,16 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev_cache)
     compilation_cache.reset_cache()
     if prev_log_dir is None:
         os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.fixture(scope="module")
@@ -339,3 +344,38 @@ def test_long_step_carries_stage_scopes_and_kernel_names(sds):
         assert _has_scope(text, scope), scope
     assert _kernel_names(text) == {"pair_frontend", "location_vote",
                                    "banded_sw"}
+
+
+def test_sharded_step_carries_stage_scopes_and_kernel_names(v5e_2x2):
+    """The sharded-index plan's step on a (data 2, model 2) mesh of the
+    described chips, at chr1 scale (2^25 buckets split over `model`, the
+    packed reference replicated, 4096 pairs a batch): its own front end
+    (bucket-sharded lookup + merge/filter) and the replicated plan's
+    stages carry the same scopes and kernel names."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.core.seedmap import SeedMapConfig
+    from repro.engine import plan
+
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(2, 2), ("data", "model"))
+    cfg = PipelineConfig(frontend_backend="pallas", light_backend="pallas",
+                         residual_backend="pallas", packed_ref=True)
+    n_model, B = mesh.shape["model"], 4096
+    state = (jax.ShapeDtypeStruct(
+                 (n_model, (1 << TABLE_BITS) // n_model + 1), jnp.int32),
+             jax.ShapeDtypeStruct((n_model, REF_LEN // n_model), jnp.int32),
+             jax.ShapeDtypeStruct((REF_LEN // BASES_PER_WORD + 1,),
+                                  jnp.uint32))
+    step = plan.jit_step(
+        plan.raw_sharded_index_step(
+            mesh, cfg, SeedMapConfig(table_bits=TABLE_BITS)),
+        mesh=mesh, state_shardings=plan.serve_state_shardings(mesh))
+    reads = jax.ShapeDtypeStruct((B, R), jnp.uint8)
+    text = step.lower(*state, reads, reads,
+                      jax.ShapeDtypeStruct((), jnp.int32)).as_text(
+                          debug_info=True)
+    for scope in ("frontend", "light_align", "residual_dp", "assemble"):
+        assert _has_scope(text, scope), scope
+    assert _kernel_names(text) == {"pair_frontend", "candidate_pair_align",
+                                   "residual_pair_dp"}

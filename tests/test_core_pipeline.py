@@ -1,18 +1,22 @@
 """Integration tests: end-to-end GenPair pipeline, simulator, baseline,
 long reads, residual routing."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import (
     INVALID_LOC, PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap,
-    map_pairs, random_reference, simulate_pairs, stage_stats,
+    map_pairs_impl, random_reference, simulate_pairs, stage_stats,
 )
 from repro.core.baseline import exact_match_rate, map_single_end
 from repro.core.long_read import LongReadConfig, map_long_reads
 from repro.core.pipeline import (
     M_DP, M_DP_OVERFLOW, M_LIGHT, M_RESIDUAL_FULL, M_UNMAPPED,
 )
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 
 @pytest.fixture(scope="module")
@@ -26,8 +30,8 @@ def world():
 def test_perfect_reads_all_light_mapped(world):
     ref, sm = world
     sim = simulate_pairs(ref, 32, ReadSimConfig(sub_rate=0, ins_rate=0, del_rate=0), seed=1)
-    res = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                    jnp.asarray(sim.reads2))
+    res = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                        jnp.asarray(sim.reads2))
     assert (np.asarray(res.method) == M_LIGHT).all()
     np.testing.assert_array_equal(np.asarray(res.pos1), sim.true_start1)
     np.testing.assert_array_equal(np.asarray(res.pos2), sim.true_start2)
@@ -38,8 +42,8 @@ def test_perfect_reads_all_light_mapped(world):
 def test_noisy_reads_mostly_mapped_correctly(world):
     ref, sm = world
     sim = simulate_pairs(ref, 128, ReadSimConfig(sub_rate=0.005), seed=2)
-    res = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                    jnp.asarray(sim.reads2))
+    res = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                        jnp.asarray(sim.reads2))
     pos1 = np.asarray(res.pos1)
     mapped = pos1 != INVALID_LOC
     assert mapped.mean() > 0.9
@@ -52,8 +56,8 @@ def test_noisy_reads_mostly_mapped_correctly(world):
 def test_stage_stats_consistency(world):
     ref, sm = world
     sim = simulate_pairs(ref, 64, ReadSimConfig(sub_rate=0.01), seed=3)
-    res = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                    jnp.asarray(sim.reads2))
+    res = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                        jnp.asarray(sim.reads2))
     st = {k: float(v) for k, v in stage_stats(res).items()}
     total = (st["light_mapped"] + st["dp_mapped"] + st["dp_overflow"]
              + st["residual_full_dp"])
@@ -70,9 +74,9 @@ def test_method_codes_partition_batch(world):
     ref, sm = world
     for sub, frac, seed in ((0.01, 0.25, 12), (0.05, 0.02, 13)):
         sim = simulate_pairs(ref, 96, ReadSimConfig(sub_rate=sub), seed=seed)
-        res = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                        jnp.asarray(sim.reads2),
-                        PipelineConfig(residual_capacity_frac=frac))
+        res = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                            jnp.asarray(sim.reads2),
+                            PipelineConfig(residual_capacity_frac=frac))
         m = np.asarray(res.method)
         had = np.asarray(res.had_hits)
         passed = np.asarray(res.passed_adjacency)
@@ -103,8 +107,8 @@ def test_residual_capacity_overflow():
     # very noisy reads force DP fallback
     sim = simulate_pairs(ref, 64, ReadSimConfig(sub_rate=0.06), seed=5)
     cfg = PipelineConfig(residual_capacity_frac=0.05)
-    res = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                    jnp.asarray(sim.reads2), cfg)
+    res = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                        jnp.asarray(sim.reads2), cfg)
     m = np.asarray(res.method)
     needs_dp = np.asarray(res.passed_adjacency & ~res.light_ok)
     cap = max(1, round(64 * 0.05))
@@ -116,8 +120,8 @@ def test_dp_rescues_noisy_pairs(world):
     ref, sm = world
     sim = simulate_pairs(ref, 64, ReadSimConfig(sub_rate=0.03), seed=6)
     cfg = PipelineConfig(residual_capacity_frac=0.9)
-    res = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                    jnp.asarray(sim.reads2), cfg)
+    res = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                        jnp.asarray(sim.reads2), cfg)
     m = np.asarray(res.method)
     assert (m == M_DP).sum() > 0
     dp_pos = np.asarray(res.pos1)[m == M_DP]
@@ -129,12 +133,12 @@ def test_paper_mode_vs_minsplit_accept_rate(world):
     """minsplit (beyond-paper) must accept at least as many pairs."""
     ref, sm = world
     sim = simulate_pairs(ref, 128, ReadSimConfig(sub_rate=0.01), seed=7)
-    r_paper = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                        jnp.asarray(sim.reads2),
-                        PipelineConfig(light_mode="paper"))
-    r_ms = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                     jnp.asarray(sim.reads2),
-                     PipelineConfig(light_mode="minsplit"))
+    r_paper = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                            jnp.asarray(sim.reads2),
+                            PipelineConfig(light_mode="paper"))
+    r_ms = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                         jnp.asarray(sim.reads2),
+                         PipelineConfig(light_mode="minsplit"))
     n_paper = (np.asarray(r_paper.method) == M_LIGHT).sum()
     n_ms = (np.asarray(r_ms.method) == M_LIGHT).sum()
     assert n_ms >= n_paper

@@ -21,4 +21,4 @@ def test_distributed_pipeline_matches_single_device():
         [sys.executable, worker], env=env, capture_output=True, text=True,
         timeout=570)
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr}"
-    assert out.stdout.count("ok:") == 6, out.stdout
+    assert out.stdout.count("ok:") == 7, out.stdout

@@ -1,17 +1,17 @@
-"""Engine API tests: Mapper sessions, execution plans, streaming, shims.
+"""Engine API tests: Mapper sessions, execution plans, streaming.
 
 Covers the ISSUE-4 acceptance points that run on one device:
-  * `Mapper.map` is bit-identical to pre-refactor `map_pairs` on both the
-    jnp-oracle and interpret-kernel backends;
+  * `Mapper.map` is bit-identical to a jitted `map_pairs_impl` on both
+    the jnp-oracle and interpret-kernel backends;
   * CSR `SeedMap` -> `PaddedSeedMap` relayout round-trips (property test
     vs the in-jit `padded_rows_device` derivation);
   * ragged tail batches flow through `map_stream` as padding + an
     `n_valid` mask, and the device-side stage totals/reductions exclude
     the padded rows;
-  * the deprecation shims warn exactly once per process and delegate.
+  * the engine path raises no deprecation warning.
 
 (The mesh plans — data-parallel and sharded-index — are pinned by
-tests/_distributed_worker.py checks 2, 3 and 6.)
+tests/_distributed_worker.py checks 2, 3, 6 and 7.)
 """
 import warnings
 
@@ -20,14 +20,16 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import reset_deprecation_warnings
 from repro.core import (
     INVALID_LOC, PipelineConfig, ReadSimConfig, SeedMapConfig,
-    build_seedmap, map_pairs, random_reference, simulate_pairs,
+    build_seedmap, map_pairs_impl, random_reference, simulate_pairs,
     stage_stat_counts, to_padded,
 )
 from repro.core.query import padded_rows_device, query_csr, query_padded
 from repro.engine import ExecutionConfig, Mapper
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 
 @pytest.fixture(scope="module")
@@ -62,8 +64,8 @@ def test_mapper_matches_map_pairs_jnp(world):
     mapper = Mapper.from_index(sm, ref, cfg,
                                ExecutionConfig(backend="jnp"))
     res_e = mapper.map(sim.reads1, sim.reads2)
-    res_l = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                      jnp.asarray(sim.reads2), cfg)
+    res_l = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                          jnp.asarray(sim.reads2), cfg)
     _assert_same_result(res_e, res_l)
     assert np.asarray(res_e.n_valid).all()
 
@@ -73,15 +75,15 @@ def test_mapper_matches_map_pairs_interpret(small_world):
     cfg = PipelineConfig(light_backend="interpret",
                          frontend_backend="interpret")
     # The engine session resolves the CSR map to a host-side
-    # `PaddedSeedMap`; map_pairs re-derives padded rows in-jit — the
-    # round-trip property below is what makes these meet bit-for-bit.
+    # `PaddedSeedMap`; `map_pairs_impl` re-derives padded rows in-jit —
+    # the round-trip property below is what makes these meet bit-for-bit.
     mapper = Mapper.from_index(sm, ref, cfg,
                                ExecutionConfig(backend="interpret"))
     from repro.core.seedmap import PaddedSeedMap
     assert isinstance(mapper.index, PaddedSeedMap)
     res_e = mapper.map(sim.reads1, sim.reads2)
-    res_l = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                      jnp.asarray(sim.reads2), cfg)
+    res_l = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                          jnp.asarray(sim.reads2), cfg)
     _assert_same_result(res_e, res_l)
 
 
@@ -376,20 +378,18 @@ def test_run_stream_clock_starts_at_first_dispatch(world):
     assert sr.seconds < 0.8 * delay
 
 
-# ------------------------------------------------------------- shims -----
+# ------------------------------------------------------ one way in -----
 def test_shims_warn_once_and_delegate(world):
+    """A session maps exactly as the jitted pipeline body, on every
+    `MapResult` field: here a packed session, which places the packed
+    words once, against the body packing the uint8 reference in-step."""
     ref, sm, sim = world
-    reset_deprecation_warnings()
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        r1 = map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                       jnp.asarray(sim.reads2), PipelineConfig())
-        map_pairs(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
-                  jnp.asarray(sim.reads2), PipelineConfig())
-    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1 and "Mapper" in str(dep[0].message)
-    mapper = Mapper.from_index(sm, ref, PipelineConfig())
-    _assert_same_result(mapper.map(sim.reads1, sim.reads2), r1)
+    cfg = PipelineConfig(packed_ref=True)
+    want = map_pairs_jit(sm, jnp.asarray(ref), jnp.asarray(sim.reads1),
+                         jnp.asarray(sim.reads2), cfg)
+    mapper = Mapper.from_index(sm, ref, cfg)
+    assert mapper._state[1].dtype == jnp.uint32
+    _assert_same_result(mapper.map(sim.reads1, sim.reads2), want)
 
 
 def test_engine_path_is_warning_clean(world):

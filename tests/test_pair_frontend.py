@@ -4,18 +4,19 @@ shared backend layer (kernels/backend).
 - interpret-mode Pallas kernels vs the staged seeding/query/pair_filter
   oracle across (S, K, Δ, C) grids, including all-invalid and
   duplicate-heavy rows and candidate overflow (n > C);
-- map_pairs end-to-end parity between frontend backends, for both the
+- `map_pairs_impl` end-to-end parity between frontend backends, for both the
   CSR SeedMap and the PaddedSeedMap input flavors;
 - the (start1, start2) pair-dedup fix in paired_adjacency_filter;
-- REPRO_BACKEND / deprecated REPRO_LIGHT_BACKEND resolution.
+- REPRO_BACKEND resolution.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import (
-    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap, map_pairs,
-    random_reference, simulate_pairs, to_padded,
+    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap,
+    map_pairs_impl, random_reference, simulate_pairs, to_padded,
 )
 from repro.core.pair_filter import paired_adjacency_filter
 from repro.core.query import QueryResult
@@ -23,6 +24,9 @@ from repro.core.seeding import seed_offsets_np
 from repro.core.seedmap import INVALID_LOC
 from repro.kernels.backend import resolve_backend
 from repro.kernels.pair_frontend import frontend_merge_filter, pair_frontend
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 RNG = np.random.default_rng(0)
 
@@ -54,7 +58,6 @@ def test_kernel_package_imports_standalone():
 # ------------------------------------------------------ backend resolver --
 def test_resolver_defaults_and_validation(monkeypatch):
     monkeypatch.delenv("REPRO_BACKEND", raising=False)
-    monkeypatch.delenv("REPRO_LIGHT_BACKEND", raising=False)
     # auto -> jnp off-TPU; explicit names pass through
     assert resolve_backend("auto") in ("jnp", "pallas")
     for b in ("jnp", "interpret", "pallas"):
@@ -65,7 +68,6 @@ def test_resolver_defaults_and_validation(monkeypatch):
 
 def test_resolver_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_BACKEND", "interpret")
-    monkeypatch.delenv("REPRO_LIGHT_BACKEND", raising=False)
     assert resolve_backend("auto") == "interpret"
     # explicit backend beats the env
     assert resolve_backend("jnp") == "jnp"
@@ -73,16 +75,6 @@ def test_resolver_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_BACKEND", "nope")
     with pytest.raises(ValueError, match="unknown backend"):
         resolve_backend("auto")
-
-
-def test_resolver_deprecated_alias(monkeypatch):
-    monkeypatch.delenv("REPRO_BACKEND", raising=False)
-    monkeypatch.setenv("REPRO_LIGHT_BACKEND", "interpret")
-    with pytest.warns(DeprecationWarning, match="REPRO_LIGHT_BACKEND"):
-        assert resolve_backend("auto") == "interpret"
-    # REPRO_BACKEND wins over the alias
-    monkeypatch.setenv("REPRO_BACKEND", "jnp")
-    assert resolve_backend("auto") == "jnp"
 
 
 def test_unknown_backend_raises():
@@ -283,7 +275,7 @@ def test_filter_keeps_distinct_mate2_placements():
     assert int(cands.pos2[0, 0]) == 80
 
 
-# ------------------------------------------------- map_pairs end to end ---
+# -------------------------------------------- map_pairs_impl end to end ---
 @pytest.fixture(scope="module")
 def small_world():
     rng = np.random.default_rng(1)
@@ -296,10 +288,10 @@ def small_world():
 
 def test_map_pairs_frontend_backends_agree(small_world):
     ref_j, sm, reads1, reads2 = small_world
-    res_jnp = map_pairs(sm, ref_j, reads1, reads2,
-                        PipelineConfig(frontend_backend="jnp"))
-    res_int = map_pairs(sm, ref_j, reads1, reads2,
-                        PipelineConfig(frontend_backend="interpret"))
+    res_jnp = map_pairs_jit(sm, ref_j, reads1, reads2,
+                            PipelineConfig(frontend_backend="jnp"))
+    res_int = map_pairs_jit(sm, ref_j, reads1, reads2,
+                            PipelineConfig(frontend_backend="interpret"))
     for f in ("pos1", "pos2", "score1", "score2", "method",
               "cigar1", "cigar2", "had_hits", "passed_adjacency"):
         np.testing.assert_array_equal(
@@ -313,11 +305,11 @@ def test_map_pairs_padded_seedmap_input(small_world):
     max_locs_per_seed), on both frontend backends."""
     ref_j, sm, reads1, reads2 = small_world
     psm = to_padded(sm)
-    base = map_pairs(sm, ref_j, reads1, reads2,
-                     PipelineConfig(frontend_backend="jnp"))
+    base = map_pairs_jit(sm, ref_j, reads1, reads2,
+                         PipelineConfig(frontend_backend="jnp"))
     for be in ("jnp", "interpret"):
-        res = map_pairs(psm, ref_j, reads1, reads2,
-                        PipelineConfig(frontend_backend=be))
+        res = map_pairs_jit(psm, ref_j, reads1, reads2,
+                            PipelineConfig(frontend_backend=be))
         for f in ("pos1", "pos2", "score1", "score2", "method"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(base, f)), np.asarray(getattr(res, f)),

@@ -8,17 +8,18 @@
 - runtime single-mate skip instrumentation: at ``block=1`` the kernel
   executes DP for exactly the failed mates (`dp_lanes`), and both DP
   kernel families trace the one shared `dp_block` recurrence;
-- `map_pairs` end-to-end parity between the jnp oracle and the interpret
+- `map_pairs_impl` end-to-end parity between the jnp oracle and the interpret
   kernel behind ``PipelineConfig.residual_backend``, plus the
   ``residual_capacity_frac=0`` static-skip semantics (no DP traced, all
   residual rows routed to M_DP_OVERFLOW).
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import (
-    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap, map_pairs,
+    PipelineConfig, ReadSimConfig, SeedMapConfig, build_seedmap,
     random_reference, simulate_pairs,
 )
 from repro.core.dp_fallback import NEG, gotoh_semiglobal
@@ -30,6 +31,9 @@ from repro.core.seedmap import INVALID_LOC
 from repro.kernels.banded_sw.kernel import count_dp_block_calls
 from repro.kernels.banded_sw.ops import banded_sw
 from repro.kernels.residual_dp import residual_pair_dp
+
+
+map_pairs_jit = jax.jit(map_pairs_impl, static_argnames=("cfg",))
 
 L, R = 5000, 100
 _CMP = ("score1", "ref_end1", "score2", "ref_end2")  # the bit-exact fields
@@ -244,14 +248,15 @@ def _sim_world(ref_len=40_000, bits=14, n=24, sub=2e-2, seed=5):
     dict(residual_capacity_frac=0.05),                # overflow regime
 ])
 def test_map_pairs_residual_backend_parity(cfg_kw):
-    """map_pairs with residual_backend=interpret is bit-identical to the
-    jnp oracle across the (band, packed, residual-mix) grid."""
+    """The pipeline with residual_backend=interpret is bit-identical to
+    the jnp oracle across the (band, packed, residual-mix) grid."""
     ref, sm, r1, r2 = _sim_world(sub=3e-2)
     refj = jnp.asarray(ref)
-    res_j = map_pairs(sm, refj, r1, r2,
-                      PipelineConfig(residual_backend="jnp", **cfg_kw))
-    res_i = map_pairs(sm, refj, r1, r2,
-                      PipelineConfig(residual_backend="interpret", **cfg_kw))
+    res_j = map_pairs_jit(sm, refj, r1, r2,
+                          PipelineConfig(residual_backend="jnp", **cfg_kw))
+    res_i = map_pairs_jit(
+        sm, refj, r1, r2,
+        PipelineConfig(residual_backend="interpret", **cfg_kw))
     for f in res_j._fields:
         np.testing.assert_array_equal(
             np.asarray(getattr(res_j, f)), np.asarray(getattr(res_i, f)),
@@ -270,18 +275,18 @@ def test_map_pairs_all_light_and_all_residual_parity():
     noisy = simulate_pairs(ref, 16, ReadSimConfig(sub_rate=0.12), seed=2)
     for sim in (perfect, noisy):
         r1, r2 = jnp.asarray(sim.reads1), jnp.asarray(sim.reads2)
-        res_j = map_pairs(sm, refj, r1, r2,
-                          PipelineConfig(residual_backend="jnp",
-                                         residual_capacity_frac=0.9))
-        res_i = map_pairs(sm, refj, r1, r2,
-                          PipelineConfig(residual_backend="interpret",
-                                         residual_capacity_frac=0.9))
+        res_j = map_pairs_jit(sm, refj, r1, r2,
+                              PipelineConfig(residual_backend="jnp",
+                                             residual_capacity_frac=0.9))
+        res_i = map_pairs_jit(sm, refj, r1, r2,
+                              PipelineConfig(residual_backend="interpret",
+                                             residual_capacity_frac=0.9))
         for f in res_j._fields:
             np.testing.assert_array_equal(
                 np.asarray(getattr(res_j, f)),
                 np.asarray(getattr(res_i, f)), err_msg=f"field {f}")
-    m = np.asarray(map_pairs(sm, refj, jnp.asarray(perfect.reads1),
-                             jnp.asarray(perfect.reads2)).method)
+    m = np.asarray(map_pairs_jit(sm, refj, jnp.asarray(perfect.reads1),
+                                 jnp.asarray(perfect.reads2)).method)
     assert (m == M_LIGHT).all()
 
 
@@ -352,7 +357,7 @@ def test_single_mate_reuses_light_score_in_map_pairs():
     ref, sm, r1, r2 = _sim_world(n=48, sub=2.5e-2, seed=13)
     refj = jnp.asarray(ref)
     cfg = PipelineConfig(residual_capacity_frac=0.9)
-    res = map_pairs(sm, refj, r1, r2, cfg)
+    res = map_pairs_jit(sm, refj, r1, r2, cfg)
     m = np.asarray(res.method)
     dp1 = np.asarray(res.dp_mate1)
     dp2 = np.asarray(res.dp_mate2)

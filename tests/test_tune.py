@@ -96,7 +96,7 @@ def test_mapper_build_picks_up_tuned_knobs(world, tmp_path):
 
 def test_default_build_ignores_cache_without_opt_in(world, monkeypatch):
     """No tune flag, no env: the session must stay bit-stable (the
-    engine-vs-map_pairs parity contract) whatever sits on disk."""
+    engine-vs-`map_pairs_impl` parity contract) whatever sits on disk."""
     ref, sm, _ = world
     monkeypatch.delenv(ENV_CACHE, raising=False)
     mapper = Mapper.from_index(sm, ref, PipelineConfig(),
